@@ -204,7 +204,7 @@ def run_full_bench(cfg: dict) -> dict:
     # steps 4+6: throughput rounds; steps 5+7: maintenance rounds.
     # Phase-level retry (resilience: {phase_attempts: N, phase_backoff_s}):
     # a round that fails transiently — a permanently failed stream, a
-    # dropped device tunnel — re-runs whole up to N times with backoff
+    # device runtime error — re-runs whole up to N times with backoff
     # before the bench aborts. Stream logs are rewritten per attempt, so a
     # retried round scrapes only its own successful run.
     res_cfg = cfg.get("resilience", {})
@@ -223,7 +223,7 @@ def run_full_bench(cfg: dict) -> dict:
                 label=f"throughput round {rnd}",
                 input_format=input_format,
                 sub_queries=sub_queries, backend=backend,
-                mode=tt_cfg.get("mode", "process"),
+                mode=tt_cfg.get("mode", "thread"),
                 warmup=int(tt_cfg.get("warmup", 0)),
                 decimal=decimal,
                 max_attempts=tt_cfg.get("stream_attempts"),

@@ -35,19 +35,56 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DEFAULT_BINARY = os.path.join(_REPO_ROOT, "native", "bin", "ndsdgen")
 
 
+_SRC_DIR = os.path.join(_REPO_ROOT, "native", "datagen")
+_SOURCES = ("gen.cpp", "gen.h", "schema_def.inc")
+
+
+def _stale(binary: str) -> bool:
+    """Is the generator missing or older than the sources it is built from?"""
+    if not os.path.exists(binary):
+        return True
+    built = os.path.getmtime(binary)
+    return any(os.path.getmtime(os.path.join(_SRC_DIR, s)) > built
+               for s in _SOURCES)
+
+
 def check_build(binary: str = DEFAULT_BINARY) -> str:
-    """Locate the native generator, building it if the tree is present
-    (reference check.py:47-66 checks the jar/dsdgen build)."""
-    if os.path.exists(binary):
-        return binary
-    src_dir = os.path.join(_REPO_ROOT, "native", "datagen")
-    if os.path.isdir(src_dir):
-        subprocess.run(["make"], cwd=src_dir, check=True,
-                       capture_output=True)
+    """Locate the native generator, (re)building it from native/datagen
+    when it is missing or older than its sources — the binary is a build
+    product (native/bin/ is git-ignored), so the data always comes from
+    the gen.cpp in the tree (reference check.py:47-66 checks the
+    jar/dsdgen build). An explicit non-default ``binary`` is used as is.
+
+    Concurrent callers (test workers, parallel CLIs) serialise on a lock
+    file and the compiler writes a temp name that is renamed into place,
+    so nobody ever executes a half-written binary."""
+    if binary != DEFAULT_BINARY or not os.path.isdir(_SRC_DIR):
         if os.path.exists(binary):
             return binary
-    raise FileNotFoundError(
-        f"ndsdgen binary not found at {binary}; run `make` in native/datagen")
+        raise FileNotFoundError(f"ndsdgen binary not found at {binary}")
+    if not _stale(binary):
+        return binary
+    import fcntl
+    os.makedirs(os.path.dirname(binary), exist_ok=True)
+    with open(binary + ".lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if _stale(binary):      # re-check: a concurrent caller may have built
+            tmp = f"{binary}.{os.getpid()}.tmp"
+            try:
+                made = subprocess.run(["make", f"BIN={tmp}"], cwd=_SRC_DIR,
+                                      capture_output=True, text=True)
+                if made.returncode != 0:
+                    raise RuntimeError(
+                        "building ndsdgen failed (a C++17 compiler and make "
+                        f"are required):\n{made.stdout}{made.stderr}")
+                for line in made.stdout.splitlines():
+                    if " -o " in line:
+                        print(f"built ndsdgen: {line.strip()}", flush=True)
+                os.replace(tmp, binary)
+            finally:
+                if os.path.exists(tmp):
+                    os.remove(tmp)
+    return binary
 
 
 def valid_range(r: str, parallel: int) -> tuple[int, int]:

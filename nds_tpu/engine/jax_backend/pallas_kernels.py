@@ -46,11 +46,14 @@ Dispatch is a thread-local op set installed by the executor
 shared-program fingerprint and the session's stream-config key).
 
 Platform handling (``probe``): on a TPU backend kernels compile through
-Mosaic; on the CPU backend they run in Pallas interpret mode — tier-1 CI
-exercises the real kernel bodies under ``JAX_PLATFORMS=cpu``; on any other
-backend (or import failure) the module reports "off" with a reason, one
-warning is logged through ``obs.log``, and every call site keeps the XLA
-lowering (``pallas_fallback_reason`` lands in ``last_exec_stats``).
+Mosaic and never run interpreted (the host record pass of a TPU process
+takes the XLA lowering instead, JaxExecutor.execute); on the CPU backend
+they run in Pallas interpret mode — tier-1 CI exercises the real kernel
+bodies under ``JAX_PLATFORMS=cpu``. A REQUESTED op that cannot run is an
+error, never a quiet XLA substitute: on a backend without a TPU Pallas
+lowering ``op_active`` raises ``PallasLoweringError``, and on a TPU every
+kernel signature is compiled standalone once (``_checked``) so a Mosaic
+refusal names the kernel and carries the compiler's message.
 """
 from __future__ import annotations
 
@@ -102,8 +105,15 @@ GROUPBY_MIN_ROWS = 1 << 12
 # platform probe + per-executor op activation
 # ---------------------------------------------------------------------------
 
+class PallasLoweringError(Exception):
+    """A requested Pallas kernel cannot run on this backend: no TPU Pallas
+    lowering here, or Mosaic refused the kernel. Names the kernel and
+    carries the compiler's message. Deliberately none of the executor's
+    _NOJIT_ERRORS (nor a NotImplementedError): a requested kernel is never
+    quietly replaced by the XLA lowering or a host fallback."""
+
+
 _PROBE: Optional[tuple] = None
-_WARNED = False
 
 
 def probe() -> tuple[str, str]:
@@ -130,9 +140,8 @@ def probe() -> tuple[str, str]:
 
 
 def _reset_probe_for_tests() -> None:
-    global _PROBE, _WARNED
+    global _PROBE
     _PROBE = None
-    _WARNED = False
 
 
 def parse_ops(spec) -> frozenset:
@@ -165,26 +174,16 @@ def active_ops() -> frozenset:
 
 
 def op_active(op: str) -> bool:
-    """Is `op` enabled for the in-flight execution AND usable here? A
-    requested-but-unusable platform logs one warning and reports off."""
-    global _WARNED
+    """Is `op` enabled for the in-flight execution? A requested op on a
+    platform that cannot run it raises PallasLoweringError naming it."""
     if op not in active_ops():
         return False
     mode, reason = probe()
     if mode == "off":
-        if not _WARNED:
-            _WARNED = True
-            get_logger("pallas").warning(
-                "pallas_ops requested but unavailable (%s); "
-                "keeping the XLA lowering", reason)
-        return False
+        raise PallasLoweringError(
+            f"pallas op {op!r} was requested (pallas_ops) but cannot run "
+            f"here: {reason}")
     return True
-
-
-def fallback_reason() -> Optional[str]:
-    """The platform reason pallas is off, or None when usable."""
-    mode, reason = probe()
-    return reason if mode == "off" else None
 
 
 def _interpret() -> bool:
@@ -194,6 +193,23 @@ def _interpret() -> bool:
 def _pl():
     from jax.experimental import pallas as pl
     return pl
+
+
+def _checked(name: str, call, arg_specs: list):
+    """On a TPU backend, compile `call` standalone once for its signature
+    (the kernel factories are lru_cached) so a Mosaic refusal surfaces as
+    PallasLoweringError naming the kernel with the compiler's message —
+    inside a whole-plan program it would be one anonymous compile failure.
+    Interpret mode has nothing to lower."""
+    if _interpret():
+        return call
+    try:
+        jax.jit(call).lower(*arg_specs).compile()
+    except Exception as e:
+        raise PallasLoweringError(
+            f"pallas kernel {name} does not lower on "
+            f"{jax.devices()[0].device_kind}: {type(e).__name__}: {e}") from e
+    return call
 
 
 def _bspec(shape, index_map):
@@ -273,9 +289,12 @@ def _sort_call(N: int, B: int, key_dtype: str, merge: bool,
 
     blocked = _bspec((B,), lambda b: (b,))
     in_specs = [blocked, _bspec((B,), lambda b: (b,))]
+    arg_specs = [jax.ShapeDtypeStruct((N,), kd),
+                 jax.ShapeDtypeStruct((N,), _I32)]
     if merge:
         in_specs = [_bspec((1,), lambda b: (0,))] + in_specs
-    return pl.pallas_call(
+        arg_specs = [jax.ShapeDtypeStruct((1,), _I32)] + arg_specs
+    call = pl.pallas_call(
         merge_kern if merge else local_kern,
         grid=(N // B,),
         in_specs=in_specs,
@@ -285,6 +304,9 @@ def _sort_call(N: int, B: int, key_dtype: str, merge: bool,
                    jax.ShapeDtypeStruct((N,), _I32)],
         interpret=interpret,
     )
+    return _checked(
+        f"sort_{'merge' if merge else 'local'}[N={N},B={B},{key_dtype}]",
+        call, arg_specs)
 
 
 def sort_pairs(key: jax.Array, idx: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -379,7 +401,7 @@ def _seg_call(n_pad: int, tile: int, cap: int, specs: tuple,
                 o_ref[:] = comb(o_ref[:], part)
 
     blocked = _bspec((tile,), lambda b: (b,))
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kern,
         grid=(n_pad // tile,),
         in_specs=[blocked] + [_bspec((tile,), lambda b: (b,))
@@ -389,6 +411,11 @@ def _seg_call(n_pad: int, tile: int, cap: int, specs: tuple,
                    for dt, _ in specs],
         interpret=interpret,
     )
+    return _checked(
+        f"seg_reduce[n={n_pad},tile={tile},segments={cap},"
+        f"{'+'.join(f'{op}:{dt}' for dt, op in specs)}]", call,
+        [jax.ShapeDtypeStruct((n_pad,), _I32)] +
+        [jax.ShapeDtypeStruct((n_pad,), jnp.dtype(dt)) for dt, _ in specs])
 
 
 def seg_supported(data: jax.Array, num_segments: int, op: str) -> bool:
@@ -458,7 +485,7 @@ def _gather_call(n_pad: int, blk: int, src_specs: tuple, interpret: bool):
 
     in_specs = [_bspec((blk,), lambda b: (b,))]
     in_specs += [_bspec((rows,), lambda b: (0,)) for rows, _ in src_specs]
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kern,
         grid=(n_pad // blk,),
         in_specs=in_specs,
@@ -467,6 +494,12 @@ def _gather_call(n_pad: int, blk: int, src_specs: tuple, interpret: bool):
                    for _, dt in src_specs],
         interpret=interpret,
     )
+    return _checked(
+        f"gather[n={n_pad},blk={blk},"
+        f"{'+'.join(f'{dt}[{rows}]' for rows, dt in src_specs)}]", call,
+        [jax.ShapeDtypeStruct((n_pad,), _I32)] +
+        [jax.ShapeDtypeStruct((rows,), jnp.dtype(dt))
+         for rows, dt in src_specs])
 
 
 def _src_bytes(src: jax.Array) -> int:

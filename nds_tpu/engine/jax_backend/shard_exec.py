@@ -39,12 +39,13 @@ from typing import Optional
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...obs import metrics as _metrics
 from ...obs.device_time import PROGRAMS as _PROGRAMS
 from ...obs.trace import TRACER
-from ...parallel.dist_ops import gather_partials, shard_map
+from ...parallel.dist_ops import gather_partials
 from ..column import Table
 from ..streaming import partition_morsel_rows
 from .device import (DTable, PackedTable, _pack_payload, bucket,

@@ -26,7 +26,7 @@ from typing import Optional
 #: executor last_stats keys with first-class fields (everything else
 #: passes through ``extra``)
 _EXECUTOR_FIELDS = ("mode", "device_ms", "precompile_s", "nojit_reason",
-                    "transient", "spec_mismatch", "segments", "segments_run",
+                    "spec_mismatch", "segments", "segments_run",
                     "seg_device_ms")
 
 
@@ -39,7 +39,6 @@ class ExecStats:
     device_ms: Optional[float] = None
     precompile_s: Optional[float] = None
     nojit_reason: Optional[str] = None
-    transient: Optional[str] = None
     spec_mismatch: Optional[str] = None
     # -- compile segmentation ------------------------------------------------
     segments: Optional[int] = None
@@ -187,7 +186,7 @@ class ExecStats:
         out: dict = {}
         if self.mode:
             out["mode"] = self.mode
-        for k in ("device_ms", "precompile_s", "nojit_reason", "transient",
+        for k in ("device_ms", "precompile_s", "nojit_reason",
                   "spec_mismatch", "segments", "segments_run",
                   "seg_device_ms", "jobs", "morsels", "morsel_rows",
                   "re_records", "shared_scan", "scan_passes",

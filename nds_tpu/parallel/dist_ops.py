@@ -19,25 +19,10 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..engine.jax_backend import kernels
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma=True):
-    """Version-portable shard_map: newer jax exports it top-level with a
-    `check_vma` kwarg; older releases keep it in jax.experimental with the
-    same knob named `check_rep`. Every call site in this tree routes
-    through here so the mesh path runs on both."""
-    try:
-        from jax import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=check_vma)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma)
 
 _I32 = jnp.int32
 

@@ -2,9 +2,7 @@
 
 One logical axis ("shards") carries data-parallel table partitioning — the
 analog of the reference's executor count (reference nds/base.template
-NUM_EXECUTORS x EXECUTOR_CORES; here chips on ICI). A second optional axis
-("streams") multiplexes concurrent query streams onto disjoint sub-slices
-for the throughput test (reference nds/nds-throughput runs N OS processes).
+NUM_EXECUTORS x EXECUTOR_CORES; here chips on ICI).
 """
 from __future__ import annotations
 

@@ -59,11 +59,23 @@ def _host_capture() -> dict:
     try:        # report.py is imported by jax-less tools (datagen)
         import jax
         out["jax"] = jax.__version__
-        out["jax_backend"] = jax.default_backend()
-        out["device_count"] = jax.device_count()
+        dev = device_capture()
+        out.update(jax_backend=dev["platform"],
+                   device_kind=dev["device_kind"],
+                   device_count=dev["device_count"])
     except Exception:
         pass
     return out
+
+
+def device_capture() -> dict:
+    """The device this process's JAX runs on, exactly as JAX reports it —
+    every record that carries a time names it."""
+    import jax
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices)}
 
 
 class BenchReport:

@@ -4,11 +4,13 @@ Capability parity with the reference throughput harness (reference
 nds/nds-throughput: xargs -P fans one full Spark app per stream;
 nds/nds_bench.py:138-157 computes elapsed = max(stream end) - min(stream
 start) by scraping the per-stream time logs). Here each stream is a full
-power run; ``process`` mode launches one OS process per stream (the
-reference's N-concurrent-apps shape — separate interpreters so the
-streams contend only for the device, not the GIL), ``thread`` mode
-multiplexes in-process sessions onto one device (cheap for tests and for
-sharing a single compiled-query cache), and ``service`` mode submits
+power run; ``thread`` mode (the default) multiplexes in-process sessions
+onto the device this process holds and shares one compiled-query cache,
+``process`` mode launches one OS process per stream (the reference's
+N-concurrent-apps shape — separate interpreters, no shared GIL) and is
+refused up front (resilience.ChipPlacementError) unless those processes
+run on the host, because a chip belongs to one process at a time and
+nothing here gives each child a chip of its own, and ``service`` mode submits
 EVERY stream's queries through one shared admission-controlled
 QueryService over a single Session (nds_tpu/service): one warehouse
 registration, one cross-client program cache, compatible queries from
@@ -35,7 +37,7 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 
 from .resilience import (DeadlineExceeded, FAULTS, RetryPolicy,
-                         run_with_deadline)
+                         check_child_placement, run_with_deadline)
 
 
 def stream_log_path(time_log_dir: str, stream: int) -> str:
@@ -298,7 +300,7 @@ def run_throughput(input_prefix: str, stream_dir: str, streams: list[int],
                    sub_queries: list[str] | None = None,
                    property_file: str | None = None,
                    backend: str | None = None,
-                   mode: str = "process",
+                   mode: str = "thread",
                    warmup: int = 0, decimal: str | None = None,
                    max_attempts: int | None = None,
                    stream_timeout: float | None = None,
@@ -309,6 +311,10 @@ def run_throughput(input_prefix: str, stream_dir: str, streams: list[int],
 
     Elapsed is max(stream Power End) - min(stream Power Start) over the
     written time logs, the reference's definition (nds_bench.py:138-157).
+
+    mode "process" raises ChipPlacementError before anything starts
+    when its children would each need an accelerator
+    (resilience.check_child_placement).
 
     mode "service" multiplexes every stream through ONE shared
     admission-controlled QueryService over a single Session (shared
@@ -333,6 +339,8 @@ def run_throughput(input_prefix: str, stream_dir: str, streams: list[int],
     """
     from .config import EngineConfig
 
+    if mode == "process":
+        check_child_placement("throughput --mode process")
     config = EngineConfig.from_property_file(property_file)
     if config.fault_points:
         # the supervisor's own fault points (stream.spawn) arm here: no
@@ -502,12 +510,15 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--sub_queries", default=None)
     p.add_argument("--property_file", default=None)
     p.add_argument("--backend", default=None, choices=["jax", "numpy"])
-    p.add_argument("--mode", default="process",
+    p.add_argument("--mode", default="thread",
                    choices=["process", "thread", "service"],
-                   help="process = one OS process per stream (reference "
-                        "shape); thread = in-process sessions; service = "
-                        "all streams through one shared admission-"
-                        "controlled QueryService (nds_tpu/service)")
+                   help="thread = in-process sessions on the device this "
+                        "process holds; service = all streams through one "
+                        "shared admission-controlled QueryService "
+                        "(nds_tpu/service); process = one OS process per "
+                        "stream (reference shape), refused unless the "
+                        "processes run on the host (JAX_PLATFORMS=cpu): a "
+                        "chip belongs to one process at a time")
     p.add_argument("--warmup", type=int, default=0,
                    help="untimed pre-runs per query in each stream")
     p.add_argument("--decimal", default=None, choices=["f64", "i64"])

@@ -361,7 +361,13 @@ def main(argv=None) -> int:
     if a.worker:
         return run_worker(a.worker)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # this parent runs an in-process baseline AND spawns engine server
+    # processes: on an accelerator it would hold the chip its servers need.
+    # It is a host-side scheduling comparison (FRONTDOOR_r01.json says so);
+    # ask for the CPU by name or be refused up front (ROADMAP R4 is the
+    # chip cell: one server process, JAX-free clients).
+    from nds_tpu.resilience import check_child_placement
+    check_child_placement("frontdoor_bench.py")
     if a.quick:
         a.interactive_clients = a.batch_clients = 8
         a.interactive_queries, a.batch_queries = 3, 2
@@ -421,8 +427,10 @@ def main(argv=None) -> int:
                for r in ph["workers"].values())
     checked = sum(r["checked"] for ph in (fifo, fair)
                   for r in ph["workers"].values())
+    from nds_tpu.report import device_capture
     record = {
-        "schema_version": 1,
+        "schema_version": 2,
+        "device": device_capture(),
         "config": {
             "seed": a.seed, "engine": dict(ENGINE_KW),
             "tenant_weights": TENANT_WEIGHTS,

@@ -100,8 +100,11 @@ def main(argv=None) -> int:
     p.add_argument("--out_of_core_min_rows", type=int, default=10_000)
     args = p.parse_args(argv)
 
+    from nds_tpu.config import maybe_enable_compile_cache
     from nds_tpu.service import FrontDoorServer, QueryService, ServiceConfig
 
+    # same persistent compile cache as every CLI (config.compile_cache_dir)
+    maybe_enable_compile_cache()
     work_dir = tempfile.mkdtemp(prefix="frontdoor_")
     session = build_session(args, work_dir)
     rc_cfg = None
@@ -121,10 +124,17 @@ def main(argv=None) -> int:
     server = FrontDoorServer(svc, host=args.host, port=args.port,
                              allow_chaos=args.allow_chaos)
     server.start()
+    import jax
+
+    from nds_tpu.report import device_capture
     print("FRONTDOOR " + json.dumps({
         "host": args.host, "port": server.port, "pid": os.getpid(),
         "epoch": server.epoch, "fair_queue": args.fair_queue,
-        "preemption": args.preemption}), flush=True)
+        "preemption": args.preemption,
+        # what this engine really runs on: the device as JAX reports it,
+        # and whether 64-bit types are on (off = f32/i32 on the device)
+        "device": device_capture(),
+        "x64": bool(jax.config.jax_enable_x64)}), flush=True)
 
     stop = {"done": False}
 

@@ -665,10 +665,8 @@ def main(argv=None) -> int:
 
     os.environ["NDS_TPU_BENCH_SF"] = a.sf
     import bench  # noqa: E402  (repo root; reads NDS_TPU_BENCH_* at import)
-    from nds_tpu.config import enable_compile_cache
-    enable_compile_cache(os.path.join(
-        os.path.expanduser("~"), ".cache",
-        f"nds_tpu_xla_{bench._host_cache_tag()}"))
+    from nds_tpu.config import maybe_enable_compile_cache
+    maybe_enable_compile_cache()
 
     def log(msg):
         print(msg, file=sys.stderr, flush=True)
@@ -750,6 +748,8 @@ def main(argv=None) -> int:
                     f"{pd.get('system_queries', 0)})")
 
     import platform
+
+    from nds_tpu.report import device_capture
     out = {
         "schema_version": 3,
         "kind": "service_open_loop",
@@ -760,12 +760,12 @@ def main(argv=None) -> int:
         "zipf": a.zipf,
         "cache_mode": a.cache,
         "platform": {"python": platform.python_version(),
-                     "machine": platform.machine(),
-                     "jax_platform": "cpu"},
-        "note": ("CPU host: the 'device' executes on the same cores, so "
-                 "QPS gains come from batching + pipelining + shared "
-                 "programs, not accelerator parallelism — TPU runs gain "
-                 "the device/host overlap on top"),
+                     "machine": platform.machine()},
+        # the device the engine ran on, as JAX reports it: on "cpu" the
+        # 'device' executes on the load generator's own cores, so QPS
+        # gains there come from batching + pipelining + shared programs,
+        # not from host/accelerator overlap
+        "device": device_capture(),
         "serial": serial,
         "runs": runs,
     }

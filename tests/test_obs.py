@@ -308,7 +308,12 @@ def test_program_registry_table_and_roofline():
     reg.record_run("q9/root", 30.0)
     reg.record_run("q1/root", 5.0)
     reg.record_cost("q9/root", {"flops": 1e6, "bytes accessed": 4e6})
-    reg.record_cost("q1/root", [{"flops": 2e3, "bytes accessed": 1e3}])
+    reg.record_cost("q1/root", {"flops": 2e3, "bytes accessed": 1e3})
+    # no bandwidth given (a CPU run): no roofline under a device metric's name
+    assert all("roofline_frac" not in r for r in reg.table())
+    with pytest.raises(dt.UnknownDeviceError, match="cpu"):
+        dt.peak_hbm_gbps("cpu")
+    assert dt.peak_hbm_gbps("TPU v5 lite") == 819.0
     rows = reg.table(bw_gbps=100.0)
     assert [r["program"] for r in rows] == ["q9/root", "q1/root"]
     top = rows[0]

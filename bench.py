@@ -178,7 +178,6 @@ def main(argv=None) -> None:
 
     from nds_tpu.engine import Session
     from nds_tpu.obs import log as obs_log
-    from nds_tpu.obs.device_time import PROGRAMS
     from nds_tpu.obs.metrics import METRICS
     from nds_tpu.obs.trace import TRACER
     from nds_tpu.power import gen_sql_from_stream, setup_tables
@@ -241,7 +240,6 @@ def main(argv=None) -> None:
     upload_bytes: dict[str, int] = {}
     exec_modes: dict[str, str] = {}
     fallback_reasons: dict[str, list] = {}
-    attribution: dict[str, float] = {}
     encodings: dict[str, dict] = {}
     adaptive_evidence: dict[str, dict] = {}
     for name in units:
@@ -280,13 +278,10 @@ def main(argv=None) -> None:
             log.error(f"FATAL: {name} fell back to host: {reasons}")
             sys.exit(1)
         best = float("inf")
-        wall_s = 0.0
-        prog_ms0 = PROGRAMS.total_ms()
         for _ in range(TIMED_RUNS):
             t0 = time.perf_counter()
             t_last = session.sql(sql, backend="jax", label=name)
             run_s = time.perf_counter() - t0
-            wall_s += run_s
             best = min(best, run_s)
             # per-template latency distribution: the same histogram family
             # the service records into, so one registry view ranks slow
@@ -294,12 +289,6 @@ def main(argv=None) -> None:
             METRICS.histogram("query_latency_ms",
                               template=name).observe(run_s * 1000.0)
         jax_ms[name] = best * 1000
-        # fraction of the timed window the per-program device-time
-        # attribution explains (>=0.9 expected: everything outside
-        # CompiledQuery dispatch is python glue)
-        attribution[name] = round(
-            (PROGRAMS.total_ms() - prog_ms0) / (wall_s * 1000), 3) \
-            if wall_s > 0 else 0.0
         # streamed queries re-upload their morsels every run; in-core
         # queries upload nothing in steady state (device-resident scans)
         upload_bytes[name] = session.last_exec_stats.get("bytes_uploaded", 0)
@@ -336,8 +325,7 @@ def main(argv=None) -> None:
                 result_hash(t_last) == ev.pop("hash_before")
         log.info(f"{name}: device {jax_ms[name]:.1f} ms, "
                  f"oracle {np_ms[name]:.1f} ms, mode {exec_modes[name]}, "
-                 f"upload {upload_bytes[name] / 1e6:.2f} MB, "
-                 f"attribution {attribution[name]:.0%}")
+                 f"upload {upload_bytes[name] / 1e6:.2f} MB")
 
     total_jax = sum(jax_ms.values())
     total_np = sum(np_ms.values())
@@ -358,15 +346,6 @@ def main(argv=None) -> None:
         if args.mesh_record:
             _write_mesh_record(args.mesh_record, mesh_scaling, units)
             log.info("mesh scaling record: %s", args.mesh_record)
-    # per-program device-time attribution: the sorted top-programs table
-    # (per-program roofline fractions from cost_analysis bytes) replaces
-    # the single global roofline_frac as the kernel-work shopping list;
-    # mesh scaling runs add their per-shard-count morsel/gather programs
-    # (labels "<q>/morsel:<table>@mesh<n>" / "<q>/gather:<table>@mesh<n>"),
-    # so the table widens to keep them visible
-    device_time_programs = PROGRAMS.table(
-        bw_gbps=bw_gbps, top=15 + (8 * len(mesh_counts) if mesh_counts
-                                   else 0))
     out = {
         "schema_version": 4,
         # the device every number below was taken on, as JAX reports it
@@ -399,10 +378,6 @@ def main(argv=None) -> None:
         # platform mode, and the degradation reason when the XLA lowering
         # served despite the flag)
         "pallas": _pallas_summary(config, session),
-        # fraction of each query's timed wall the per-program device times
-        # explain (acceptance: >= 0.9)
-        "attribution_frac": attribution,
-        "device_time_programs": device_time_programs,
         # uniform engine counters (obs.metrics): every layer writes through
         # one registry, every report reads the same names
         "metrics": METRICS.snapshot(),
@@ -443,7 +418,6 @@ def main(argv=None) -> None:
         QUERY_LOG.flush()
         out["query_log"] = args.query_log
     if args.trace:
-        from nds_tpu.obs.device_time import format_table
         trace_dir = args.trace_dir or BENCH_DIR
         out["trace_file"] = TRACER.write_chrome_trace(
             os.path.join(trace_dir, f"bench_trace_sf{SCALE}.json"))
@@ -453,8 +427,6 @@ def main(argv=None) -> None:
         # file expands on (open trace_file in ui.perfetto.dev)
         out["spans"] = TRACER.aggregate()
         log.info("trace: %s (open in ui.perfetto.dev)", out["trace_file"])
-        log.info("top programs by device time:\n%s",
-                 format_table(device_time_programs))
     print(json.dumps(out))
 
 

@@ -34,7 +34,7 @@ from .summary import ProgramSummary
 #: ALL_CAPS constants whose inc/add/set/observe-shaped methods are NOT
 #: metric writes (trackers/recorders that share the verb vocabulary)
 NON_METRIC_CONSTS = frozenset({
-    "DEVICE_MEM", "FLIGHT", "TRACER", "METRICS", "PROGRAMS",
+    "DEVICE_MEM", "FLIGHT", "TRACER", "METRICS",
 })
 
 #: fallback when the gate module cannot be parsed for its own constant

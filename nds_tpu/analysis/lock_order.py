@@ -32,7 +32,7 @@ The declared hierarchy (outer acquired first, LOWER level number):
   44  ``ShardedMorselQuery._lock`` — sharded stream bookkeeping
   50  leaf stores: ``ResultCache._lock``, ``FeedbackStore._lock``,
       ``QueryLog._lock``, ``FaultRegistry._lock``, ``CircuitBreaker._lock``,
-      ``ProgramRegistry._lock``, ``DeviceMemTracker._lock``,
+      ``DeviceMemTracker._lock``,
       ``resilience._ABANDONED_LOCK``
   55  observability sinks callable from under any leaf store:
       ``FlightRecorder._lock``, ``Tracer._lock``
@@ -77,7 +77,6 @@ CONST_CLASS_HINTS = {
     "TRACER": "Tracer",
     "METRICS": "MetricsRegistry",
     "QUERY_LOG": "QueryLog",
-    "PROGRAMS": "ProgramRegistry",
     "DEVICE_MEM": "DeviceMemTracker",
 }
 
@@ -107,7 +106,6 @@ LOCK_LEVELS = {
     "QueryLog._lock": 50,
     "FaultRegistry._lock": 50,
     "CircuitBreaker._lock": 50,
-    "ProgramRegistry._lock": 50,
     "DeviceMemTracker._lock": 50,
     "resilience._ABANDONED_LOCK": 50,
     "FlightRecorder._lock": 55,

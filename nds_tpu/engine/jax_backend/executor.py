@@ -22,7 +22,8 @@ backend for that node only (eager mode; such plans are never compiled).
 from __future__ import annotations
 
 import dataclasses
-import os
+import hashlib
+import re
 import threading
 from typing import Callable, Optional
 
@@ -32,7 +33,6 @@ import numpy as np
 from jax import lax
 
 from ...obs import metrics as _metrics
-from ...obs.device_time import PROGRAMS as _PROGRAMS
 from ...obs.trace import TRACER
 from ..column import Table, dec_scale, is_dec
 from ..executor import Executor as HostExecutor
@@ -50,6 +50,10 @@ from .device import (DCol, DTable, PackedTable, bucket, decode_col,
                      widen_col)
 
 _I32 = jnp.int32
+
+# XLA's compile and persistent-cache events count into METRICS from the
+# first program on, eager host kernels of the record pass included
+_metrics.install_xla_counters()
 
 
 class NotJittable(Exception):
@@ -73,10 +77,40 @@ _NOJIT_ERRORS = (NotJittable, NotImplementedError,
                  jax.errors.TracerArrayConversionError,
                  jax.errors.ConcretizationTypeError)
 
-#: force cost_analysis capture on the jit (no-AOT) path even without
-#: tracing — one extra lower+compile per program, on its first sighting
-_COST_ANALYSIS = os.environ.get(
-    "NDS_TPU_COST_ANALYSIS", "").lower() in ("1", "true", "yes", "on")
+
+def program_name(label: str, fingerprint: Optional[str] = None) -> str:
+    """The name a plan program is jitted under: ``nds_<query>_<unit>``
+    (``[A-Za-z0-9_]``, at most 64 characters). JAX calls the HLO module
+    ``jit_<name>``, so the device trace's ``XLA Modules`` line, the compile
+    cache's file names and every instruction's ``op_name``
+    (``jit(nds_query9_root)/.../AggregateNode#3/agg_apply/...``) carry it.
+
+    ``label`` is the program's ``<query>/<unit>`` label. Where the query
+    part is no name a caller gave but ``Session._auto_label``'s hash of
+    the SQL text, pass the parameterized plan's ``fingerprint`` and it
+    takes the query's place: one hoisted program then has one name for
+    every literal. The module name is part of JAX's compile-cache key, so
+    nothing that differs between two processes running the same statement
+    may enter it — no ``id()``, no counter, no stream number, no
+    parameter value."""
+    query, _, unit = label.partition("/")
+    unit = unit or "root"           # an unsegmented statement is its root
+    if fingerprint:
+        query = "plan" + fingerprint[:12]
+    name = "nds_" + re.sub(r"[^A-Za-z0-9]+", "_",
+                           f"{query}_{unit}").strip("_")
+    if len(name) > 64:
+        name = name[:55] + "_" + hashlib.sha1(name.encode()).hexdigest()[:8]
+    return name
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name`` for ``jax.jit``, which takes the module's name
+    from the function's ``__name__`` (a bound method cannot be renamed)."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 class _Recorder:
@@ -191,7 +225,6 @@ def shared_fingerprint(pplan, shard_min_rows: int,
     the device-lane executor from its worker threads) computes the same key
     the executor publishes under: plan structure + the compile-relevant
     engine configuration (x64 tier, shard threshold, kernel choice)."""
-    import hashlib
     x64 = jax.config.read("jax_enable_x64")
     body = _plan_fingerprint(pplan)
     pk = ",".join(sorted(pallas_ops))
@@ -249,7 +282,8 @@ class CompiledQuery:
                  mesh=None, param_dtypes: tuple = (),
                  shard_min_rows: int = 1 << 18, label: str = "",
                  pallas_ops: frozenset = frozenset(),
-                 decision_nodes: Optional[tuple] = None):
+                 decision_nodes: Optional[tuple] = None,
+                 name_fingerprint: Optional[str] = None):
         self.plan = plan
         self.decisions = decisions
         self.scan_keys = scan_keys
@@ -263,15 +297,15 @@ class CompiledQuery:
         # the kernel choice is part of the program's identity: replay must
         # trace the same pallas/XLA sides the recording executor took
         self.pallas_ops = frozenset(pallas_ops)
-        # device-time attribution key (obs.device_time): "<query>/<unit>";
-        # every run's measured dispatch wall accumulates under it, and the
-        # jax.profiler annotation carries it into hardware profiles
+        # "<query>/<unit>": the label of this program's spans and host
+        # annotations, and (through program_name) of its HLO module on the
+        # device trace. name_fingerprint: see program_name
         self.label = label or "program"
+        self.module_name = program_name(self.label, name_fingerprint)
         self._fn = None
         self._aot = None     # AOT executable from precompile()
         self._aot_specs = None  # flat (shape, dtype) list the AOT was lowered for
         self._aot_arg_specs = None  # per-argument [(label, specs)] for reports
-        self._cost_recorded = False  # cost_analysis captured once per program
         # _SHARED_PROGRAMS hands one CompiledQuery to every stream of a
         # template: concurrent multi-stream runs must not race the lazy
         # _fn/_aot initialization (ADVICE r5)
@@ -288,17 +322,7 @@ class CompiledQuery:
                          mesh=self.mesh, params=params,
                          shard_min_rows=self.shard_min_rows,
                          pallas_ops=self.pallas_ops)
-        if isinstance(self.plan, (list, tuple)):
-            outs = []
-            for p in self.plan:
-                # memo resets between member plans, mirroring the per-plan
-                # record passes (record_plans) so both consume the shared
-                # decision schedule identically
-                ex._memo = {}
-                outs.append(ex.execute(p))
-            out = tuple(outs)
-        else:
-            out = ex.execute(self.plan)
+        out = ex.replay(self.plan)
         if rec.idx != len(rec.decisions):
             raise NotJittable("decision schedule length drift")
         if ex.fallback_nodes:
@@ -339,7 +363,7 @@ class CompiledQuery:
         FAULTS.fire("jax.compile")
         with self._lock:
             if self._fn is None:
-                self._fn = jax.jit(self._trace)
+                self._fn = jax.jit(_named(self._trace, self.module_name))
             fn = self._fn
         params = tuple(jax.ShapeDtypeStruct((), phys_dtype(d))
                        for d in self.param_dtypes)
@@ -347,25 +371,12 @@ class CompiledQuery:
         with TRACER.span("compile", cat="compile", label=self.label):
             aot = fn.lower(scan_specs, params).compile()
         _metrics.COMPILES.inc()
-        self._record_cost(aot)
         with self._lock:
             self._aot = aot
             self._aot_specs = self._flat_specs((scan_specs, params))
             self._aot_arg_specs = self._arg_spec_table(scan_specs, params)
         if stats is not None:
             stats["precompile_s"] = round(_time.perf_counter() - t0, 3)
-
-    def _record_cost(self, compiled) -> None:
-        """Attach the program's static cost_analysis() FLOPs/bytes to the
-        device-time registry ONCE — the per-program roofline denominator.
-        Best-effort: cost data enriches attribution, never fails a run."""
-        if self._cost_recorded:
-            return
-        try:
-            _PROGRAMS.record_cost(self.label, compiled.cost_analysis())
-            self._cost_recorded = True
-        except Exception:
-            self._cost_recorded = True   # unsupported backend: don't retry
 
     @staticmethod
     def _flat_specs(tree) -> Optional[list]:
@@ -452,64 +463,74 @@ class CompiledQuery:
             first = self._fn is None
             if first:
                 FAULTS.fire("jax.compile")
-                self._fn = jax.jit(self._trace)
+                self._fn = jax.jit(_named(self._trace, self.module_name))
             fn, aot = self._fn, self._aot
         if first:
             _metrics.COMPILES.inc()   # jit path compiles inside the call
         FAULTS.fire("jax.execute")
-        # attribution boundary (the Flare lesson): the compiled-program
-        # dispatch is the unit device time is measured at; the jax.profiler
-        # annotation carries the same label into hardware profiles
+        # the compiled-program dispatch is the unit the host's side of
+        # device time is measured at (the Flare lesson): exec = exec.args
+        # (build and check the arguments) + exec.wait (the call, and with
+        # the tracer on the wait until the outputs are ready) + exec.fetch
+        # (device-to-host copy, schedule check). The device's side is the
+        # module's row on the profiler's XLA Modules line
         with TRACER.span("exec", cat="device", label=self.label,
                          first=first):
             t1 = _time.perf_counter()
-            args = self._args(scans, values)
-            if aot is not None and not self._specs_match(args):
-                # shape/dtype drift against the precompiled specs: take the
-                # jit path explicitly (the persistent compile cache still
-                # serves the binary when the lowering matches) instead of
-                # letting the AOT call fail and masking the error class.
-                # The per-argument expected-vs-got report lands in stats so
-                # the drift is attributable to a specific scan/param, not a
-                # bare mismatch.
-                if stats is not None:
-                    report = self.spec_mismatch_report(scans, values)
-                    if report:
-                        stats["spec_mismatch"] = report
-                with self._lock:
-                    if self._aot is aot:
-                        self._aot = None
-                aot = None
+            with TRACER.span("exec.args", cat="device"):
+                args = self._args(scans, values)
+                if aot is not None and not self._specs_match(args):
+                    # shape/dtype drift against the precompiled specs: take
+                    # the jit path explicitly (the persistent compile cache
+                    # still serves the binary when the lowering matches)
+                    # instead of letting the AOT call fail and masking the
+                    # error class. The per-argument expected-vs-got report
+                    # lands in stats so the drift is attributable to a
+                    # specific scan/param, not a bare mismatch.
+                    if stats is not None:
+                        report = self.spec_mismatch_report(scans, values)
+                        if report:
+                            stats["spec_mismatch"] = report
+                    with self._lock:
+                        if self._aot is aot:
+                            self._aot = None
+                    aot = None
             with jax.profiler.TraceAnnotation(self.label):
-                if aot is not None:
-                    try:
-                        out, checks = aot(*args)
-                    except (TypeError, ValueError) as aot_err:
-                        # drift the shape check cannot see (committed-device
-                        # / sharding mismatch). Retry via jit once; a jit
-                        # failure of the SAME class is a genuine runtime
-                        # error — re-raise it with the AOT error as explicit
-                        # context instead of swallowing the original.
-                        with self._lock:
-                            if self._aot is aot:
-                                self._aot = None
+                with TRACER.span("exec.wait", cat="device"):
+                    if aot is not None:
                         try:
-                            out, checks = fn(*args)
-                        except type(aot_err):
-                            raise aot_err
-                else:
-                    out, checks = fn(*args)
-                # ONE device_get for result + checks: every transfer
-                # synchronises with the device, so piecemeal np.asarray
-                # would dominate. keep_device (segment outputs feeding
-                # downstream programs): only the check scalars come back.
-                if keep_device:
-                    checks_host = jax.device_get(checks)
-                    out_host = out
-                else:
-                    out_host, checks_host = jax.device_get((out, checks))
-            t2 = _time.perf_counter()
-        _verify_schedule(self.decisions, checks_host)
+                            out, checks = aot(*args)
+                        except (TypeError, ValueError) as aot_err:
+                            # drift the shape check cannot see (committed-
+                            # device / sharding mismatch). Retry via jit
+                            # once; a jit failure of the SAME class is a
+                            # genuine runtime error — re-raise it with the
+                            # AOT error as explicit context instead of
+                            # swallowing the original.
+                            with self._lock:
+                                if self._aot is aot:
+                                    self._aot = None
+                            try:
+                                out, checks = fn(*args)
+                            except type(aot_err):
+                                raise aot_err
+                    else:
+                        out, checks = fn(*args)
+                    if TRACER.enabled:
+                        jax.block_until_ready((out, checks))
+                with TRACER.span("exec.fetch", cat="device"):
+                    # ONE device_get for result + checks: every transfer
+                    # synchronises with the device, so piecemeal np.asarray
+                    # would dominate. keep_device (segment outputs feeding
+                    # downstream programs): only the check scalars come
+                    # back.
+                    if keep_device:
+                        checks_host = jax.device_get(checks)
+                        out_host = out
+                    else:
+                        out_host, checks_host = jax.device_get((out, checks))
+                    t2 = _time.perf_counter()
+                    _verify_schedule(self.decisions, checks_host)
         if stats is not None:
             checks_int = [int(c) for c in checks_host]
             if "decision_rows" in stats:
@@ -526,17 +547,6 @@ class CompiledQuery:
                 if rows:
                     stats["node_rows"] = rows
         device_ms = round((t2 - t1) * 1000, 3)
-        _PROGRAMS.record_run(self.label, device_ms, first=first)
-        if aot is not None:
-            self._record_cost(aot)      # cheap: executable already built
-        elif first and (TRACER.enabled or _COST_ANALYSIS):
-            # jit path keeps no public handle on its executable: re-lower
-            # once (host-side, paid on the untimed compile+run sighting
-            # only, and only when attribution is wanted) to pull FLOPs/bytes
-            try:
-                self._record_cost(fn.lower(*args).compile())
-            except Exception:
-                self._cost_recorded = True
         if stats is not None:
             stats.update(mode="compile+run" if first else "compiled",
                          device_ms=device_ms)
@@ -568,6 +578,7 @@ class BatchedQuery:
         self.cq = cq
         self.cap = cap
         self.label = f"{cq.label}@batch{cap}"
+        self.module_name = f"{cq.module_name[:56]}_batch{cap}"
         self._fn = None
         self._lock = threading.Lock()
 
@@ -577,36 +588,7 @@ class BatchedQuery:
             return out, tuple(checks)
         return lax.map(one, stacked)
 
-    def run(self, scans: dict, rows: list,
-            stats: Optional[dict] = None) -> list:
-        """Run ``rows`` (parameter-value tuples, len <= cap) in ONE
-        dispatch; returns one HOST-side DTable per row (numpy leaves —
-        device_get happens once for the whole stacked output)."""
-        import time as _time
-
-        from ...resilience import FAULTS
-        dts = self.cq.param_dtypes
-        full = list(rows) + [rows[-1]] * (self.cap - len(rows))
-        stacked = tuple(
-            jnp.asarray([r[j] for r in full], dtype=phys_dtype(d))
-            for j, d in enumerate(dts))
-        scan_tuple = tuple(scans[k] for k in self.cq.scan_keys)
-        with self._lock:
-            first = self._fn is None
-            if first:
-                FAULTS.fire("jax.compile")
-                self._fn = jax.jit(self._trace)
-            fn = self._fn
-        if first:
-            _metrics.COMPILES.inc()
-        FAULTS.fire("jax.execute")
-        with TRACER.span("exec", cat="device", label=self.label,
-                         first=first, batch=len(rows)):
-            t1 = _time.perf_counter()
-            with jax.profiler.TraceAnnotation(self.label):
-                out, checks = fn(scan_tuple, stacked)
-                out_host, checks_host = jax.device_get((out, checks))
-            t2 = _time.perf_counter()
+    def _verify(self, checks_host) -> None:
         for (kind, planned), actual in zip(self.cq.decisions, checks_host):
             a = np.asarray(actual)
             if kind == "cap":
@@ -618,8 +600,44 @@ class BatchedQuery:
                 raise ReplayMismatch(
                     f"batched exact decision drift: {a.tolist()} != "
                     f"{planned}")
+
+    def run(self, scans: dict, rows: list,
+            stats: Optional[dict] = None) -> list:
+        """Run ``rows`` (parameter-value tuples, len <= cap) in ONE
+        dispatch; returns one HOST-side DTable per row (numpy leaves —
+        device_get happens once for the whole stacked output)."""
+        import time as _time
+
+        from ...resilience import FAULTS
+        with self._lock:
+            first = self._fn is None
+            if first:
+                FAULTS.fire("jax.compile")
+                self._fn = jax.jit(_named(self._trace, self.module_name))
+            fn = self._fn
+        if first:
+            _metrics.COMPILES.inc()
+        FAULTS.fire("jax.execute")
+        with TRACER.span("exec", cat="device", label=self.label,
+                         first=first, batch=len(rows)):
+            t1 = _time.perf_counter()
+            with TRACER.span("exec.args", cat="device"):
+                dts = self.cq.param_dtypes
+                full = list(rows) + [rows[-1]] * (self.cap - len(rows))
+                stacked = tuple(
+                    jnp.asarray([r[j] for r in full], dtype=phys_dtype(d))
+                    for j, d in enumerate(dts))
+                scan_tuple = tuple(scans[k] for k in self.cq.scan_keys)
+            with jax.profiler.TraceAnnotation(self.label):
+                with TRACER.span("exec.wait", cat="device"):
+                    out, checks = fn(scan_tuple, stacked)
+                    if TRACER.enabled:
+                        jax.block_until_ready((out, checks))
+                with TRACER.span("exec.fetch", cat="device"):
+                    out_host, checks_host = jax.device_get((out, checks))
+                    t2 = _time.perf_counter()
+                    self._verify(checks_host)
         device_ms = round((t2 - t1) * 1000, 3)
-        _PROGRAMS.record_run(self.label, device_ms, first=first)
         if stats is not None:
             stats.update(mode="batched", device_ms=device_ms,
                          batch=len(rows))
@@ -692,6 +710,13 @@ class JaxExecutor:
         # recorded during the run inherit "<label>/<unit>" program labels
         # for device-time attribution
         self.query_label: str = ""
+        # the label is Session._auto_label's hash of the SQL text, not a
+        # name the caller gave: programs are then named from the
+        # parameterized plan's fingerprint (program_name)
+        self.query_label_auto: bool = False
+        # replay only: {id(node): "TypeName#k"} (verify.node_labels) of the
+        # plan being traced, the named scopes execute() opens
+        self._scope_labels: dict = {}
         # SPMD execution: with a mesh, fact-sized scans upload row-sharded
         # (NamedSharding over the first axis); GSPMD partitions the compiled
         # whole-plan program and inserts the collectives (the Spark-shuffle
@@ -960,9 +985,10 @@ class JaxExecutor:
                 self._scan_cache_rec.pop(old, None)
 
     def _unit_label(self, key) -> str:
-        """Attribution label for a compile unit: "<query>/<unit>" — the key
-        the device-time registry ranks programs by (segments keep a short
-        fingerprint so q14/q23-style shared CTEs stay distinguishable)."""
+        """Label of a compile unit: "<query>/<unit>" — what its spans, its
+        host annotations and (through program_name) its HLO module are
+        called (segments keep a short fingerprint so q14/q23-style shared
+        CTEs stay distinguishable)."""
         base = self.query_label or "query"
         if isinstance(key, tuple) and len(key) == 2 and \
                 isinstance(key[1], str):
@@ -970,7 +996,28 @@ class JaxExecutor:
                 return f"{base}/{key[1][:12]}"
             if key[1] == "root":
                 return f"{base}/root"
+            if key[0] == "stream-incore":    # Session._incore_partial
+                return f"{base}/incore:{key[1][:8]}"
         return base
+
+    def name_fingerprint(self, plan) -> Optional[str]:
+        """What names ``plan``'s program in the query label's place
+        (program_name): its fingerprint where the label is the hash of the
+        SQL text, None where the caller gave the label."""
+        if not self.query_label_auto:
+            return None
+        return _plan_fingerprint(plan, mat_by_identity=False)
+
+    def _new_cq(self, key, ent) -> CompiledQuery:
+        """The program of a recorded (or adopted) plan entry."""
+        return CompiledQuery(ent["plan"], ent["decisions"],
+                             ent["scan_keys"], mesh=self._mesh,
+                             param_dtypes=ent.get("param_dtypes", ()),
+                             shard_min_rows=self._shard_min_rows,
+                             label=ent.get("label", self._unit_label(key)),
+                             pallas_ops=self._pallas_ops,
+                             decision_nodes=ent.get("decision_nodes"),
+                             name_fingerprint=ent.get("name_fp"))
 
     def _run_unit(self, key, plan, keep_device: bool = False) -> DTable:
         """One compile unit through the record -> compile -> replay
@@ -1001,14 +1048,7 @@ class JaxExecutor:
                         f"nojit: {ent['nojit_reason']}")
                 return self._eager_ent(ent)
             else:                                      # second sighting
-                cq = CompiledQuery(ent["plan"], ent["decisions"],
-                                   ent["scan_keys"], mesh=self._mesh,
-                                   param_dtypes=ent.get("param_dtypes", ()),
-                                   shard_min_rows=self._shard_min_rows,
-                                   label=ent.get("label",
-                                                 self._unit_label(key)),
-                                   pallas_ops=self._pallas_ops,
-                                   decision_nodes=ent.get("decision_nodes"))
+                cq = self._new_cq(key, ent)
                 try:
                     out = self._run_compiled(cq, ent, keep_device)
                     ent["cq"] = cq
@@ -1053,7 +1093,8 @@ class JaxExecutor:
                 "params": tuple(pvalues), "param_dtypes": tuple(pdtypes),
                 "decision_nodes": nodes_attr,
                 "cq": None, "nojit": len(self.fallback_nodes) > fb0,
-                "fp": fp, "label": self._unit_label(key)}
+                "fp": fp, "label": self._unit_label(key),
+                "name_fp": self.name_fingerprint(pplan)}
             self._publish_recorded(ent)
             self._plans[key] = ent
             self._fp_block = None
@@ -1097,7 +1138,8 @@ class JaxExecutor:
                    "scan_keys": sh["scan_keys"], "params": pvalues,
                    "param_dtypes": pdtypes, "cq": sh.get("cq"),
                    "decision_nodes": sh.get("decision_nodes"),
-                   "nojit": False, "fp": fp}
+                   "nojit": False, "fp": fp,
+                   "name_fp": self.name_fingerprint(sh["plan"])}
             scan_meta = dict(sh["scan_meta"])
         for k, v in scan_meta.items():
             self._scan_meta.setdefault(k, v)
@@ -1270,14 +1312,7 @@ class JaxExecutor:
             specs = self._scan_specs(ent)
             if specs is None:
                 continue
-            cq = CompiledQuery(ent["plan"], ent["decisions"],
-                               ent["scan_keys"], mesh=self._mesh,
-                               param_dtypes=ent.get("param_dtypes", ()),
-                               shard_min_rows=self._shard_min_rows,
-                               label=ent.get("label", self._unit_label(k)),
-                               pallas_ops=self._pallas_ops,
-                               decision_nodes=ent.get("decision_nodes"))
-            todo.append((k, ent, cq, specs))
+            todo.append((k, ent, self._new_cq(k, ent), specs))
         if not todo:
             return {}
         workers = max_workers or int(_os.environ.get(
@@ -1501,8 +1536,15 @@ class JaxExecutor:
             return self._memo[key]
         prev_node = self._cur_node
         self._cur_node = node
+        scope = self._scope_labels.get(key)
         try:
-            result = self._run(node)
+            if scope is None:
+                result = self._run(node)
+            else:
+                # trace time only: every instruction of this node carries
+                # ".../TypeName#k/<kernel>/..." as its op_name
+                with jax.named_scope(scope):
+                    result = self._run(node)
         except NotImplementedError as e:
             if self._replay:
                 raise
@@ -1512,6 +1554,25 @@ class JaxExecutor:
             self._cur_node = prev_node
         self._memo[key] = result
         return result
+
+    def replay(self, plan):
+        """Trace ``plan`` (or the member plans of a fused morsel group, in
+        order under the one decision schedule) with a named scope per plan
+        node; a replay executor's only entry point."""
+        from ..verify import node_labels
+        if not isinstance(plan, (list, tuple)):
+            self._scope_labels = node_labels(plan)
+            return self.execute(plan)
+        outs = []
+        for i, p in enumerate(plan):
+            # memo resets between member plans, mirroring the per-plan
+            # record passes (record_plans) so both consume the shared
+            # decision schedule identically
+            self._memo = {}
+            self._scope_labels = node_labels(p)
+            with jax.named_scope(f"member{i}"):
+                outs.append(self.execute(p))
+        return tuple(outs)
 
     def execute_to_host(self, node: PlanNode) -> Table:
         return to_host(self.execute(node))
@@ -2822,20 +2883,21 @@ class JaxExecutor:
 
 # -- plan utilities -----------------------------------------------------------
 
-def _plan_fingerprint(node) -> str:
+def _plan_fingerprint(node, mat_by_identity: bool = True) -> str:
     """Stable structural hash of a plan subtree (for executor-synthesized
     segment keys; CTE segments use planner AST fingerprints instead). Two
     structurally identical subtrees — including literals, so stream-
     parameterized plans never collide — share a segment cache slot.
-    MaterializedNodes hash by identity (callers exclude them)."""
+    MaterializedNodes hash by identity (callers exclude them), or, for a
+    program's name, which no ``id()`` may enter, by label and schema."""
     import dataclasses as _dc
-    import hashlib
 
     parts: list[str] = []
 
     def rec(x):
         if isinstance(x, MaterializedNode):
-            parts.append(f"mat:{id(x)}")
+            parts.append(f"mat:{id(x)}" if mat_by_identity else
+                         f"mat:{x.label}:{x.out_names}:{x.out_dtypes}")
             return
         if isinstance(x, np.ndarray):
             # repr truncates long arrays -> collision risk; hash content

@@ -30,6 +30,10 @@ def _iota(n: int) -> jax.Array:
     return jnp.arange(n, dtype=_I32)
 
 
+#: every public lowering below opens a named scope under its own name
+_scoped = _pk.scoped
+
+
 # ---------------------------------------------------------------------------
 # Pallas dispatch seams (ISSUE 7): each helper swaps in the hand-tiled
 # pallas_kernels implementation when its op flag is active for the in-flight
@@ -49,6 +53,7 @@ def _sort1(key: jax.Array, idx: jax.Array) -> tuple[jax.Array, jax.Array]:
     return lax.sort((key, idx), num_keys=1, is_stable=True)
 
 
+@_scoped
 def gather_many(arrays: list, idx: jax.Array) -> list:
     """Batched same-index gather (multi-column join/late-mat shape): one
     VMEM-staged pallas pass over all stageable columns when "gather" is
@@ -85,6 +90,7 @@ def _seg_multi(pairs: list, gid: jax.Array, num_segments: int) -> list:
 # factorize: joint dense ranking of key tuples
 # ---------------------------------------------------------------------------
 
+@_scoped
 def dense_rank(key_data: list[jax.Array], key_valid: list[jax.Array],
                alive: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Assign each alive row a dense group id over its key tuple.
@@ -118,6 +124,7 @@ def dense_rank(key_data: list[jax.Array], key_valid: list[jax.Array],
     return _gid_from_sorted(new_group, alive_sorted, perm, n)
 
 
+@_scoped
 def unscatter(perm: jax.Array, values: tuple) -> tuple:
     """Undo a permutation WITHOUT scatter: sort by `perm` (which is a
     permutation of 0..n-1, so sorting restores original row order) carrying
@@ -204,6 +211,7 @@ def _sat_product(ranges: list[jax.Array], cap: int) -> jax.Array:
     return p
 
 
+@_scoped
 def group_tier(key_data: list[jax.Array], key_valid: list[jax.Array],
                alive: jax.Array) -> jax.Array:
     """Traced packability decision: 1 = the key tuple packs into one
@@ -235,6 +243,7 @@ def _pack_keys(key_data: list[jax.Array], key_valid: list[jax.Array],
     return c
 
 
+@_scoped
 def dense_rank_packsort(key_data: list[jax.Array], key_valid: list[jax.Array],
                         alive: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Tier-2 dense_rank: single packed-key sort (one operand vs 2K+2)."""
@@ -252,11 +261,13 @@ def dense_rank_packsort(key_data: list[jax.Array], key_valid: list[jax.Array],
 # filter / compact / limit
 # ---------------------------------------------------------------------------
 
+@_scoped
 def filter_alive(alive: jax.Array, mask_data: jax.Array,
                  mask_valid: jax.Array) -> jax.Array:
     return alive & mask_data.astype(bool) & mask_valid
 
 
+@_scoped
 def compaction_perm(alive: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Stable permutation bringing alive rows to the front; returns
     (perm, count). Sort-based: a 2-operand lax.sort measures ~60x cheaper
@@ -267,6 +278,7 @@ def compaction_perm(alive: jax.Array) -> tuple[jax.Array, jax.Array]:
     return perm, jnp.sum(alive.astype(_I32))
 
 
+@_scoped
 def limit_alive(alive: jax.Array, n_keep: int) -> jax.Array:
     """Keep the first `n_keep` alive rows in physical order."""
     pos = jnp.cumsum(alive.astype(_I32)) - 1
@@ -277,6 +289,7 @@ def limit_alive(alive: jax.Array, n_keep: int) -> jax.Array:
 # sort
 # ---------------------------------------------------------------------------
 
+@_scoped
 def sort_perm(key_data: list[jax.Array], key_valid: list[jax.Array],
               key_specs: tuple, alive: jax.Array) -> jax.Array:
     """Permutation realizing Spark ORDER BY semantics; dead rows go last.
@@ -350,6 +363,7 @@ def _seg(data: jax.Array, gid: jax.Array, num_segments: int, op: str) -> jax.Arr
     raise AssertionError(op)
 
 
+@_scoped
 def agg_apply(gid: jax.Array, alive: jax.Array, func: str, arg,
               cap_out: int) -> tuple[jax.Array, jax.Array]:
     """One per-group aggregate. `arg` is a (data, valid) tuple or None.
@@ -426,6 +440,7 @@ def _extreme(dtype, func: str):
                        dtype=dtype)
 
 
+@_scoped
 def group_representatives(gid: jax.Array, alive: jax.Array,
                           data: jax.Array, valid: jax.Array,
                           cap_out: int) -> tuple[jax.Array, jax.Array]:
@@ -444,6 +459,7 @@ def group_representatives(gid: jax.Array, alive: jax.Array,
     return padded_vals[:cap_out], padded_valid[:cap_out]
 
 
+@_scoped
 def distinct_within_group(gid: jax.Array, alive: jax.Array,
                           data: jax.Array, valid: jax.Array
                           ) -> jax.Array:
@@ -463,6 +479,7 @@ def distinct_within_group(gid: jax.Array, alive: jax.Array,
 # sorted aggregation: scans over key-sorted rows instead of segment scatters
 # ---------------------------------------------------------------------------
 
+@_scoped
 def sorted_agg_scan(vals: jax.Array, new_group: jax.Array, op) -> jax.Array:
     """Inclusive within-group scan over KEY-SORTED rows (group totals sit at
     group-end rows). This is the scatter-free replacement for
@@ -471,6 +488,7 @@ def sorted_agg_scan(vals: jax.Array, new_group: jax.Array, op) -> jax.Array:
     return _seg_scan(vals, new_group, op)
 
 
+@_scoped
 def group_ends(new_group: jax.Array, alive_sorted: jax.Array) -> jax.Array:
     """Row mask of each group's LAST alive row in sorted order."""
     n = new_group.shape[0]
@@ -494,6 +512,7 @@ def _seg_scan(vals: jax.Array, new_part: jax.Array, op) -> jax.Array:
     return out
 
 
+@_scoped
 def window_ordered_core(sgid: jax.Array, tie_data: list[jax.Array],
                         tie_valid: list[jax.Array], arg, func: str
                         ) -> tuple[jax.Array, jax.Array]:
@@ -580,6 +599,7 @@ def window_ordered_core(sgid: jax.Array, tie_data: list[jax.Array],
 # joins
 # ---------------------------------------------------------------------------
 
+@_scoped
 def build_side(gid_right: jax.Array, alive_right: jax.Array
                ) -> tuple[jax.Array, jax.Array]:
     """Sort right-side gids (dead rows pushed to +inf); returns (sorted_gid, perm)."""
@@ -587,6 +607,7 @@ def build_side(gid_right: jax.Array, alive_right: jax.Array
     return _sort1(key, _iota(alive_right.shape[0]))
 
 
+@_scoped
 def probe_counts_by_gid(build_gid: jax.Array, build_alive: jax.Array,
                         probe_gid: jax.Array, probe_alive: jax.Array,
                         gid_cap: int) -> tuple[jax.Array, jax.Array]:
@@ -609,6 +630,7 @@ def probe_counts_by_gid(build_gid: jax.Array, build_alive: jax.Array,
     return lo.astype(_I32), cnt.astype(_I32)
 
 
+@_scoped
 def expand_join(lo: jax.Array, cnt: jax.Array, probe_alive: jax.Array,
                 cap_out: int) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Materialize (left_row, build_sorted_pos) pairs for an inner join.

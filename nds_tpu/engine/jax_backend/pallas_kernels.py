@@ -105,6 +105,23 @@ GROUPBY_MIN_ROWS = 1 << 12
 # platform probe + per-executor op activation
 # ---------------------------------------------------------------------------
 
+def scoped(fn):
+    """Run a kernel entry point under ``jax.named_scope(<its name>)``:
+    inside a plan program every instruction it emits then carries
+    ``jit(nds_<query>_<unit>)/.../<TypeName#k>/<kernel>/...`` as its
+    ``op_name``, what a profile groups device time by. A scope is a debug
+    location: it changes no instruction and is stripped from the compile
+    cache's key. (A new context manager per call: ``jax.named_scope`` used
+    as a decorator shares one between the compile pool's threads.)"""
+    name = fn.__name__
+
+    @functools.wraps(fn)
+    def scoped_fn(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+    return scoped_fn
+
+
 class PallasLoweringError(Exception):
     """A requested Pallas kernel cannot run on this backend: no TPU Pallas
     lowering here, or Mosaic refused the kernel. Names the kernel and
@@ -309,6 +326,7 @@ def _sort_call(N: int, B: int, key_dtype: str, merge: bool,
         call, arg_specs)
 
 
+@scoped
 def sort_pairs(key: jax.Array, idx: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Sort (key, idx) pairs ascending by the total order (key, idx).
 
@@ -433,6 +451,7 @@ def seg_supported(data: jax.Array, num_segments: int, op: str) -> bool:
     return op in ("min", "max")
 
 
+@scoped
 def seg_reduce_multi(operands: list, gid: jax.Array,
                      num_segments: int) -> list:
     """Fused segment partials: operands is [(data, op)] with every entry
@@ -460,6 +479,7 @@ def seg_reduce_multi(operands: list, gid: jax.Array,
     return list(out)
 
 
+@scoped
 def seg_reduce(data: jax.Array, gid: jax.Array, num_segments: int,
                op: str) -> jax.Array:
     """Single-operand convenience over ``seg_reduce_multi``."""
@@ -512,6 +532,7 @@ def gather_supported(src: jax.Array) -> bool:
         _src_bytes(src) <= GATHER_SRC_BYTES
 
 
+@scoped
 def take_many(srcs: list, idx: jax.Array) -> list:
     """Gather ``[src[idx] for src in srcs]`` with VMEM-staged sources.
 
@@ -563,6 +584,7 @@ def take_many(srcs: list, idx: jax.Array) -> list:
     return out
 
 
+@scoped
 def take(src: jax.Array, idx: jax.Array) -> jax.Array:
     """Single-column convenience over ``take_many``."""
     return take_many([src], idx)[0]

@@ -17,9 +17,10 @@ parallel/mesh.make_mesh):
   the shard_map boundary is the collective), producing device-local
   partial aggregates. A second compiled program — dist_ops.gather_partials
   — is the morsel's ONE collective: a tiled all_gather of the bounded
-  decomposed partials, measured and attributed separately
-  (`<query>/gather:<table>@mesh<n>`) so collective time and bytes are
-  first-class numbers in the bench scaling record.
+  decomposed partials, with spans of its own
+  (`<query>/gather:<table>@mesh<n>`) and a module of its own on the device
+  trace (`jit_nds_<query>_morsel_<table>_gather` beside `..._local`), so
+  collective time and bytes are first-class numbers.
 
 The host-side final merge is unchanged: gathered per-replica partials are
 just more rows of the same partial schema streaming's _decompose /
@@ -43,14 +44,14 @@ from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...obs import metrics as _metrics
-from ...obs.device_time import PROGRAMS as _PROGRAMS
 from ...obs.trace import TRACER
 from ...parallel.dist_ops import gather_partials
 from ..column import Table
 from ..streaming import partition_morsel_rows
 from .device import (DTable, PackedTable, _pack_payload, bucket,
                      plan_lanes)
-from .executor import JaxExecutor, ReplayMismatch, _no_load, _Recorder
+from .executor import (JaxExecutor, ReplayMismatch, _named, _no_load,
+                       _Recorder, program_name)
 
 
 # -- sharded morsel staging ---------------------------------------------------
@@ -163,7 +164,8 @@ class ShardedMorselQuery:
 
     def __init__(self, plan, decisions: list, scan_keys: tuple, mesh,
                  morsel_key: str, label: str = "",
-                 pallas_ops: frozenset = frozenset()):
+                 pallas_ops: frozenset = frozenset(),
+                 name_fingerprint: Optional[str] = None):
         self.plan = plan
         self.decisions = decisions
         self.scan_keys = tuple(scan_keys)
@@ -175,6 +177,7 @@ class ShardedMorselQuery:
         self.label = f"{base}@mesh{self.n_shards}"
         self.gather_label = base.replace("/morsel:", "/gather:", 1) \
             + f"@mesh{self.n_shards}"
+        self.module_name = program_name(base, name_fingerprint)[:56]
         self._fn = None
         self._gather = None
         self._replicated: dict = {}     # scan key -> (src id, replicated)
@@ -188,14 +191,7 @@ class ShardedMorselQuery:
         ex = JaxExecutor(_no_load, recorder=rec, scan_tables=scans,
                          mesh=None, shard_local=True,
                          pallas_ops=self.pallas_ops)
-        if isinstance(self.plan, (list, tuple)):
-            outs = []
-            for p in self.plan:
-                ex._memo = {}           # per-plan memo reset, like record
-                outs.append(ex.execute(p))
-            out = tuple(outs)
-        else:
-            out = ex.execute(self.plan)
+        out = ex.replay(self.plan)
         if rec.idx != len(rec.decisions):
             raise ReplayMismatch("decision schedule length drift (sharded)")
         if ex.fallback_nodes:
@@ -215,8 +211,9 @@ class ShardedMorselQuery:
         local = shard_map(self._trace_local, mesh=self.mesh,
                           in_specs=(P(axis), P()),
                           out_specs=(P(axis), P(axis)), check_vma=False)
-        self._fn = jax.jit(local)
-        self._gather = jax.jit(gather_partials(self.mesh))
+        self._fn = jax.jit(_named(local, self.module_name + "_local"))
+        self._gather = jax.jit(_named(gather_partials(self.mesh),
+                                      self.module_name + "_gather"))
 
     def _replicate(self, key: str, dt):
         """Commit a dimension-scan table replicated over the mesh once; the
@@ -252,9 +249,6 @@ class ShardedMorselQuery:
         accumulates collective_bytes / collective_ms / local device_ms."""
         from ...resilience import FAULTS
 
-        morsel = scans[self.morsel_key]
-        others = tuple(self._replicate(k, scans[k])
-                       for k in self._other_keys)
         with self._lock:
             first = self._fn is None
             if first:
@@ -266,13 +260,19 @@ class ShardedMorselQuery:
         with TRACER.span("exec", cat="device", label=self.label,
                          first=first, shards=self.n_shards):
             t0 = time.perf_counter()
+            with TRACER.span("exec.args", cat="device"):
+                morsel = scans[self.morsel_key]
+                others = tuple(self._replicate(k, scans[k])
+                               for k in self._other_keys)
             with jax.profiler.TraceAnnotation(self.label):
-                out, checks = self._fn(morsel, others)
-                checks_host = jax.device_get(checks)
-            t1 = time.perf_counter()
-        _PROGRAMS.record_run(self.label, round((t1 - t0) * 1000, 3),
-                             first=first)
-        self._verify(checks_host)
+                with TRACER.span("exec.wait", cat="device"):
+                    out, checks = self._fn(morsel, others)
+                    if TRACER.enabled:
+                        jax.block_until_ready((out, checks))
+                with TRACER.span("exec.fetch", cat="device"):
+                    checks_host = jax.device_get(checks)
+                    t1 = time.perf_counter()
+                    self._verify(checks_host)
         # ONE collective: all_gather of the sharded partial blocks. Bytes
         # model: ring all-gather ingress per device — each replica receives
         # the other n-1 replicas' blocks, (n-1)/n of the gathered total.
@@ -288,8 +288,6 @@ class ShardedMorselQuery:
                 merged = self._gather(out)
                 out_host = jax.device_get(merged)
             t3 = time.perf_counter()
-        _PROGRAMS.record_run(self.gather_label,
-                             round((t3 - t2) * 1000, 3), first=first)
         if stats is not None:
             stats["collective_bytes"] = \
                 stats.get("collective_bytes", 0) + coll_bytes

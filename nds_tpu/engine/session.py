@@ -167,8 +167,12 @@ class Session:
         # and ExecStats.node_stats are built from it
         self._last_stream_profile: Optional[dict] = None
         # label of the in-flight sql() call (runners pass the query name);
-        # compiled programs inherit it for device-time attribution
+        # compiled programs inherit it: their spans, host annotations and
+        # HLO module names carry it. _label_auto: no caller gave it, it is
+        # _auto_label's hash of the SQL text (programs are then named from
+        # their plan's fingerprint, executor.program_name)
         self._active_label: str = ""
+        self._label_auto: bool = False
         # query-log statement context (_sql_locked sets both per call):
         # wall start + whether this statement cuts its own log row
         self._stmt_t0: float = 0.0
@@ -759,7 +763,7 @@ class Session:
             saved = (self.last_fallbacks, self.last_exec_stats,
                      self.last_exec_stats_typed, self.last_profile,
                      self._last_stream_profile, self._active_label,
-                     self._stmt_t0, self._stmt_log)
+                     self._label_auto, self._stmt_t0, self._stmt_log)
             win = DEVICE_MEM.window_peak()
             self._in_preempt = True
             try:
@@ -769,7 +773,7 @@ class Session:
                 (self.last_fallbacks, self.last_exec_stats,
                  self.last_exec_stats_typed, self.last_profile,
                  self._last_stream_profile, self._active_label,
-                 self._stmt_t0, self._stmt_log) = saved
+                 self._label_auto, self._stmt_t0, self._stmt_log) = saved
                 # restore the outer statement's peak window: the nested
                 # statement re-marked it, and the outer stream's
                 # mem_peak_bytes must cover its own whole wall
@@ -872,6 +876,7 @@ class Session:
         self.last_exec_stats = {}
         self.last_exec_stats_typed = None
         self._active_label = label or self._auto_label(query)
+        self._label_auto = not label
         # query-log context for _finish_exec_stats: statement wall start
         # + whether THIS statement cuts its own row (the service logs per
         # ticket instead — richer context, no duplicates)
@@ -892,6 +897,7 @@ class Session:
                         return result
                 jexec = self._jax_executor()
                 jexec.query_label = self._active_label
+                jexec.query_label_auto = self._label_auto
 
                 def factory():
                     if plan is not None:
@@ -920,6 +926,15 @@ class Session:
     def _auto_label(query: str) -> str:
         import hashlib
         return "q" + hashlib.sha1(query.encode()).hexdigest()[:8]
+
+    def _name_fingerprint(self, plans) -> Optional[str]:
+        """For a morsel program's name (executor.program_name): the plans'
+        fingerprint where the statement's label is the hash of its text,
+        None where the caller gave the label."""
+        if not self._label_auto:
+            return None
+        from .jax_backend.executor import _plan_fingerprint
+        return _plan_fingerprint(plans, mat_by_identity=False)
 
     # -- EXPLAIN ANALYZE (obs/profile.py) ------------------------------------
     def _profiled_locked(self, query: str, use_jax: bool) -> Table:
@@ -997,6 +1012,7 @@ class Session:
             from .jax_backend.device import device_bytes
             jexec = self._jax_executor()
             jexec.query_label = self._active_label
+            jexec.query_label_auto = self._label_auto
             jexec.fallback_nodes = []
             jexec._memo = {}
             ctx = _jax.default_device(jexec._eager_device) \
@@ -1222,7 +1238,10 @@ class Session:
                 self._stream_cache.pop(query, None)
             sent = "miss"
         if sent == "miss":
-            plan = Planner(self._catalog()).plan_query(parse_sql(query))
+            with TRACER.span("plan", label=self._active_label):
+                with TRACER.span("parse"):
+                    ast = parse_sql(query)
+                plan = Planner(self._catalog()).plan_query(ast)
             jobs = streaming.find_streaming_jobs(
                 plan, lambda t: self._est_rows_for(t, 0),
                 self.config.out_of_core_min_rows)
@@ -1482,6 +1501,8 @@ class Session:
         from .jax_backend import to_host
         from .jax_backend.executor import _plan_fingerprint
         jexec = shared["jexec"]
+        jexec.query_label = self._active_label
+        jexec.query_label_auto = self._label_auto
         key = ("stream-incore", _plan_fingerprint(branch.partial_plan))
         out = jexec.run_query(key, lambda: branch.partial_plan)
         return to_host(out)
@@ -1609,7 +1630,9 @@ class Session:
                     mesh=jexec._mesh,
                     shard_min_rows=jexec._shard_min_rows,
                     label=f"{self._active_label}/morsel:{group.table}",
-                    pallas_ops=jexec._pallas_ops)]
+                    pallas_ops=jexec._pallas_ops,
+                    name_fingerprint=self._name_fingerprint(
+                        list(group.plans)))]
                 state["ents"] = [{"scan_keys": scan_keys}]
             else:
                 # fusion over budget (or single member): per-member
@@ -1626,7 +1649,8 @@ class Session:
                         shard_min_rows=jexec._shard_min_rows,
                         label=f"{self._active_label}/morsel:"
                               f"{group.table}#{bi}",
-                        pallas_ops=jexec._pallas_ops))
+                        pallas_ops=jexec._pallas_ops,
+                        name_fingerprint=self._name_fingerprint(p)))
                     ents.append({"scan_keys": scan_keys})
                 state["cqs"], state["ents"] = cqs, ents
             state["fused"] = fuse
@@ -1653,7 +1677,9 @@ class Session:
                 state["cqs"] = [ShardedMorselQuery(
                     list(group.plans), decisions, scan_keys, mesh, mkey,
                     label=f"{self._active_label}/morsel:{group.table}",
-                    pallas_ops=ops)]
+                    pallas_ops=ops,
+                    name_fingerprint=self._name_fingerprint(
+                        list(group.plans)))]
                 state["ents"] = [{"scan_keys": scan_keys}]
             else:
                 cqs, ents = [], []
@@ -1668,7 +1694,8 @@ class Session:
                         p, decisions, scan_keys, mesh, mkey,
                         label=f"{self._active_label}/morsel:"
                               f"{group.table}#{bi}",
-                        pallas_ops=ops))
+                        pallas_ops=ops,
+                        name_fingerprint=self._name_fingerprint(p)))
                     ents.append({"scan_keys": scan_keys})
                 state["cqs"], state["ents"] = cqs, ents
             state["fused"] = fuse

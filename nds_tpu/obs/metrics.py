@@ -576,6 +576,15 @@ PROGRAMS_ADOPTED = METRICS.counter(
     "programs_adopted", "cross-stream shared-program adoptions")
 COMPILES = METRICS.counter(
     "compiles", "whole-plan XLA compilations (jit first-run + precompile)")
+XLA_COMPILES = METRICS.counter(  # lint: counter-exempt (counts XLA's events: moves with the compile cache's state and the JAX version, not with the engine's decisions)
+    "xla_compiles", "XLA backend compile events of any program, a host "
+    "kernel of the record pass included; a fetch from the persistent "
+    "cache counts (jax.monitoring backend_compile_duration)")
+XLA_CACHE_HITS = METRICS.counter(  # lint: counter-exempt (counts XLA's events: moves with the compile cache's state and the JAX version, not with the engine's decisions)
+    "xla_cache_hits", "programs JAX's persistent compilation cache served")
+XLA_CACHE_MISSES = METRICS.counter(  # lint: counter-exempt (counts XLA's events: moves with the compile cache's state and the JAX version, not with the engine's decisions)
+    "xla_cache_misses", "programs compiled because the persistent "
+    "compilation cache did not hold them")
 SCAN_PASSES = METRICS.counter(
     "scan_passes", "streamed morsel loops over a big table")
 MORSELS = METRICS.counter(
@@ -806,3 +815,40 @@ SERVICE_MATERIALIZE_HIST = METRICS.histogram(
 QUERY_LATENCY_HIST = METRICS.histogram(
     "query_latency_ms", "timed single-caller query latency distribution "
     "(bench timed runs / power stream, labeled by template)")
+
+
+# -- XLA's own events ---------------------------------------------------------
+
+_XLA_EVENT_COUNTERS = {
+    "/jax/compilation_cache/cache_hits": XLA_CACHE_HITS,
+    "/jax/compilation_cache/cache_misses": XLA_CACHE_MISSES,
+}
+_XLA_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_xla_counters_installed = False
+_xla_install_lock = threading.Lock()
+
+
+def _on_xla_event(event: str, **_kwargs) -> None:
+    c = _XLA_EVENT_COUNTERS.get(event)
+    if c is not None:
+        c.inc()
+
+
+def _on_xla_duration(event: str, _duration_s: float, **_kwargs) -> None:
+    if event == _XLA_COMPILE_EVENT:
+        XLA_COMPILES.inc()
+
+
+def install_xla_counters() -> None:
+    """Count XLA's compile and persistent-cache events through
+    ``jax.monitoring``, once per process however often this is called. The
+    engine's jax-side modules call it when they are imported; this module
+    itself stays free of jax (the front door's clients import it)."""
+    global _xla_counters_installed
+    with _xla_install_lock:
+        if _xla_counters_installed:
+            return
+        _xla_counters_installed = True
+    from jax import monitoring
+    monitoring.register_event_listener(_on_xla_event)
+    monitoring.register_event_duration_secs_listener(_on_xla_duration)

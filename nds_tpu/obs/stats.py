@@ -36,6 +36,10 @@ class ExecStats:
     # -- mode + device timing ------------------------------------------------
     mode: str = ""           # record|compile+run|compiled|eager|adopted|
     #                          streaming (the session's out-of-core path)
+    #: the HOST's wall around one dispatch: arguments, the call, the wait
+    #: and the device-to-host copy (the ``exec`` span's extent). No device
+    #: duration — that is the program's row on the profiler's XLA Modules
+    #: line (``obs.xplane``). The key stays: report schemas read it.
     device_ms: Optional[float] = None
     precompile_s: Optional[float] = None
     nojit_reason: Optional[str] = None

@@ -27,15 +27,60 @@ Design constraints, in order:
 
 Enable per-process with ``configure(enabled=True)`` (runners expose
 ``--trace``) or by exporting ``NDS_TPU_TRACE=1``.
+
+**One picture with the device trace.** While the tracer is on, every span
+used as a context manager also enters a ``jax.profiler.TraceAnnotation``
+named ``nds.<span name>`` (``nds.<span name>:<label>`` where the span has
+a ``label``) on its own thread, so a ``jax.profiler`` trace taken over a
+traced run (``power --trace T --profile_folder P``, the front-door server
+likewise) shows ``nds.plan:query9``, ``nds.exec:query9/root``,
+``nds.morsel.stage``, ``nds.service/lane_idle`` ... on the host threads, on
+the clock of the device lines. Detached spans (``begin()``/``end()`` on two
+threads) are not mirrored. Both exports carry ``clock``: the tracer's
+``perf_counter`` epoch beside ``time.time()`` at the same instant. The
+profiler's clock is that wall clock counted from the ``profile_start_time``
+its ``Task Environment`` plane states, so an xplane event at ``start_ns``
+and a span at ``ts`` are the same instant when ``profile_start_time +
+start_ns == (clock.epoch_unix_s * 1e6 + ts) * 1e3`` (they agree within a
+millisecond; ``scripts/trace_report.py --xplane`` lays them over each
+other).
+
+XLA's own phases arrive through ``jax.monitoring`` while the tracer is on:
+``xla.trace`` (jaxpr tracing), ``xla.lower`` (jaxpr to MLIR) and
+``xla.compile`` (backend compile, or the fetch from the persistent cache),
+each labelled with the function's name (``jit(nds_query9_root)``).
+
+``jax`` is never imported from here: a process that has not imported it
+(the front door's clients) gets spans and no annotations.
+
+Span names (``cat``): ``query`` > ``plan`` > ``parse`` / ``plan.pass`` /
+``plan.verify``; ``record``; ``compile``; ``upload`` / ``lane.pack``;
+``exec`` > ``exec.args`` / ``exec.wait`` / ``exec.fetch``; ``collective``;
+``morsel.stage`` / ``morsel.stage_sharded`` / ``morsel.exec`` /
+``merge.partials`` / ``finalize``; ``system_query``; the service's
+``service/ticket`` > ``service/queue`` / ``service/plan`` /
+``service/lane_wait`` / ``service/dispatch`` / ``service/materialize`` /
+``frontdoor/reply`` and, on the lane thread, ``service/lane_idle``.
 """
 from __future__ import annotations
 
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Optional
+
+#: prefix of the ``jax.profiler.TraceAnnotation`` that mirrors a span
+ANNOTATION_PREFIX = "nds."
+
+#: ``jax.monitoring`` duration events recorded as spans while the tracer is on
+XLA_EVENT_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "xla.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "xla.lower",
+    "/jax/core/compile/backend_compile_duration": "xla.compile",
+}
 
 
 class _NullSpan:
@@ -77,7 +122,7 @@ class Span:
     device lane at completion, with every stage span parent-linked to it
     through the explicit ``parent=`` override."""
     __slots__ = ("name", "cat", "attrs", "sid", "parent", "tid", "_t0",
-                 "_tracer", "_parent_override", "_detached")
+                 "_tracer", "_parent_override", "_detached", "_note")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, attrs: dict,
                  parent: Optional[int] = None):
@@ -91,6 +136,7 @@ class Span:
         self._t0 = 0.0
         self._parent_override = parent
         self._detached = False
+        self._note = None       # the mirroring profiler annotation
 
     def set(self, **attrs) -> "Span":
         """Attach attributes discovered mid-span (rows, bytes, mode...)."""
@@ -112,6 +158,12 @@ class Span:
             is not None else (stack[-1] if stack else 0)
         self._open()
         stack.append(self.sid)
+        note = tr._annotation()
+        if note is not None:
+            label = self.attrs.get("label")
+            self._note = note(f"{ANNOTATION_PREFIX}{self.name}:{label}"
+                              if label else ANNOTATION_PREFIX + self.name)
+            self._note.__enter__()
         return self
 
     def begin(self) -> "Span":
@@ -147,6 +199,9 @@ class Span:
             tr._events.append(event)
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
+            self._note = None
         stack = self._tracer._stack()
         if stack and stack[-1] == self.sid:
             stack.pop()
@@ -165,6 +220,9 @@ class Tracer:
         self._ids = itertools.count(1)
         self._tls = threading.local()
         self._epoch = time.perf_counter()
+        self._epoch_unix = time.time()
+        self._note_cls = None          # jax.profiler.TraceAnnotation, once seen
+        self._xla_listening = False    # the jax.monitoring span listener is on
 
     # -- recording -----------------------------------------------------------
     def span(self, name: str, cat: str = "engine",
@@ -194,6 +252,27 @@ class Tracer:
         with self._lock:
             self._events.append(event)
 
+    def complete(self, name: str, start_unix_s: float, end_unix_s: float,
+                 cat: str = "engine", **attrs) -> None:
+        """Record a span that has already ended, given on ``time.time()``'s
+        clock (what ``jax.monitoring`` hands over) and placed through the
+        anchor ``clear()`` recorded. Same event layout as a live span; the
+        parent is the calling thread's innermost open span."""
+        if not self.enabled:
+            return
+        stack = self._stack()
+        event = {
+            "name": name, "cat": cat, "ph": "X",
+            "ts": round((start_unix_s - self._epoch_unix) * 1e6, 1),
+            "dur": round(max(end_unix_s - start_unix_s, 0.0) * 1e6, 1),
+            "pid": os.getpid(), "tid": threading.get_ident(),
+            "sid": next(self._ids), "parent": stack[-1] if stack else 0,
+        }
+        if attrs:
+            event["args"] = attrs
+        with self._lock:
+            self._events.append(event)
+
     def _stack(self) -> list[int]:
         stack = getattr(self._tls, "stack", None)
         if stack is None:
@@ -201,17 +280,69 @@ class Tracer:
             self._tls.stack = stack
         return stack
 
+    # -- the profiler's side -------------------------------------------------
+    def _annotation(self):
+        """``jax.profiler.TraceAnnotation`` where this process has imported
+        jax (never imported from here), else None. The first sighting also
+        registers the ``jax.monitoring`` span listener a ``configure`` before
+        jax's import had to leave out."""
+        cls = self._note_cls
+        if cls is None:
+            jax = sys.modules.get("jax")
+            if jax is None:
+                return None
+            cls = self._note_cls = jax.profiler.TraceAnnotation
+            self._listen_xla(self.enabled)
+        return cls
+
+    def _on_xla_span(self, event: str, start_s: float, end_s: float,
+                     **kwargs) -> None:
+        name = XLA_EVENT_SPANS.get(event)
+        if name is not None:
+            self.complete(name, start_s, end_s, cat="xla",
+                          label=str(kwargs.get("fun_name", "")))
+
+    def _listen_xla(self, on: bool) -> None:
+        """Register (once) or unregister the listener that turns XLA's
+        trace / lower / compile events into spans."""
+        jax = sys.modules.get("jax")
+        if on == self._xla_listening or jax is None:
+            return
+        monitoring = jax.monitoring
+        with self._lock:
+            if on == self._xla_listening:
+                return
+            if on:
+                monitoring.register_event_time_span_listener(
+                    self._on_xla_span)
+            else:
+                monitoring.unregister_event_time_span_listener(
+                    self._on_xla_span)
+            self._xla_listening = on
+
     # -- control -------------------------------------------------------------
     def configure(self, enabled: bool = True, clear: bool = True) -> None:
         if clear:
             self.clear()
         self.enabled = enabled
+        self._listen_xla(enabled)
 
     def clear(self) -> None:
         with self._lock:
             self._events = []
             self._open = {}
+        # the anchor: one instant on both clocks. Spans are timed on
+        # perf_counter; the profiler (and jax.monitoring) on the wall clock
         self._epoch = time.perf_counter()
+        self._epoch_unix = time.time()
+
+    def clock(self) -> dict:
+        """The anchor that places ``ts`` on the wall clock, and through it
+        on a ``jax.profiler`` trace's axis (module docstring)."""
+        return {"epoch_perf_counter_s": self._epoch,
+                "epoch_unix_s": self._epoch_unix,
+                "profiler_clock": "unix, from the xplane's "
+                                  "profile_start_time"}
 
     # -- inspection ----------------------------------------------------------
     def events(self) -> list[dict]:
@@ -243,16 +374,21 @@ class Tracer:
     def write_chrome_trace(self, path: str) -> str:
         """Chrome trace-event JSON: open the file in Perfetto
         (ui.perfetto.dev) or chrome://tracing."""
-        payload = {"traceEvents": self.events(), "displayTimeUnit": "ms"}
+        payload = {"traceEvents": self.events(), "displayTimeUnit": "ms",
+                   "clock": self.clock()}
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         with open(path, "w") as f:
             json.dump(payload, f)
         return path
 
     def write_jsonl(self, path: str) -> str:
-        """One event per line — greppable / streamable log form."""
+        """One event per line — greppable / streamable log form. The first
+        line is a metadata event (``ph`` "M") carrying ``clock``."""
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         with open(path, "w") as f:
+            f.write(json.dumps({"name": "clock", "ph": "M", "ts": 0,
+                                "pid": os.getpid(), "tid": 0,
+                                "args": self.clock()}) + "\n")
             for e in self.events():
                 f.write(json.dumps(e) + "\n")
         return path

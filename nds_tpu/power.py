@@ -133,8 +133,8 @@ def run_one_query(session: Session, sql: str, query_name: str,
     statements = [s for s in sql.split(";") if s.strip()]
     result = None
     for stmt in statements:
-        # the query name labels spans and per-program device-time
-        # attribution (obs.device_time): "query9/root" etc.
+        # the query name labels the statement's spans and names its
+        # programs on the device trace (jit_nds_query9_root etc.)
         result = session.sql(stmt, backend=backend, label=query_name)
     if output_prefix and result is not None:
         import pyarrow.parquet as pq

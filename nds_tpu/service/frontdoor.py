@@ -68,6 +68,7 @@ from typing import Optional
 
 from ..obs import metrics as _metrics
 from ..obs.flight import FLIGHT
+from ..obs.trace import TRACER
 from ..resilience import (FAULTS, AdmissionRejected, CircuitOpen,
                           DeadlineExceeded, FaultError, TransientError)
 from .service import ServiceClosed
@@ -281,7 +282,12 @@ class _Handler(socketserver.StreamRequestHandler):
                 }}
         if header.get("hash"):
             resp["result_hash"] = result_hash(table)
-        return self._reply(resp, table_to_ipc(arrow_bridge.to_arrow(table)))
+        # the last hop of a request: engine Table -> Arrow -> IPC bytes ->
+        # socket, on this connection's thread, linked to the ticket's root
+        with TRACER.span("frontdoor/reply", cat="service",
+                         parent=ticket.trace_id, label=ticket.label):
+            return self._reply(
+                resp, table_to_ipc(arrow_bridge.to_arrow(table)))
 
     def _op_cache_snapshot(self, fd: "FrontDoorServer") -> bool:
         from ..engine import arrow_bridge

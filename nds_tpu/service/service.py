@@ -899,8 +899,13 @@ class QueryService:
     def _next_batch(self) -> Optional[list]:  # lint: device-lane (runs on the device-lane thread)
         cfg = self.config
         with self._cv:
-            while self._running and (self._hold or not self._ready):
-                self._cv.wait(0.05)
+            # nothing ready: the lane (and with it the device) idles until
+            # a planner hands a ticket over; with one ready the span is
+            # empty. The configured linger below is the lane's own choice
+            # and no idling.
+            with TRACER.span("service/lane_idle", cat="service"):
+                while self._running and (self._hold or not self._ready):
+                    self._cv.wait(0.05)
             if not self._running:
                 return None
         if cfg.batch_linger_ms > 0:

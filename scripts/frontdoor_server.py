@@ -98,6 +98,14 @@ def main(argv=None) -> int:
     p.add_argument("--dispatch_timeout_s", type=float, default=0.0)
     p.add_argument("--chunk_rows", type=int, default=8192)
     p.add_argument("--out_of_core_min_rows", type=int, default=10_000)
+    p.add_argument("--trace", default=None, metavar="PATH",
+                   help="span tracer on; Chrome trace written here at "
+                        "shutdown (as power --trace)")
+    p.add_argument("--profile_folder", default=None,
+                   help="jax.profiler trace of the whole serving time under "
+                        "this folder; with --trace the spans are on it as "
+                        "nds.* host events (scripts/trace_report.py "
+                        "--xplane)")
     args = p.parse_args(argv)
 
     from nds_tpu.config import maybe_enable_compile_cache
@@ -105,6 +113,9 @@ def main(argv=None) -> int:
 
     # same persistent compile cache as every CLI (config.compile_cache_dir)
     maybe_enable_compile_cache()
+    if args.trace:
+        from nds_tpu.obs.trace import TRACER
+        TRACER.configure(enabled=True)
     work_dir = tempfile.mkdtemp(prefix="frontdoor_")
     session = build_session(args, work_dir)
     rc_cfg = None
@@ -142,6 +153,8 @@ def main(argv=None) -> int:
         stop["done"] = True
 
     signal.signal(signal.SIGTERM, _term)
+    if args.profile_folder:
+        jax.profiler.start_trace(args.profile_folder)
     try:
         # serve until the parent closes our stdin (the clean-shutdown
         # handshake) or SIGTERM flips the flag
@@ -154,6 +167,10 @@ def main(argv=None) -> int:
     finally:
         server.stop()
         svc.close()
+        if args.profile_folder:
+            jax.profiler.stop_trace()
+        if args.trace:
+            TRACER.write_chrome_trace(args.trace)
     return 0
 
 

@@ -2,12 +2,10 @@
 
 Times the three ISSUE-7 kernel families — tiled segmented sort, fused
 group-by partial aggregation, batched multi-column gather — against their
-generic XLA lowerings over a rows x dtype grid (obs.device_time.timed_call:
-the clock stops after block_until_ready). Every timed run reports into the
-PR-6 per-program registry under a "kernel/<name>:<impl>" label, so the
-microbench table carries the same per-program roofline fractions (against
-the published HBM bandwidth of the device it ran on) as the engine's bench
-JSON. Each Pallas kernel's outcome is recorded on its own: "ok" with its
+generic XLA lowerings over a rows x dtype grid (the clock stops after
+block_until_ready). Every record carries its best wall and, on a device
+with published peaks, the share of the HBM bandwidth its analytic bytes
+reach over that wall. Each Pallas kernel's outcome is recorded on its own: "ok" with its
 result checked bit-identical to the XLA lowering's, or "refused" with the
 compiler's message (PallasLoweringError) — one refusal does not end the
 run.
@@ -25,6 +23,7 @@ import argparse
 import json
 import os
 import sys
+import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -33,8 +32,8 @@ def _parse_args(argv=None):
     p = argparse.ArgumentParser(
         prog="kernel_bench.py",
         description="XLA vs Pallas microbench per relational kernel "
-                    "(per-kernel compile outcome, per-program roofline "
-                    "table)")
+                    "(per-kernel compile outcome, best wall, roofline "
+                    "share)")
     p.add_argument("--kernels", default="sort,groupby,gather",
                    help="comma subset of sort,groupby,gather")
     p.add_argument("--rows", default="65536,262144",
@@ -68,8 +67,7 @@ def main(argv=None) -> int:
     if not args.no_x64:
         jax.config.update("jax_enable_x64", True)
     from nds_tpu.engine.jax_backend import pallas_kernels as pk
-    from nds_tpu.obs.device_time import (PROGRAMS, format_table,
-                                         roofline_bw_gbps, timed_call)
+    from nds_tpu.obs.device_time import roofline_bw_gbps
     from nds_tpu.report import device_capture
 
     device = device_capture()
@@ -86,13 +84,20 @@ def main(argv=None) -> int:
     rng = np.random.default_rng(778)
     records: list[dict] = []
 
+    def timed_call(fn, *call_args):
+        """(wall ms, host result) of one call; the clock stops only after
+        block_until_ready, the host copy is taken outside it."""
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(fn(*call_args))
+        ms = (time.perf_counter() - t0) * 1000.0
+        return ms, jax.device_get(out)
+
     def run_pair(name: str, n: int, dt: str, xla_fn, pallas_fn,
                  bytes_accessed: float, args_):
         want = None
         for impl, fn in (("xla", xla_fn), ("pallas", pallas_fn)):
             if fn is None:
                 continue
-            label = f"kernel/{name}:{impl}"
             rec = {"kernel": name, "impl": impl, "rows": n, "dtype": dt,
                    "mode": mode if impl == "pallas" else "xla",
                    "device": device}
@@ -108,10 +113,12 @@ def main(argv=None) -> int:
             for _ in range(max(1, args.iters)):
                 ms, host = timed_call(jfn, *args_)
                 best = min(best, ms)
-                PROGRAMS.record_run(label, ms)
-            PROGRAMS.record_cost(label, {"flops": 0.0,
-                                         "bytes accessed": bytes_accessed})
             rec.update(status="ok", best_ms=round(best, 3))
+            if bw_gbps and best > 0:
+                # the analytic bytes of the call over its best wall, as a
+                # share of the device's published bandwidth
+                rec["roofline_frac"] = round(
+                    bytes_accessed / (bw_gbps * 1e9) / (best / 1e3), 5)
             if impl == "xla":
                 want = host
             elif want is not None:
@@ -185,7 +192,12 @@ def main(argv=None) -> int:
                       + (f", bit-identical to xla: "
                          f"{r.get('bit_identical_to_xla')}"
                          if r["status"] == "ok" else f": {r['error']}"))
-        print(format_table(PROGRAMS.table(bw_gbps=bw_gbps)))
+        for r in records:
+            if r["status"] == "ok":
+                rf = r.get("roofline_frac")
+                print(f"kernel/{r['kernel']}:{r['impl']:<7} best "
+                      f"{r['best_ms']:>9.3f} ms  roofline "
+                      f"{f'{rf:.4f}' if rf is not None else '-'}")
     return 0
 
 

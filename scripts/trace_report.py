@@ -13,15 +13,22 @@ view a Perfetto session would start from:
 - JSONL event logs (one event per line, same rollup);
 - flight-recorder JSONL dumps (``obs.flight``): per-event-type counts,
   per-tenant rollup, and the slowest completed tickets;
-- bench JSON lines (the ``bench.py`` stdout object): the per-program
-  device-time table, per-query attribution fractions, the engine metrics
-  snapshot, and (schema >= 3) histogram quantile tables.
+- bench JSON lines (the ``bench.py`` stdout object): the engine metrics
+  snapshot, the span rollup, and (schema >= 3) histogram quantile tables;
+- ``--xplane FILE``: a ``jax.profiler`` trace (``*.xplane.pb``, e.g. from
+  ``power --profile_folder``): device time per program from the device's
+  own clock (the ``XLA Modules`` line; plan programs are
+  ``jit_nds_<query>_<unit>``) and the device's idle gaps by the ``nds.``
+  span that covers each; given the Chrome trace of the same run as
+  ARTIFACT too, how far the two clocks are apart after the recorded anchor.
 
 Usage:  python scripts/trace_report.py ARTIFACT [--top N]
+        python scripts/trace_report.py --xplane FILE [ARTIFACT] [--top N]
 
 Stdlib plus the dependency-free ``nds_tpu.obs.metrics`` (histogram
-quantile math); safe to point at artifacts from any round
-(schema_version tolerant — unknown keys are ignored).
+quantile math); ``--xplane`` needs jax (``nds_tpu.obs.xplane``). Safe to
+point at artifacts from any round (schema_version tolerant — unknown keys
+are ignored).
 """
 from __future__ import annotations
 
@@ -228,23 +235,6 @@ def print_flight(events: list[dict], top: int) -> None:
 def print_bench(doc: dict, top: int) -> None:
     print(f"bench: {doc.get('metric')} = {doc.get('value')} "
           f"{doc.get('unit', '')} (vs_baseline {doc.get('vs_baseline')})")
-    programs = doc.get("device_time_programs") or []
-    if programs:
-        print("\ntop programs by device time:")
-        head = (f"{'program':<40} {'runs':>5} {'total_ms':>10} "
-                f"{'mean_ms':>9} {'roofline':>9}")
-        print(head)
-        print("-" * len(head))
-        for r in programs[:top]:
-            rf = r.get("roofline_frac")
-            print(f"{r['program'][:40]:<40} {r['runs']:>5} "
-                  f"{r['device_ms']:>10.1f} {r['mean_ms']:>9.2f} "
-                  f"{(f'{rf:.4f}' if rf is not None else '-'):>9}")
-    attribution = doc.get("attribution_frac") or {}
-    if attribution:
-        print("\ndevice-time attribution (fraction of timed wall):")
-        for q, frac in attribution.items():
-            print(f"  {q:<12} {frac:.1%}")
     metrics = doc.get("metrics") or {}
     if metrics:
         print("\nengine metrics:")
@@ -273,13 +263,49 @@ def print_bench(doc: dict, top: int) -> None:
                   f"{snap['max'] if snap['max'] is not None else 0:>9.1f}")
 
 
+def print_xplane(path: str, chrome_trace: str | None, top: int) -> None:
+    """The device's side of a run: device time per program and idle gaps
+    by covering ``nds.`` span, both on the device trace's clock."""
+    sys.path.insert(0, REPO)
+    from nds_tpu.obs import xplane
+    trace = xplane.read(path)
+    rows = xplane.program_table(trace)
+    print(f"device time by program ({len(trace['devices'])} device "
+          f"plane(s), XLA Modules line):")
+    head = (f"{'program':<56} {'runs':>6} {'total_ms':>10} {'mean_ms':>9} "
+            f"{'max_ms':>9}")
+    print(head)
+    print("-" * len(head))
+    for r in rows[:top]:
+        print(f"{r['program'][:56]:<56} {r['runs']:>6} "
+              f"{r['device_ms']:>10.1f} {r['mean_ms']:>9.2f} "
+              f"{r['max_ms']:>9.2f}")
+    print(f"\ndevice idle gaps by covering {xplane.ANNOTATION_PREFIX}* span "
+          f"({len(trace['spans'])} host events):")
+    for name, seconds in xplane.idle_gaps(trace)[:top]:
+        print(f"  {seconds * 1e3:>10.1f} ms  {name}")
+    if chrome_trace:
+        with open(chrome_trace) as f:
+            doc = json.load(f)
+        check = xplane.clock_check(trace, doc["traceEvents"], doc["clock"])
+        print(f"\nspans against their host events after the anchor: {check}")
+
+
 def main(argv: list[str] | None = None) -> int:
     p = argparse.ArgumentParser(prog="trace_report.py")
-    p.add_argument("artifact", help="Chrome trace / JSONL event log / "
-                                    "bench JSON")
+    p.add_argument("artifact", nargs="?",
+                   help="Chrome trace / JSONL event log / bench JSON")
+    p.add_argument("--xplane", default=None, metavar="FILE",
+                   help="a jax.profiler *.xplane.pb: device time per "
+                        "program, idle gaps by nds.* span")
     p.add_argument("--top", type=int, default=10,
                    help="rows in the slowest-spans / top-programs tables")
     a = p.parse_args(argv)
+    if a.xplane:
+        print_xplane(a.xplane, a.artifact, a.top)
+        return 0
+    if not a.artifact:
+        p.error("an ARTIFACT or --xplane FILE is required")
     try:
         events = load_events(a.artifact)
         if events is not None and is_flight_log(events):

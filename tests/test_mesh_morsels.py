@@ -198,13 +198,29 @@ def test_gspmd_mesh_records_pallas_fallback_reason(data):
 
 
 def test_device_time_attribution_labels(data, baseline):
-    from nds_tpu.obs.device_time import PROGRAMS
-    run(data, STAR, mesh_shards=8, label="attr")
-    labels = [row["program"] for row in PROGRAMS.table(top=200)]
-    assert any(l.startswith("attr/morsel:fact") and l.endswith("@mesh8")
-               for l in labels), labels
-    assert any(l.startswith("attr/gather:fact") and l.endswith("@mesh8")
-               for l in labels), labels
+    """The sharded morsel's two programs under their own names: the spans'
+    labels on the host side, the HLO modules on the device trace's."""
+    from nds_tpu.obs.trace import TRACER
+    TRACER.configure(enabled=True)
+    try:
+        run(data, STAR, mesh_shards=8, label="attr")
+        events = TRACER.events()
+    finally:
+        TRACER.configure(enabled=False)
+    labels = {e["name"]: e["args"]["label"] for e in events
+              if e["name"] in ("exec", "collective")}
+    assert labels["exec"].startswith("attr/morsel:fact") and \
+        labels["exec"].endswith("@mesh8"), labels
+    assert labels["collective"].startswith("attr/gather:fact") and \
+        labels["collective"].endswith("@mesh8"), labels
+    modules = {e["args"]["label"] for e in events
+               if e["name"] == "xla.compile"}
+    import re
+    for half in ("local", "gather"):
+        assert any(re.fullmatch(rf"jit\(nds_attr_morsel_fact(_\d+)?_{half}\)",
+                                m) for m in modules), modules
+    kids = {e["name"] for e in events if e["name"].startswith("exec.")}
+    assert kids == {"exec.args", "exec.wait", "exec.fetch"}
 
 
 def test_sharded_vs_sqlite_oracle(data):

@@ -1,5 +1,5 @@
 """Observability layer (nds_tpu/obs): span tracer, metrics registry,
-device-time attribution, typed ExecStats, logging channel.
+the program's names on the device trace, typed ExecStats, logging channel.
 
 Acceptance-backed properties:
 - disabled tracer hooks are near-free (the <2% bench-slice overhead bound
@@ -8,12 +8,20 @@ Acceptance-backed properties:
   every parent id resolvable) that exports to valid Chrome trace-event
   JSON (Perfetto-loadable);
 - metrics counters move correctly under the fault-injection smoke run;
+- every plan program is the HLO module ``jit_nds_<query>_<unit>`` under a
+  name that is the same in every process, its instructions carry the plan
+  node and kernel as ``op_name``, and the scopes change no instruction;
+- a traced run's spans are ``nds.*`` host events of a ``jax.profiler``
+  trace, within a millisecond after the recorded clock anchor;
 - ExecStats is built in one place with a dict view identical to the
   legacy untyped ``last_exec_stats`` keys, and records EVERY prefetch
   error.
 """
+import contextlib
+import glob
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -24,7 +32,7 @@ import pytest
 
 from nds_tpu.config import EngineConfig
 from nds_tpu.engine import Session
-from nds_tpu.obs import device_time as dt
+from nds_tpu.obs import device_time as peaks
 from nds_tpu.obs import log as obs_log
 from nds_tpu.obs import metrics as om
 from nds_tpu.obs.stats import ExecStats
@@ -85,6 +93,52 @@ def test_disabled_run_records_nothing():
     s.sql(QUERY, backend="jax")
     assert TRACER.events() == []
     assert TRACER.open_spans() == []
+
+
+def test_disabled_new_call_sites_allocate_nothing():
+    """The hooks this layer grew since (the exec.* children, complete(),
+    the lane's idle span) are the same shared no-op when off: no span
+    object, no event, no profiler annotation, no listener."""
+    import tracemalloc
+    assert not TRACER.enabled and not TRACER._xla_listening
+    for _ in range(100):            # warm every code path first
+        with TRACER.span("exec.wait", cat="device"):
+            pass
+    tracemalloc.start()
+    before = tracemalloc.take_snapshot()
+    for _ in range(2000):
+        for name in ("exec.args", "exec.wait", "exec.fetch",
+                     "service/lane_idle", "frontdoor/reply"):
+            with TRACER.span(name, cat="device") as sp:
+                assert sp is NULL_SPAN
+        TRACER.complete("xla.compile", 1.0, 2.0, cat="xla", label="f")
+    after = tracemalloc.take_snapshot()
+    tracemalloc.stop()
+    grown = sum(d.size_diff for d in after.compare_to(before, "filename")
+                if "obs/trace.py" in str(d.traceback))
+    assert grown < 1024, f"disabled hooks allocated {grown} bytes"
+    assert TRACER.events() == []
+
+
+def test_tracer_never_imports_jax():
+    """A process that has not imported jax (the front door's clients) gets
+    spans and no annotations; obs/trace.py and obs/metrics.py import no
+    jax, on or off."""
+    code = (
+        "import sys\n"
+        "from nds_tpu.obs.trace import TRACER\n"
+        "from nds_tpu.obs import metrics\n"
+        "with TRACER.span('off'): pass\n"
+        "TRACER.configure(enabled=True)\n"
+        "with TRACER.span('on', label='x'): pass\n"
+        "TRACER.complete('xla.compile', 1.0, 2.0)\n"
+        "assert [e['name'] for e in TRACER.events()] == "
+        "['on', 'xla.compile']\n"
+        "assert not TRACER._xla_listening\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                         capture_output=True, text=True)
+    assert out.returncode == 0, out.stderr
 
 
 # -- tracer: enabled lifecycle ------------------------------------------------
@@ -183,7 +237,10 @@ def test_jsonl_export_and_aggregate(tmp_path):
         pass
     path = TRACER.write_jsonl(str(tmp_path / "events.jsonl"))
     lines = [json.loads(ln) for ln in open(path)]
-    assert len(lines) == 3
+    # the clock anchor first (a metadata event), then the three spans
+    assert lines[0]["ph"] == "M" and lines[0]["name"] == "clock"
+    assert set(lines[0]["args"]) >= {"epoch_perf_counter_s", "epoch_unix_s"}
+    assert [ln["name"] for ln in lines[1:]] == ["b", "a", "a"]
     agg = TRACER.aggregate()
     assert agg["a"]["count"] == 2
     assert agg["b"]["count"] == 1
@@ -201,17 +258,15 @@ def test_trace_report_cli_on_trace_and_bench_json(tmp_path):
     assert out.returncode == 0, out.stderr
     assert "cli.span" in out.stdout
     bench = {"metric": "m", "value": 1.0, "unit": "ms", "vs_baseline": 1.0,
-             "device_time_programs": [
-                 {"program": "q1/root", "runs": 3, "device_ms": 30.0,
-                  "mean_ms": 10.0, "max_ms": 12.0, "roofline_frac": 0.01}],
-             "attribution_frac": {"q1": 0.97},
+             "spans": {"exec": {"count": 3, "total_ms": 30.0,
+                                "max_ms": 12.0}},
              "metrics": {"queries_run": 3}}
     bpath = tmp_path / "bench.json"
     bpath.write_text(json.dumps(bench))
     out = subprocess.run([sys.executable, script, str(bpath)],
                          capture_output=True, text=True)
     assert out.returncode == 0, out.stderr
-    assert "q1/root" in out.stdout
+    assert "exec" in out.stdout
     assert "queries_run" in out.stdout
 
 
@@ -300,45 +355,250 @@ def test_exhausted_retries_still_counted():
     assert om.METRICS.delta(before).get("retries") == 2
 
 
-# -- device-time attribution --------------------------------------------------
+# -- the program's names on the device side -----------------------------------
 
-def test_program_registry_table_and_roofline():
-    reg = dt.ProgramRegistry()
-    reg.record_run("q9/root", 10.0)
-    reg.record_run("q9/root", 30.0)
-    reg.record_run("q1/root", 5.0)
-    reg.record_cost("q9/root", {"flops": 1e6, "bytes accessed": 4e6})
-    reg.record_cost("q1/root", {"flops": 2e3, "bytes accessed": 1e3})
-    # no bandwidth given (a CPU run): no roofline under a device metric's name
-    assert all("roofline_frac" not in r for r in reg.table())
-    with pytest.raises(dt.UnknownDeviceError, match="cpu"):
-        dt.peak_hbm_gbps("cpu")
-    assert dt.peak_hbm_gbps("TPU v5 lite") == 819.0
-    rows = reg.table(bw_gbps=100.0)
-    assert [r["program"] for r in rows] == ["q9/root", "q1/root"]
-    top = rows[0]
-    assert top["runs"] == 2
-    assert top["device_ms"] == 40.0
-    assert top["mean_ms"] == 20.0
-    assert top["max_ms"] == 30.0
-    # roofline = (bytes / bw) / mean_run_s = (4e6/1e11) / 0.020 = 0.002
-    assert abs(top["roofline_frac"] - 0.002) < 1e-6
-    assert dt.coverage(rows, 50.0) == pytest.approx(0.9)
-    text = dt.format_table(rows)
-    assert "q9/root" in text and "roofline" in text
+from nds_tpu.engine.jax_backend.executor import program_name  # noqa: E402
+
+HOISTED = ("SELECT k, COUNT(*) AS c, SUM(v) AS sv FROM t WHERE v > {lit} "
+           "GROUP BY k ORDER BY k")
 
 
-def test_compiled_runs_attribute_device_time():
-    before = dt.PROGRAMS.snapshot()
+@pytest.mark.parametrize("label,fp,want", [
+    ("query9/root", None, "nds_query9_root"),
+    ("query9", None, "nds_query9_root"),
+    ("query9/seg:3fa91c02", None, "nds_query9_seg_3fa91c02"),
+    ("query9/morsel:store_sales#1", None, "nds_query9_morsel_store_sales_1"),
+    ("q1a2b3c4d/root", "0123456789abcdef", "nds_plan0123456789ab_root"),
+    ("q1a2b3c4d/morsel:t", "0123456789abcdef", "nds_plan0123456789ab_morsel_t"),
+])
+def test_program_name_from_label_or_fingerprint(label, fp, want):
+    assert program_name(label, fp) == want
+
+
+def test_program_name_charset_and_length():
+    name = program_name("weird label: SELECT * FROM t/" + "x" * 200)
+    assert re.fullmatch(r"[A-Za-z0-9_]{1,64}", name)
+    assert name == program_name("weird label: SELECT * FROM t/" + "x" * 200)
+    assert name != program_name("weird label: SELECT * FROM t/" + "x" * 201)
+    assert program_name("peak of the \u00e9t\u00e9/root") == \
+        "nds_peak_of_the_t_root"
+
+
+def test_device_peaks_table_refuses_an_unknown_device():
+    assert peaks.peak_hbm_gbps("TPU v5 lite") == 819.0
+    with pytest.raises(peaks.UnknownDeviceError, match="cpu"):
+        peaks.peak_hbm_gbps("cpu")
+    assert peaks.roofline_bw_gbps({"platform": "cpu",
+                                   "device_kind": "cpu"}) is None
+
+
+def _module_names(session) -> list:
+    return sorted(ent["cq"].module_name
+                  for ent in session._jax_executor()._plans.values()
+                  if isinstance(ent, dict) and ent.get("cq") is not None)
+
+
+def _compiled(session, sql, label=None) -> list:
+    for _ in range(2):          # record, then compile+run
+        session.sql(sql, backend="jax", label=label)
+    return _module_names(session)
+
+
+def test_module_name_is_the_same_in_every_process():
+    """An unlabelled statement is named from its parameterized plan's
+    fingerprint: the same name from two fresh executors, from a child
+    process, and for two literals of the one hoisted program (a name from
+    the SQL text's hash would compile once per literal)."""
+    from nds_tpu.engine.jax_backend.executor import clear_shared_programs
+    a = _compiled(make_session(), HOISTED.format(lit=10))
+    clear_shared_programs()
+    b = _compiled(make_session(), HOISTED.format(lit=10))
+    clear_shared_programs()
+    c = _compiled(make_session(), HOISTED.format(lit=500))
+    assert a == b == c and len(a) == 1
+    assert re.fullmatch(r"nds_plan[0-9a-f]{12}_root", a[0]), a
+    code = (
+        "import sys; sys.path.insert(0, 'tests')\n"
+        "import conftest, test_obs as t\n"
+        "print('NAMES', t._compiled(t.make_session(), "
+        "t.HOISTED.format(lit=77)))\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                         capture_output=True, text=True)
+    assert out.returncode == 0, out.stderr[-2000:]
+    line = next(ln for ln in out.stdout.splitlines()
+                if ln.startswith("NAMES"))
+    assert line == f"NAMES {a}"
+    # a label the caller gave is the name, whatever the literal
+    clear_shared_programs()
+    assert _compiled(make_session(), HOISTED.format(lit=10),
+                     label="query9") == ["nds_query9_root"]
+
+
+def _lowered(session):
+    je = session._jax_executor()
+    ent = next(e for e in je._plans.values()
+               if isinstance(e, dict) and e.get("cq") is not None)
+    cq = ent["cq"]
+    return cq._fn.lower(*cq._args(je._scans_for(ent),
+                                  ent.get("params", ())))
+
+
+def test_replayed_plan_is_named_and_scoped():
     s = make_session()
-    for _ in range(3):
-        s.sql(QUERY, backend="jax", label="attr_q")
-    after = dt.PROGRAMS.snapshot()
-    new = {k: v for k, v in after.items() if k not in before}
-    assert any(k.startswith("attr_q") for k in new), new
-    st = next(v for k, v in new.items() if k.startswith("attr_q"))
-    assert st.runs >= 2          # compile+run + compiled replay
-    assert st.device_ms > 0
+    _compiled(s, QUERY, label="obs_q")
+    lowered = _lowered(s)
+    assert lowered.as_text().startswith("module @jit_nds_obs_q_root ")
+    ops = set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))
+    scoped = [o for o in ops if o.startswith("jit(nds_obs_q_root)/")]
+    # the plan node (verify.node_labels' TypeName#k) and the kernel
+    assert any(re.search(r"/AggregateNode#\d+/", o) for o in scoped), ops
+    assert any(re.search(r"/SortNode#\d+/(\w+/)*sort_perm/", o)
+               for o in scoped), scoped
+
+
+def test_scopes_change_no_instruction(monkeypatch):
+    """Scopes are debug locations: with debug info stripped (what JAX's
+    compile-cache key hashes) the lowered program is the same text with
+    and without them."""
+    import jax
+    from nds_tpu.engine.jax_backend.executor import clear_shared_programs
+    s = make_session()
+    _compiled(s, QUERY, label="obs_q")
+    with_scopes = _lowered(s)
+    assert "AggregateNode#" in with_scopes.as_text(debug_info=True)
+    clear_shared_programs()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda _name: contextlib.nullcontext())
+    s2 = make_session()
+    _compiled(s2, QUERY, label="obs_q")
+    without = _lowered(s2)
+    assert "AggregateNode#" not in without.as_text(debug_info=True)
+    assert with_scopes.as_text() == without.as_text()
+
+
+def test_exec_children_lie_inside_exec_and_cover_it():
+    TRACER.configure(enabled=True)
+    s = make_session()
+    for _ in range(4):
+        s.sql(QUERY, backend="jax", label="obs_q")
+    events = TRACER.events()
+    execs = [e for e in events if e["name"] == "exec"]
+    assert len(execs) == 3
+    for ex in execs:
+        kids = [e for e in events if e.get("parent") == ex["sid"]
+                and e["name"].startswith("exec.")]
+        assert [k["name"] for k in kids] == ["exec.args", "exec.wait",
+                                             "exec.fetch"]
+        for k in kids:
+            assert k["ts"] >= ex["ts"]
+            assert k["ts"] + k["dur"] <= ex["ts"] + ex["dur"] + 0.2
+        assert sum(k["dur"] for k in kids) >= 0.95 * ex["dur"], (ex, kids)
+
+
+# -- the tracer on the profiler's clock ---------------------------------------
+
+def test_spans_are_host_events_of_a_profile_within_a_millisecond(tmp_path):
+    """A CPU jax.profiler trace over a traced query: every mirrored span is
+    an ``nds.*`` host event whose interval agrees with the tracer's own
+    ``ts``/``dur`` after the recorded anchor."""
+    import jax
+    from nds_tpu.obs import xplane
+    s = make_session()
+    for _ in range(2):
+        s.sql(QUERY, backend="jax", label="prof_q")
+    TRACER.configure(enabled=True)
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        for _ in range(3):
+            s.sql(QUERY, backend="jax", label="prof_q")
+        with TRACER.span("detached.parent") as parent:
+            sp = TRACER.span("detached", parent=parent.sid).begin()
+            sp.end()
+    finally:
+        jax.profiler.stop_trace()
+    trace = xplane.read(glob.glob(
+        str(tmp_path / "prof" / "plugins" / "profile" / "*" /
+            "*.xplane.pb"))[0])
+    names = {n for _s, _e, n in trace["spans"]}
+    assert {"nds.query:prof_q", "nds.exec:prof_q", "nds.exec.wait",
+            "nds.exec.fetch", "nds.detached.parent"} <= names, names
+    assert "nds.detached" not in names      # begin()/end() is not mirrored
+    mirrored = [e for e in TRACER.events()       # live context-manager spans
+                if e["name"] != "detached" and e["cat"] != "xla"]
+    assert len(trace["spans"]) == len(mirrored)
+    check = xplane.clock_check(trace, TRACER.events(), TRACER.clock())
+    assert check["matched"] == len(trace["spans"])
+    assert check["max_start_ms"] < 1.0 and check["max_dur_ms"] < 1.0, check
+    # the same through the operator's tool, from the exported files
+    chrome = TRACER.write_chrome_trace(str(tmp_path / "t.json"))
+    assert json.load(open(chrome))["clock"] == TRACER.clock()
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "scripts", "trace_report.py"),
+         "--xplane", glob.glob(str(tmp_path / "prof" / "plugins" /
+                                   "profile" / "*" / "*.xplane.pb"))[0],
+         chrome], capture_output=True, text=True,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "device time by program" in out.stdout
+    assert "'matched': %d" % len(trace["spans"]) in out.stdout
+
+
+def test_complete_places_a_finished_span_through_the_anchor():
+    TRACER.configure(enabled=True)
+    with TRACER.span("outer") as outer:
+        t0 = time.time()
+        time.sleep(0.002)
+        TRACER.complete("done", t0, t0 + 0.001, cat="xla", label="f")
+    done = next(e for e in TRACER.events() if e["name"] == "done")
+    out = next(e for e in TRACER.events() if e["name"] == "outer")
+    assert done["parent"] == outer.sid and done["args"] == {"label": "f"}
+    assert abs(done["dur"] - 1000.0) < 1.0
+    assert out["ts"] - 1000.0 <= done["ts"] <= out["ts"] + out["dur"]
+
+
+# -- XLA's own events ---------------------------------------------------------
+
+def test_xla_events_become_spans_and_counters():
+    import jax
+    import jax.numpy as jnp
+    from jax import monitoring
+    from jax._src.monitoring import \
+        get_event_time_span_listeners as listeners
+    for _ in range(3):              # however often: one listener
+        TRACER.configure(enabled=True)
+    assert listeners().count(TRACER._on_xla_span) == 1
+    om.install_xla_counters()
+    om.install_xla_counters()
+    before = om.METRICS.snapshot()
+
+    def fresh(x):                   # a program no cache has seen traced
+        return jnp.cumsum(x * 3.0 + float(time.time_ns() % 997))
+    fresh.__name__ = "nds_test_fresh"
+    jax.jit(fresh)(jnp.arange(16.0)).block_until_ready()
+    d = om.METRICS.delta(before)
+    assert d.get("xla_compiles", 0) >= 1
+    assert d.get("xla_cache_hits", 0) + d.get("xla_cache_misses", 0) >= 1
+    by_name = {}
+    for e in TRACER.events():
+        by_name.setdefault(e["name"], []).append(e)
+    for name in ("xla.trace", "xla.lower", "xla.compile"):
+        mine = [e for e in by_name.get(name, [])
+                if "nds_test_fresh" in e["args"]["label"]]
+        assert mine and all(e["dur"] >= 0 and e["cat"] == "xla"
+                            for e in mine), (name, by_name.keys())
+    # each cache event counts once (one listener however often installed)
+    before = om.METRICS.snapshot()
+    monitoring.record_event("/jax/compilation_cache/cache_hits")
+    monitoring.record_event("/jax/compilation_cache/cache_misses")
+    assert om.METRICS.delta(before) == {"xla_cache_hits": 1,
+                                        "xla_cache_misses": 1}
+    # off: the span listener is gone, the counters stay
+    TRACER.configure(enabled=False)
+    assert listeners().count(TRACER._on_xla_span) == 0
+    before = om.METRICS.snapshot()
+    jax.jit(lambda x: x - 41.5)(jnp.arange(4.0)).block_until_ready()
+    assert om.METRICS.delta(before).get("xla_compiles", 0) >= 1
+    assert TRACER.events() == []
 
 
 # -- ExecStats ----------------------------------------------------------------
@@ -394,6 +654,32 @@ def test_session_installs_typed_stats_both_paths(tmp_path):
     assert s2.last_exec_stats_typed.mode in ("record", "compile+run",
                                              "compiled", "adopted")
     assert s2.last_exec_stats == s2.last_exec_stats_typed.to_dict()
+
+
+def test_streaming_path_plans_under_a_plan_span_once(tmp_path):
+    """What the streamed cell's ``plan_s`` reads: the morsel path's own
+    parse and plan, in the first statement and in no warm one."""
+    import pyarrow.parquet as pq
+    rng = np.random.default_rng(3)
+    fact = pa.table({
+        "fk": pa.array(rng.integers(0, 50, 30_000), type=pa.int32()),
+        "v": pa.array(rng.integers(0, 100, 30_000), type=pa.int64())})
+    path = os.path.join(str(tmp_path), "fact.parquet")
+    pq.write_table(fact, path, row_group_size=4096)
+    TRACER.configure(enabled=True)
+    s = Session(EngineConfig(chunk_rows=4096, out_of_core_min_rows=10_000))
+    s.register_parquet("fact", path)
+    for _ in range(2):
+        s.sql("SELECT fk, SUM(v) FROM fact GROUP BY fk", backend="jax",
+              label="obs_stream")
+        assert s.last_exec_stats["mode"] == "streaming"
+    events = [e for e in TRACER.events() if e.get("ph") == "X"]
+    plans = [e for e in events if e["name"] == "plan"]
+    assert len(plans) == 1 and plans[0]["args"]["label"] == "obs_stream"
+    parse = [e for e in events if e["name"] == "parse"]
+    assert len(parse) == 1 and parse[0]["parent"] == plans[0]["sid"]
+    by_sid = {e["sid"]: e for e in events}
+    assert by_sid[plans[0]["parent"]]["name"] == "query"
 
 
 # -- logging ------------------------------------------------------------------

@@ -373,6 +373,59 @@ def test_service_serial_lane_nests_session_spans_under_ticket(data):
     assert ticket.stats.mode != "batched"
 
 
+def test_lane_idle_never_overlaps_a_dispatch(data):
+    """service/lane_idle is the lane thread waiting for a ticket: on that
+    thread, as a profiler annotation too, and never while it dispatches."""
+    TRACER.configure(enabled=True)
+    session = make_session(data)
+    with QueryService(session, ServiceConfig(max_batch=8)) as svc:
+        for i in range(3):
+            svc.sql(TPL.format(a=5, b=60), label="warm")
+            time.sleep(0.06)
+        tickets = hold_batch(
+            svc, [TPL.format(a=5 + i, b=60 + i) for i in range(3)])
+        for t in tickets:
+            t.result(timeout=120)
+    events = [e for e in TRACER.events() if e.get("ph") == "X"]
+    idle = [e for e in events if e["name"] == "service/lane_idle"]
+    busy = [e for e in events if e["name"] == "service/dispatch"]
+    assert idle and busy
+    assert len({e["tid"] for e in idle}) == 1           # the lane thread
+    assert {e["tid"] for e in idle} <= {e["tid"] for e in busy}
+    assert sum(e["dur"] for e in idle) >= 100_000       # it did wait
+    for i in idle:
+        for b in busy:
+            assert i["ts"] + i["dur"] <= b["ts"] + 1.0 or \
+                b["ts"] + b["dur"] <= i["ts"] + 1.0, (i, b)
+
+
+def test_frontdoor_reply_is_parent_linked_to_its_ticket(data):
+    from nds_tpu.service import FrontDoorServer
+    from nds_tpu.service.frontdoor import FlightClient
+    TRACER.configure(enabled=True)
+    session = make_session(data)
+    with QueryService(session) as svc:
+        server = FrontDoorServer(svc, host="127.0.0.1", port=0)
+        server.start()
+        try:
+            with FlightClient("127.0.0.1", server.port,
+                              timeout_s=120.0) as client:
+                table, resp = client.query(SERIAL_SQL, label="wire")
+        finally:
+            server.stop()
+    assert table.num_rows > 0 and resp["stats"]["exec_ms"] > 0
+    events = TRACER.events()
+    span_tree(events)                   # no dangling parent
+    by_sid = {e["sid"]: e for e in events}
+    reply = [e for e in events if e["name"] == "frontdoor/reply"]
+    assert len(reply) == 1
+    root = by_sid[reply[0]["parent"]]
+    assert root["name"] == "service/ticket"
+    assert root["args"]["label"] == reply[0]["args"]["label"] == "wire"
+    # the reply follows the ticket's completion, on the connection's thread
+    assert reply[0]["ts"] >= root["ts"]
+
+
 def test_service_records_histograms_per_tenant_and_template(data):
     session = make_session(data)
     before = {k: v["count"]

@@ -38,7 +38,8 @@ NON_METRIC_CONSTS = frozenset({
 })
 
 #: fallback when the gate module cannot be parsed for its own constant
-DEFAULT_REPORT_ONLY_SUFFIXES = ("_ms", "_bytes", "bytes_uploaded")
+DEFAULT_REPORT_ONLY_SUFFIXES = ("_ms", "_bytes", "bytes_uploaded",
+                                "bytes_fetched")
 
 
 def _gate_artifacts(root: str | None):

@@ -28,8 +28,9 @@ Three observation surfaces, one store:
   sighting (record-pass actuals + replay check scalars). The next
   sighting right-sizes its capacity-ladder buckets from these instead of
   inflating every cap to the morsel bound (``streaming.adapt_schedule``)
-  — the q9-class 0-group aggregate drops from the 32768-row morsel
-  bucket to the minimal ladder bucket.
+  — a low-cardinality GROUP BY drops from the morsel bucket to the
+  minimal ladder bucket (a keyless aggregate records no cap to adapt:
+  its one group is static).
 
 Discipline (the house default-off contract):
 

@@ -45,9 +45,9 @@ from ..plan import (
 from . import jexprs, kernels
 from . import pallas_kernels as _pallas
 from .device import (DCol, DTable, PackedTable, bucket, decode_col,
-                     encode_against, free_dtable, phys_dtype, rank_key,
-                     string_rank_lut, to_device, to_host, unpack_table,
-                     widen_col)
+                     device_bytes, encode_against, free_dtable, phys_dtype,
+                     rank_key, string_rank_lut, to_device, to_host,
+                     unpack_table, widen_col)
 
 _I32 = jnp.int32
 
@@ -249,6 +249,11 @@ def _node_rows(decisions: list, node_labels: tuple, actuals: list) -> dict:
         if lbl not in rows or a > rows[lbl]:
             rows[lbl] = a
     return rows
+
+
+def count_fetched(fetched) -> None:
+    """Add what one jax.device_get returned to bytes_fetched."""
+    _metrics.BYTES_FETCHED.inc(device_bytes(fetched))
 
 
 def _verify_schedule(decisions: list, checks_host: list) -> None:
@@ -531,6 +536,8 @@ class CompiledQuery:
                         out_host, checks_host = jax.device_get((out, checks))
                     t2 = _time.perf_counter()
                     _verify_schedule(self.decisions, checks_host)
+                count_fetched(checks_host if keep_device
+                              else (out_host, checks_host))
         if stats is not None:
             checks_int = [int(c) for c in checks_host]
             if "decision_rows" in stats:
@@ -637,6 +644,7 @@ class BatchedQuery:
                     out_host, checks_host = jax.device_get((out, checks))
                     t2 = _time.perf_counter()
                     self._verify(checks_host)
+                count_fetched((out_host, checks_host))
         device_ms = round((t2 - t1) * 1000, 3)
         if stats is not None:
             stats.update(mode="batched", device_ms=device_ms,
@@ -2433,11 +2441,15 @@ class JaxExecutor:
         gid, num_groups_t = self._dense_rank(
             [rank_key(c) for c in active], [c.valid for c in active],
             child.alive)
-        num_groups = self._decide_cap(num_groups_t)
-        if not active:
+        if active:
+            num_groups = self._decide_cap(num_groups_t)
+        else:
             # a global aggregate (incl. a rollup's grand-total grouping set)
-            # over empty input still yields one row
-            num_groups = max(num_groups, 1)
+            # has ONE group by construction, and over empty input still
+            # yields its one row: a static 1, never a recorded capacity
+            # decision (streaming.inflate_schedule would raise it to the
+            # morsel bound and push every agg_apply onto the scatter path)
+            num_groups = 1
             num_groups_t = jnp.maximum(num_groups_t, 1)
         alive_for_agg = child.alive
         cap_out = bucket(max(num_groups, 1))

@@ -51,7 +51,7 @@ from ..streaming import partition_morsel_rows
 from .device import (DTable, PackedTable, _pack_payload, bucket,
                      plan_lanes)
 from .executor import (JaxExecutor, ReplayMismatch, _named, _no_load,
-                       _Recorder, program_name)
+                       _Recorder, count_fetched, program_name)
 
 
 # -- sharded morsel staging ---------------------------------------------------
@@ -273,6 +273,7 @@ class ShardedMorselQuery:
                     checks_host = jax.device_get(checks)
                     t1 = time.perf_counter()
                     self._verify(checks_host)
+                count_fetched(checks_host)
         # ONE collective: all_gather of the sharded partial blocks. Bytes
         # model: ring all-gather ingress per device — each replica receives
         # the other n-1 replicas' blocks, (n-1)/n of the gathered total.
@@ -288,6 +289,7 @@ class ShardedMorselQuery:
                 merged = self._gather(out)
                 out_host = jax.device_get(merged)
             t3 = time.perf_counter()
+            count_fetched(out_host)
         if stats is not None:
             stats["collective_bytes"] = \
                 stats.get("collective_bytes", 0) + coll_bytes

@@ -841,12 +841,14 @@ def adapt_schedule(decisions: list, morsel_cap: int,
     """Feedback-driven inflate_schedule (EngineConfig.adaptive_plans):
     each cap decision is clamped to the LARGER of its record-pass actual
     and the feedback store's observed maximum for that decision, instead
-    of the morsel bound — the q9-class 0-group aggregate then provisions
-    the minimal ladder bucket, not the 32768-row morsel bucket, and every
-    downstream gather shrinks with it. ``observed`` is the index-aligned
-    per-decision maxima (FeedbackStore.member_caps); None (or a
-    length-drifted list — a structurally different schedule) falls back
-    to plain morsel-bound inflation. An observed cap is a CEILING HINT:
+    of the morsel bound — a grouped aggregate of low cardinality (GROUP BY
+    a 5-value key) then provisions the minimal ladder bucket, not the
+    morsel bucket, and every downstream gather shrinks with it. (A keyless
+    aggregate records no cap at all: its one group is static.)
+    ``observed`` is the index-aligned per-decision maxima
+    (FeedbackStore.member_caps); None (or a length-drifted list — a
+    structurally different schedule) falls back to plain morsel-bound
+    inflation. An observed cap is a CEILING HINT:
     a later morsel exceeding it fails the replay's schedule check
     (ReplayMismatch) and re-records eagerly, so under-observation costs a
     re-record, never a wrong answer."""

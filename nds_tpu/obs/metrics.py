@@ -591,6 +591,9 @@ MORSELS = METRICS.counter(
     "morsels", "morsels executed across all streamed queries")
 BYTES_UPLOADED = METRICS.counter(
     "bytes_uploaded", "host->device bytes staged for streamed morsels")
+BYTES_FETCHED = METRICS.counter(
+    "bytes_fetched", "device->host bytes returned by program dispatches: "
+    "results and check scalars")
 HOST_FALLBACKS = METRICS.counter(
     "host_fallbacks", "plan nodes served by the host oracle backend")
 PREFETCH_ERRORS = METRICS.counter(

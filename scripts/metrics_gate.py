@@ -90,7 +90,7 @@ STRICT_ZERO = (
 
 #: report-only name suffixes: wall-clock and byte-volume metrics flake
 #: with host load / layout evolution — printed for the log, never gated
-REPORT_ONLY_SUFFIXES = ("_ms", "_bytes", "bytes_uploaded")
+REPORT_ONLY_SUFFIXES = ("_ms", "_bytes", "bytes_uploaded", "bytes_fetched")
 
 RATIO_LO, RATIO_HI = 0.5, 2.0
 ABS_SLACK = 2

@@ -4,10 +4,10 @@ loop from observed actuals back into plans.
 Acceptance-backed properties — all COUNT-shaped or bit-identity (no wall
 budgets: this host is 1-core and timing tests flake):
 
-- **q9-class right-sizing**: the first sighting of a streamed query
-  provisions every capacity decision at the morsel bucket; the second
-  sighting re-records from observed actuals and provisions the minimal
-  ladder bucket instead — with the response hash-identical across every
+- **low-cardinality right-sizing**: the first sighting of a streamed
+  grouped aggregate (``Q`` groups by the 5-value ``k``) provisions every
+  capacity decision at the morsel bucket; the second sighting re-records
+  from observed actuals and provisions the minimal ladder bucket instead — with the response hash-identical across every
   sighting (right-sizing is provisioning, never results);
 - **ceiling hint, never a correctness input**: a profile observed on
   small data replayed against grown data overflows the adapted schedule,
@@ -111,7 +111,7 @@ def test_member_caps_requires_structural_match():
                           False, 0) is None          # no such member
 
 
-# -- the q9-class right-size -------------------------------------------------
+# -- the low-cardinality right-size -------------------------------------------
 
 def test_second_sighting_rightsizes_caps_bit_identically():
     """First sighting provisions the morsel bucket; the second re-records
@@ -120,19 +120,19 @@ def test_second_sighting_rightsizes_caps_bit_identically():
     s = make_session(adaptive_plans=True)
     s.register_arrow("big", low_card())
     h0, _r0, a0 = counters()
-    ref = arrow_rows(s.sql(Q, label="q9ish"))
+    ref = arrow_rows(s.sql(Q, label="lowcard"))
     assert FEEDBACK_HITS.value == h0          # nothing to consume yet
-    assert s._feedback.stamp("q9ish") > 0     # ...but it observed
-    out2 = arrow_rows(s.sql(Q, label="q9ish"))
+    assert s._feedback.stamp("lowcard") > 0     # ...but it observed
+    out2 = arrow_rows(s.sql(Q, label="lowcard"))
     assert FEEDBACK_HITS.value == h0 + 1      # profile consumed
     assert ADAPTIVE_REPLANS.value == a0 + 1   # stamp-driven re-plan
-    out3 = arrow_rows(s.sql(Q, label="q9ish"))  # steady state: replay
+    out3 = arrow_rows(s.sql(Q, label="lowcard"))  # steady state: replay
     assert FEEDBACK_HITS.value == h0 + 1
     assert out2 == ref and out3 == ref
     # the observed profile needs the MINIMAL bucket, not the morsel one
-    cells = cap_cells(s._feedback, "q9ish", "big")
+    cells = cap_cells(s._feedback, "lowcard", "big")
     assert all(c <= 8 for row in cells for c in row)
-    applied = s._feedback.applied["q9ish"]
+    applied = s._feedback.applied["lowcard"]
     assert applied["cap_cells_after"] * 100 <= applied["cap_cells_before"]
 
 

@@ -118,3 +118,62 @@ def test_mesh_sharded_compiled_run():
     assert fact_keys
     spec = ex._scan_cache[fact_keys[0]].cols[0].data.sharding.spec
     assert len(spec) == 1 and spec[0] == "shards"
+
+
+# -- keyless aggregates: the group count is a static 1 -----------------------
+
+def keyless_table(n: int) -> pa.Table:
+    rng = np.random.default_rng(3)
+    return pa.table({
+        "g": pa.array(rng.integers(0, 5, n), type=pa.int32()),
+        "h": pa.array(rng.integers(0, 3, n), type=pa.int32()),
+        "v": pa.array(rng.integers(0, 1000, n), type=pa.int64())})
+
+
+def keyless_expected(t: pa.Table, shape: str) -> list:
+    """The answers by plain numpy, independent of the engine."""
+    g, h, v = (t.column(c).to_numpy() for c in ("g", "h", "v"))
+    if shape == "resident":
+        m = v < 500
+        return [(int(m.sum()), int(v[m].sum()), float(v[m].mean()),
+                 int(v[m].min()))]
+    if shape == "resident_empty":
+        return [(0, None, None, None)]
+    rows = [(None, None, len(v), int(v.sum()))]       # the grand total
+    for gi in np.unique(g):
+        rows.append((int(gi), None, int((g == gi).sum()),
+                     int(v[g == gi].sum())))
+        for hi in np.unique(h[g == gi]):
+            m = (g == gi) & (h == hi)
+            rows.append((int(gi), int(hi), int(m.sum()), int(v[m].sum())))
+    return rows
+
+
+KEYLESS_SQL = {
+    "resident": "SELECT COUNT(*) c, SUM(v) sv, AVG(v) av, MIN(v) lo "
+                "FROM t WHERE v < 500",
+    "resident_empty": "SELECT COUNT(*) c, SUM(v) sv, AVG(v) av, MIN(v) lo "
+                      "FROM t WHERE v < 0",
+    "rollup_total": "SELECT g, h, COUNT(*) c, SUM(v) sv FROM t "
+                    "GROUP BY ROLLUP(g, h)",
+}
+
+
+# 20000 rows take _aggregate_sorted (its k == 0 level is _aggregate_one too)
+@pytest.mark.parametrize("n", [2000, 20000])
+@pytest.mark.parametrize("shape", sorted(KEYLESS_SQL))
+def test_keyless_aggregate_resident_and_rollup_grand_total(shape, n):
+    t = keyless_table(n)
+    s = Session()
+    s.register_arrow("t", t)
+    q = KEYLESS_SQL[shape]
+    want = sorted(keyless_expected(t, shape), key=repr)
+    assert sorted(s.sql(q, backend="numpy").to_pylist(), key=repr) == want
+    for mode in ("record", "compile+run", "compiled"):
+        assert sorted(s.sql(q, backend="jax").to_pylist(), key=repr) == want
+    assert s.last_exec_stats["mode"] == "compiled"
+    cq = s._jax_exec._plans[("sql", q)]["cq"]
+    agg_caps = [v for (kind, v), node in zip(cq.decisions, cq.decision_nodes)
+                if kind == "cap" and (node or "").startswith("AggregateNode")]
+    # only the keyed grouping sets decide a capacity: (g, h) and (g)
+    assert agg_caps == ([15, 5] if shape == "rollup_total" else [])

@@ -343,3 +343,66 @@ def test_sf001_nds_queries_sharded_vs_single(tmp_path_factory):
     # at least one bench-slice query must have actually sharded (query9's
     # scalar-subquery battery streams store_sales at this threshold)
     assert streamed_sharded >= 1
+
+
+# -- keyless aggregates under the replica mesh --------------------------------
+# Their group count is a static 1 in the per-replica schedule too: nothing for
+# inflate_schedule(decisions, shard_cap) to raise, bucket(1) partial rows per
+# replica on the all_gather instead of shard_cap.
+
+def keyless_sql(where: str) -> str:
+    return (f"SELECT (SELECT COUNT(*) FROM fact WHERE {where}) AS c, "
+            f"(SELECT SUM(amt) FROM fact WHERE {where}) AS sa, "
+            f"(SELECT AVG(qty) FROM fact WHERE {where}) AS aq "
+            "FROM dim WHERE dk = 0")
+
+
+KEYLESS_WHERE = {
+    "live": "pos >= 0",
+    # the per-replica schedule is recorded on the first morsel's first slice
+    "recorded_slice_all_filtered_out": f"pos >= {CHUNK}",
+    "empty_input": "pos < 0",
+}
+
+
+@pytest.mark.parametrize("fuse", [True, False], ids=["fused", "per_member"])
+@pytest.mark.parametrize("case", sorted(KEYLESS_WHERE))
+def test_keyless_aggregates_under_two_shards(data, case, fuse, monkeypatch):
+    from nds_tpu.engine.jax_backend.executor import JaxExecutor
+    fact = data["fact"].append_column(
+        "pos", pa.array(np.arange(N_FACT), type=pa.int32()))
+    sql = keyless_sql(KEYLESS_WHERE[case])
+    pos = np.arange(N_FACT)
+    m = {"live": pos >= 0, "recorded_slice_all_filtered_out": pos >= CHUNK,
+         "empty_input": pos < 0}[case]
+    qty = np.array([np.nan if q is None else q
+                    for q in fact.column("qty").to_pylist()])[m]
+    amt = fact.column("amt").to_numpy()[m]
+    want = [(int(m.sum()), int(amt.sum()) if m.any() else None,
+             float(np.nanmean(qty)) if m.any() else None)]
+
+    cap_nodes = []
+    decide_cap = JaxExecutor._decide_cap
+
+    def spy_cap(self, scalar):
+        if self._rec is not None and self._rec.mode == "record":
+            cap_nodes.append(type(self._cur_node).__name__)
+        return decide_cap(self, scalar)
+
+    monkeypatch.setattr(JaxExecutor, "_decide_cap", spy_cap)
+    cfg = {} if fuse else {"stream_fusion_max_branches": 1}
+    s = make_session(data, mesh_shards=2, fact=fact, **cfg)
+    assert rows_of(s.sql(sql, backend="numpy")) == want
+    got = s.sql(sql, backend="jax", label=f"keyless2_{case}")
+    st = dict(s.last_exec_stats)
+    assert rows_of(got) == want
+    assert st["mode"] == "streaming" and st["mesh_shards"] == 2
+    assert st["fused_groups"] == (1 if fuse else 0)
+    assert st.get("re_records", 0) == 0
+    assert cap_nodes and "AggregateNode" not in cap_nodes, cap_nodes
+    # 3 members of bucket(1) = 8 partial rows a replica, a few columns each:
+    # under 1 KB a morsel where shard_cap-sized partials moved over 100 KB
+    assert 0 < st["collective_bytes"] < 1024 * st["morsels"]
+    single, st0 = run(data, sql, mesh_shards=0, fact=fact,
+                      label=f"keyless0_{case}", **cfg)
+    assert rows_of(single) == want and "mesh_shards" not in st0

@@ -715,3 +715,47 @@ def test_bench_report_schema_version_and_host_capture():
         "*********(redacted)"
     rep.record_metrics({"queries_run": 2})
     assert rep.summary["metrics"] == {"queries_run": 2}
+
+
+def test_bytes_fetched_counts_what_a_dispatch_returns(monkeypatch):
+    """bytes_fetched moves by the nbytes of what a program dispatch's
+    device_get returned (result and check scalars), and by exactly zero
+    where no program is dispatched: the host backend, the record pass."""
+    import jax
+    from nds_tpu.engine.jax_backend.executor import CompiledQuery
+    inside, returned, outputs = [], [], []
+    device_get, run = jax.device_get, CompiledQuery.run
+
+    def spy_get(x):
+        out = device_get(x)
+        if inside:
+            returned.append(sum(leaf.nbytes
+                                for leaf in jax.tree_util.tree_leaves(out)))
+        return out
+
+    def spy_run(self, *args, **kw):
+        inside.append(self)
+        try:
+            outputs.append(run(self, *args, **kw))
+            return outputs[-1]
+        finally:
+            inside.pop()
+
+    monkeypatch.setattr(jax, "device_get", spy_get)
+    monkeypatch.setattr(CompiledQuery, "run", spy_run)
+    s = make_session()
+    before = om.BYTES_FETCHED.value
+    s.sql(QUERY, backend="numpy")
+    s.sql(QUERY, backend="jax")                 # the record pass
+    assert om.BYTES_FETCHED.value == before and returned == []
+    for dispatch in (1, 2):
+        out = s.sql(QUERY, backend="jax")
+        assert len(returned) == dispatch
+        assert om.BYTES_FETCHED.value - before == sum(returned)
+    # the output table (7 groups in bucket(7) = 8 rows) and one i32 check
+    # scalar per decision of the schedule
+    cq = s._jax_exec._plans[("sql", QUERY)]["cq"]
+    assert out.num_rows == 7 and outputs[-1].capacity == 8
+    assert returned[-1] == 4 * len(cq.decisions) + sum(
+        leaf.nbytes for leaf in jax.tree_util.tree_leaves(outputs[-1]))
+    assert "bytes_fetched" in om.METRICS.describe()

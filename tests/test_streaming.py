@@ -327,3 +327,145 @@ def test_not_in_big_build_streaming(tmp_path):
     streamed = s.sql(q, backend="jax")
     assert s.last_exec_stats["mode"] == "streaming"
     assert rows_of(oracle) == rows_of(streamed)
+
+
+# -- keyless aggregates: one group by construction ---------------------------
+# A q9-class statement: scalar subqueries with no GROUP BY over the streamed
+# table. Their group count is a static 1, not a recorded capacity decision, so
+# inflate_schedule has nothing to raise to the morsel bound and the compiled
+# morsel program sums exact integers by the masked reduce, into bucket(1) rows.
+
+KEYLESS_MORSELS = 3
+# morsel i holds pos in [i * CHUNK, (i + 1) * CHUNK): a predicate on pos
+# filters whole morsels out — the first is the one the schedule is recorded on
+KEYLESS_WHERE = {
+    "every_morsel_live": "pos >= 0",
+    "recorded_morsel_all_filtered_out": f"pos >= {CHUNK}",
+    "last_morsel_all_filtered_out": f"pos < {2 * CHUNK}",
+    "empty_input": "pos < 0",
+}
+
+
+def keyless_session(fuse: bool) -> Session:
+    from decimal import Decimal
+    n = KEYLESS_MORSELS * CHUNK
+    rng = np.random.default_rng(26)
+    qty = rng.integers(1, 100, n).astype(object)
+    qty[rng.random(n) < 0.05] = None
+    cents = rng.integers(0, 100_000, n)
+    sales = pa.table({
+        "pos": pa.array(np.arange(n), type=pa.int32()),
+        "qty": pa.array(list(qty), type=pa.int32()),
+        "amt": pa.array([Decimal(int(c)).scaleb(-2) for c in cents],
+                        type=pa.decimal128(7, 2)),
+        "price": pa.array(np.round(rng.uniform(1, 100, n), 2)),
+    })
+    cfg = EngineConfig(out_of_core=True, chunk_rows=CHUNK,
+                       out_of_core_min_rows=10_000, decimal_physical="i64",
+                       stream_fusion_max_branches=0 if fuse else 1)
+    s = Session(cfg)
+    s.register_arrow("sales", sales)
+    s.register_arrow("one", pa.table({"k": pa.array([1], type=pa.int32())}))
+    return s
+
+
+def keyless_query(where: str, value: str = "amt") -> str:
+    return f"""
+    SELECT (SELECT COUNT(*) FROM sales WHERE {where}) AS c,
+           (SELECT COUNT(qty) FROM sales WHERE {where} AND qty < 50) AS cq,
+           (SELECT SUM(qty) FROM sales WHERE {where}) AS sq,
+           (SELECT AVG({value}) FROM sales WHERE {where}) AS av,
+           (SELECT MAX(qty) FROM sales WHERE {where} AND qty < 50) AS mx
+    FROM one WHERE k = 1
+    """
+
+
+def run_spied(s: Session, q: str, monkeypatch):
+    """Run `q` streamed; returns (result, the node type of every capacity
+    decision the record passes made, [(program, abstract args, output)] of
+    every morsel dispatch)."""
+    import jax
+    from nds_tpu.engine.jax_backend.executor import (CompiledQuery,
+                                                     JaxExecutor)
+    cap_nodes, dispatches = [], []
+    decide_cap, run = JaxExecutor._decide_cap, CompiledQuery.run
+
+    def spy_cap(self, scalar):
+        if self._rec is not None and self._rec.mode == "record":
+            cap_nodes.append(type(self._cur_node).__name__)
+        return decide_cap(self, scalar)
+
+    def spy_run(self, scans, values=(), **kw):
+        out = run(self, scans, values, **kw)
+        if "/morsel:" in self.label:
+            specs = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                self._args(scans, values))
+            dispatches.append((self, specs, out))
+        return out
+
+    monkeypatch.setattr(JaxExecutor, "_decide_cap", spy_cap)
+    monkeypatch.setattr(CompiledQuery, "run", spy_run)
+    result = s.sql(q, backend="jax")
+    assert s.last_exec_stats["mode"] == "streaming"
+    assert s.last_exec_stats["morsels"] == KEYLESS_MORSELS
+    assert s.last_exec_stats.get("re_records", 0) == 0
+    return result, cap_nodes, dispatches
+
+
+def agg_apply_ops(dispatches) -> set:
+    """op_names under AggregateNode#k/agg_apply in the compiled text of
+    every distinct morsel program that was dispatched."""
+    import re
+    ops = set()
+    for cq, specs in {id(cq): (cq, specs)
+                      for cq, specs, _ in dispatches}.values():
+        text = cq._fn.lower(*specs).compile().as_text()
+        ops |= {o for o in re.findall(r'op_name="([^"]*)"', text)
+                if re.search(r"/AggregateNode#\d+/agg_apply/", o)}
+    return ops
+
+
+@pytest.mark.parametrize("fuse", [True, False],
+                         ids=["fused", "per_member"])
+@pytest.mark.parametrize("case", sorted(KEYLESS_WHERE))
+def test_keyless_aggregates_record_no_group_capacity(case, fuse,
+                                                     monkeypatch):
+    from nds_tpu.engine.jax_backend.device import bucket
+    s = keyless_session(fuse)
+    q = keyless_query(KEYLESS_WHERE[case])
+    oracle = s.sql(q, backend="numpy")
+    streamed, cap_nodes, dispatches = run_spied(s, q, monkeypatch)
+    assert rows_of(streamed) == rows_of(oracle)
+    if case == "empty_input":
+        # the empty input's one row: counts 0, every other aggregate NULL
+        assert rows_of(streamed) == [(0, 0, None, None, None)]
+    # the schedule holds the filters' compactions and nothing of the
+    # aggregates, so there is nothing for inflate_schedule to raise
+    assert cap_nodes and "AggregateNode" not in cap_nodes, cap_nodes
+    members = 5
+    assert len(dispatches) == KEYLESS_MORSELS * (1 if fuse else members)
+    partials = [t for _cq, _specs, out in dispatches
+                for t in (out if fuse else [out])]
+    assert len(partials) == KEYLESS_MORSELS * members
+    assert {t.capacity for t in partials} == {bucket(1)}
+    ops = agg_apply_ops(dispatches)
+    assert any("reduce" in o for o in ops), ops
+    assert not [o for o in ops if "scatter" in o], ops
+
+
+def test_keyless_float_aggregate_keeps_segment_sum_into_one_bucket(
+        monkeypatch):
+    """A float operand keeps jax.ops.segment_sum in record and replay alike
+    (the ULP rule above kernels._MASKED_SEG_MAX): the keyless change gives it
+    bucket(1) segments instead of the morsel bound, and no other path."""
+    from nds_tpu.engine.jax_backend.device import bucket
+    s = keyless_session(fuse=True)
+    q = keyless_query("pos >= 0", value="price")
+    oracle = s.sql(q, backend="numpy")
+    streamed, cap_nodes, dispatches = run_spied(s, q, monkeypatch)
+    assert rows_of(streamed) == rows_of(oracle)
+    assert "AggregateNode" not in cap_nodes
+    assert {t.capacity for _cq, _specs, out in dispatches
+            for t in out} == {bucket(1)}
+    assert [o for o in agg_apply_ops(dispatches) if "scatter" in o]

@@ -17,10 +17,15 @@ parallel/mesh.make_mesh):
   the shard_map boundary is the collective), producing device-local
   partial aggregates. A second compiled program — dist_ops.gather_partials
   — is the morsel's ONE collective: a tiled all_gather of the bounded
-  decomposed partials, with spans of its own
-  (`<query>/gather:<table>@mesh<n>`) and a module of its own on the device
+  decomposed partials, with a span of its own (`collective`, labelled
+  `<query>/gather:<table>@mesh<n>`) and a module of its own on the device
   trace (`jit_nds_<query>_morsel_<table>_gather` beside `..._local`), so
-  collective time and bytes are first-class numbers.
+  collective time and bytes are first-class numbers. The `collective` span
+  covers the gather program alone: its dispatch and, while the tracer is
+  on, the wait for its result. The gathered partials' copy to the host is
+  an `exec.fetch` span after it, as on the one-chip path. Untraced,
+  nothing waits between the two, so `ExecStats.collective_ms` stays the
+  wall from the gather's dispatch to its result on the host.
 
 The host-side final merge is unchanged: gathered per-replica partials are
 just more rows of the same partial schema streaming's _decompose /
@@ -246,7 +251,8 @@ class ShardedMorselQuery:
         """Dispatch the local program + the partial gather for one morsel;
         returns the host partial DTable (or tuple, fused groups) whose rows
         are the concatenation of every replica's partial block. `stats`
-        accumulates collective_bytes / collective_ms / local device_ms."""
+        accumulates collective_bytes / collective_ms (gather dispatch to
+        result on the host) / local device_ms."""
         from ...resilience import FAULTS
 
         with self._lock:
@@ -282,14 +288,19 @@ class ShardedMorselQuery:
             for leaf in jax.tree_util.tree_leaves(out)
             if hasattr(leaf, "size"))
         coll_bytes = sharded_bytes * (self.n_shards - 1) // self.n_shards
-        with TRACER.span("collective", cat="device",
-                         label=self.gather_label, bytes=coll_bytes):
-            t2 = time.perf_counter()
-            with jax.profiler.TraceAnnotation(self.gather_label):
+        t2 = time.perf_counter()
+        with jax.profiler.TraceAnnotation(self.gather_label):
+            with TRACER.span("collective", cat="device",
+                             label=self.gather_label, bytes=coll_bytes):
                 merged = self._gather(out)
+                if TRACER.enabled:
+                    jax.block_until_ready(merged)
+            # the gathered partials' copy to the host is a fetch like the
+            # one-chip path's, not part of the collective
+            with TRACER.span("exec.fetch", cat="device"):
                 out_host = jax.device_get(merged)
-            t3 = time.perf_counter()
-            count_fetched(out_host)
+        t3 = time.perf_counter()
+        count_fetched(out_host)
         if stats is not None:
             stats["collective_bytes"] = \
                 stats.get("collective_bytes", 0) + coll_bytes

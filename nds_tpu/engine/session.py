@@ -1174,6 +1174,10 @@ class Session:
             _metrics.MORSELS.inc(stats.morsels)
         if stats.bytes_uploaded:
             _metrics.BYTES_UPLOADED.inc(stats.bytes_uploaded)
+        if stats.re_records:
+            _metrics.MORSEL_RE_RECORDS.inc(stats.re_records)
+        if stats.collective_bytes:
+            _metrics.COLLECTIVE_BYTES.inc(stats.collective_bytes)
         if stats.host_decode_ms:
             # the staging-thread wall, registry-visible per process (the
             # per-table split stays in the stats record)
@@ -1773,6 +1777,7 @@ class Session:
                 # the next sighting provisions for what was seen.
                 free_dtable(jexec._scan_cache_rec.pop(mkey, None))
                 re_records += 1
+                _metrics.REPLAY_MISMATCHES.inc()
                 if adaptive and state.get("adapted"):
                     _metrics.ADAPTIVE_REPLANS.inc()
                     from ..obs.flight import FLIGHT

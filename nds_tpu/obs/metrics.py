@@ -602,6 +602,13 @@ STREAM_RESTARTS = METRICS.counter(
     "stream_restarts", "throughput stream attempts beyond the first")
 REPLAY_MISMATCHES = METRICS.counter(
     "replay_mismatches", "compiled schedules invalidated by capacity drift")
+MORSEL_RE_RECORDS = METRICS.counter(
+    "morsel_re_records", "streamed morsels that overflowed their compiled "
+    "schedule and were re-recorded eagerly by the host record pass: under "
+    "mesh_shards that morsel ran on one chip, not on the mesh")
+COLLECTIVE_BYTES = METRICS.counter(
+    "collective_bytes", "per-chip ingress of the sharded morsels' partial "
+    "all_gathers by the ring model: (n-1)/n of the gathered total")
 # Pallas kernel dispatches (pallas_kernels): counted at build time — once
 # per kernel instantiation under a jit trace, once per call in eager record
 PALLAS_SORT_CALLS = METRICS.counter(

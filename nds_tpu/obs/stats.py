@@ -84,7 +84,8 @@ class ExecStats:
     sharded_groups: Optional[int] = None
     #: per-device ingress of the per-morsel partial all_gathers (ring model)
     collective_bytes: Optional[int] = None
-    #: measured wall of the partial-gather dispatches
+    #: wall from each partial gather's dispatch to its result on the host
+    #: (the `collective` span plus the `exec.fetch` after it)
     collective_ms: Optional[float] = None
     # -- pallas kernels (EngineConfig.pallas_ops) ----------------------------
     #: the validated op subset active for this execution (None = flag off)

@@ -55,8 +55,11 @@ each labelled with the function's name (``jit(nds_query9_root)``).
 
 Span names (``cat``): ``query`` > ``plan`` > ``parse`` / ``plan.pass`` /
 ``plan.verify``; ``record``; ``compile``; ``upload`` / ``lane.pack``;
-``exec`` > ``exec.args`` / ``exec.wait`` / ``exec.fetch``; ``collective``;
-``morsel.stage`` / ``morsel.stage_sharded`` / ``morsel.exec`` /
+``exec`` > ``exec.args`` / ``exec.wait`` / ``exec.fetch``; ``collective``
+(a sharded morsel's gather program alone: its dispatch and, tracer on,
+the wait for its result; the gathered partials' copy to the host is the
+``exec.fetch`` after it); ``morsel.stage`` / ``morsel.stage_sharded`` /
+``morsel.exec`` /
 ``merge.partials`` / ``finalize``; ``system_query``; the service's
 ``service/ticket`` > ``service/queue`` / ``service/plan`` /
 ``service/lane_wait`` / ``service/dispatch`` / ``service/materialize`` /

@@ -47,9 +47,9 @@ BASELINE = os.path.join(REPO, "cicd", "metrics_baseline.json")
 #: is a behavior regression (a replay invalidated, an operator falling
 #: back to host, a staging thread failing), never noise
 STRICT_ZERO = (
-    "replay_mismatches", "host_fallbacks", "query_failures",
-    "prefetch_errors", "fault_point_firings", "service_rejected",
-    "service_deadline_expired", "stream_restarts",
+    "replay_mismatches", "morsel_re_records", "host_fallbacks",
+    "query_failures", "prefetch_errors", "fault_point_firings",
+    "service_rejected", "service_deadline_expired", "stream_restarts",
     # chaos-hardened serving: a CLEAN workload must never trip a breaker
     # or quarantine a program — movement here means the self-healing
     # machinery fired on healthy traffic

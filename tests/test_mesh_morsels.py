@@ -19,7 +19,10 @@ Contracts pinned here:
   force empty pallas_ops" restriction is lifted for the sharded morsel
   path); the GSPMD whole-plan mesh path still records
   pallas_fallback_reason="mesh";
-- collective accounting (collective_bytes / collective_ms) and per-shard
+- collective accounting (collective_bytes / collective_ms, the
+  `collective_bytes` counter, a `collective` span that ends before the
+  fetch begins), a morsel that overflows the sharded schedule re-recorded
+  and counted (`morsel_re_records`, `replay_mismatches`), and per-shard
   device-time attribution labels ("<q>/morsel:<t>@mesh<n>" /
   "<q>/gather:<t>@mesh<n>") are observable;
 - independent SQLite oracle agreement for the sharded path.
@@ -221,6 +224,62 @@ def test_device_time_attribution_labels(data, baseline):
                                 m) for m in modules), modules
     kids = {e["name"] for e in events if e["name"].startswith("exec.")}
     assert kids == {"exec.args", "exec.wait", "exec.fetch"}
+
+
+def test_an_overflowing_sharded_morsel_is_re_recorded_and_counted(
+        data, baseline, monkeypatch):
+    """One morsel's checks overflow the sharded schedule: the session
+    re-records that morsel eagerly (on one chip), the answer stays what the
+    one-chip path gives, and the statement and the registry both say so."""
+    from nds_tpu.engine.jax_backend.executor import ReplayMismatch
+    from nds_tpu.engine.jax_backend.shard_exec import ShardedMorselQuery
+    from nds_tpu.obs.metrics import METRICS
+    verify, raised = ShardedMorselQuery._verify, []
+
+    def overflow_once(self, checks_host):
+        if not raised:
+            raised.append(True)
+            raise ReplayMismatch("sharded capacity overflow: forced")
+        verify(self, checks_host)
+    monkeypatch.setattr(ShardedMorselQuery, "_verify", overflow_once)
+    before = METRICS.snapshot()
+    t, st = run(data, STAR, mesh_shards=4, label="rerec4")
+    moved = METRICS.delta(before)
+    assert raised and rows_of(t) == baseline["star"]
+    assert st["mesh_shards"] == 4 and st["sharded_groups"] == 1
+    assert st["re_records"] == 1
+    assert moved["morsel_re_records"] == 1
+    assert moved["replay_mismatches"] == 1
+    # the re-recorded morsel gathered nothing: the others still did
+    assert moved["collective_bytes"] == st["collective_bytes"] > 0
+
+
+def test_the_collective_span_ends_before_the_fetch_begins(data, baseline):
+    """Tracer on: every `collective` span covers the gather program alone
+    and the gathered partials' copy to the host is the `exec.fetch` after
+    it; the `collective_bytes` counter moves by what the statement says."""
+    from nds_tpu.obs.metrics import METRICS
+    from nds_tpu.obs.trace import TRACER
+    before = METRICS.snapshot()
+    TRACER.configure(enabled=True)
+    try:
+        t, st = run(data, STAR, mesh_shards=4, label="parted4")
+        events = TRACER.events()
+    finally:
+        TRACER.configure(enabled=False)
+    assert rows_of(t) == baseline["star"]
+    assert METRICS.delta(before)["collective_bytes"] == \
+        st["collective_bytes"] > 0
+    spans = sorted((e for e in events if e.get("ph") == "X" and
+                    e["name"] in ("collective", "exec.fetch")),
+                   key=lambda e: e["ts"])
+    gathers = [i for i, e in enumerate(spans) if e["name"] == "collective"]
+    assert len(gathers) == st["morsels"]
+    for i in gathers:
+        # exec.fetch (the checks), collective, exec.fetch (the partials)
+        assert spans[i - 1]["name"] == spans[i + 1]["name"] == "exec.fetch"
+        assert spans[i]["ts"] + spans[i]["dur"] <= spans[i + 1]["ts"]
+        assert spans[i]["args"]["bytes"] > 0
 
 
 def test_sharded_vs_sqlite_oracle(data):

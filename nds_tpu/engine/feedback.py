@@ -25,12 +25,18 @@ Three observation surfaces, one store:
   late-materialization decisions back to what the data actually is.
 - **groups** — per-decision observed MAXIMA of each streamed scan
   group's capacity schedule, merged across every morsel of every
-  sighting (record-pass actuals + replay check scalars). The next
-  sighting right-sizes its capacity-ladder buckets from these instead of
+  sighting (record-pass actuals + replay check scalars: the rows
+  ``Session._stream_group`` collects on every streamed pass, store or
+  no store). A FIRST sighting that finds a structurally matching profile
+  here right-sizes its capacity-ladder buckets from it instead of
   inflating every cap to the morsel bound (``streaming.adapt_schedule``)
   — a low-cardinality GROUP BY drops from the morsel bucket to the
   minimal ladder bucket (a keyless aggregate records no cap to adapt:
-  its one group is static).
+  its one group is static). A repeated sighting within one stream-plan
+  cache entry needs no store for that: the session sizes it from the
+  entry's own first whole pass. What this surface adds is the profile
+  that outlives the entry (a re-registration, a second process) and the
+  drift sentinel below.
 
 Discipline (the house default-off contract):
 
@@ -46,7 +52,7 @@ Discipline (the house default-off contract):
   stale schedule (``feedback_refreshes``; stamp-driven re-records count
   ``adaptive_replans``).
 - ``EngineConfig.adaptive_plans=False`` (the default) never constructs a
-  store: zero new counters, bit-identical plans and schedules.
+  store: the three feedback counters stay strictly zero.
 
 Persistence is one crash-consistent JSON document beside the query log,
 written with the warehouse's atomic-rename discipline

@@ -1647,7 +1647,9 @@ class JaxExecutor:
         """Record/replay a CAPACITY-DEPENDENT structural branch.
 
         Capacities drift between record and replay by design (streaming
-        inflates every cap decision to the morsel bound, inflate_schedule),
+        raises every cap decision to the morsel bound on a first sighting
+        and sizes it from a whole pass's maxima afterwards: inflate_schedule
+        / adapt_schedule),
         so a branch gated on `capacity >= X` must take the RECORDED side
         under replay — both sides are semantically correct, and replaying
         the record-time choice keeps the decision schedule aligned. The

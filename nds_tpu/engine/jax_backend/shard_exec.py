@@ -280,6 +280,13 @@ class ShardedMorselQuery:
                     t1 = time.perf_counter()
                     self._verify(checks_host)
                 count_fetched(checks_host)
+        if stats is not None and "decision_rows" in stats:
+            # index-aligned per-decision actuals, the max over replicas
+            # (what _verify held against each cap), handed out only to a
+            # caller that pre-seeded the key, as CompiledQuery.run does
+            stats["decision_rows"] = [
+                int(np.asarray(a).max()) if np.size(a) else 0
+                for a in checks_host]
         # ONE collective: all_gather of the sharded partial blocks. Bytes
         # model: ring all-gather ingress per device — each replica receives
         # the other n-1 replicas' blocks, (n-1)/n of the gathered total.

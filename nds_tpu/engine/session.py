@@ -1289,8 +1289,10 @@ class Session:
             shared = self._new_stream_executor()
             sent = {"plan": plan, "jobs": jobs, "groups": groups,
                     "exec": shared,
-                    "gstates": [{"cqs": None, "ents": None, "fused": False}
-                                for _ in groups],
+                    # "tight": None until a whole pass has been seen, then
+                    # whether the programs are sized from it (_stream_group)
+                    "gstates": [{"cqs": None, "ents": None, "fused": False,
+                                 "tight": None} for _ in groups],
                     # profile generation this state was planned from: a
                     # later generation move invalidates it (drift sentinel)
                     "fb_stamp": self._feedback.stamp(self._active_label)
@@ -1525,8 +1527,7 @@ class Session:
         return arrow_bridge.to_arrow(out)
 
     def _stream_group(self, group, shared: dict, state: dict,
-                      sinks: list, prefetch_errs: list,
-                      shard_stats: Optional[dict] = None):
+                      sinks: list, prefetch_errs: list, shard_stats: dict):
         """Morsel loop for one shared-scan group: ONE morsel iterator and
         ONE double-buffered upload per morsel serve EVERY member branch (a
         worker thread packs + stages morsel i+1 while the device runs
@@ -1549,7 +1550,23 @@ class Session:
         replays the same recorded per-morsel schedule on its rows inside
         shard_map, and one all_gather moves the bounded decomposed
         partials before the unchanged host merge
-        (jax_backend/shard_exec.ShardedMorselQuery). Returns (morsels,
+        (jax_backend/shard_exec.ShardedMorselQuery).
+        The group's schedule is the morsel bound only until one whole pass
+        has been seen. On the first sighting every cap of the recorded
+        schedule(s) is raised to the bound (streaming.inflate_schedule: one
+        program has to serve morsels nobody has seen), and every replay's
+        check scalars — on the host anyway, for the schedule check — are
+        max-merged into `state["obs"]`, per member, index-aligned with the
+        decisions. When the loop has reached the end of the table with no
+        ReplayMismatch, the programs are replaced by ones whose caps are
+        those maxima (tighten): `state` lives in a stream-cache entry keyed
+        by the statement's text and the catalog generation, so every later
+        sighting from it meets the same rows and the maxima are exact. The
+        tight programs compile at the second sighting; a statement seen once
+        behaves as it always did. The schedule check stays as the net: a
+        tight replay that overflows re-records that morsel eagerly, and the
+        group goes back to the bound for the rest of the entry's life.
+        Returns (morsels,
         re_records, bytes_uploaded, sharded, host_decode_ms, rows_streamed)
         or None when some member is not device-runnable."""
         import threading
@@ -1578,132 +1595,111 @@ class Session:
         rows_streamed = 0
 
         adaptive = self._feedback is not None and mesh is None
+        # what a replica's (one chip: the morsel's) capacities are bounded by
+        bound = shard_cap if mesh is not None else morsel_rows
 
-        def adapt(decisions_raw, member: int):
-            """One member's replay schedule: morsel-bound inflation, or —
-            when the feedback store holds a structurally matching profile
-            for this (template, table, member) — observed maxima instead
+        def first_schedule(decisions_raw, member: int):
+            """One member's schedule on the group's first sighting: every
+            cap at the bound, or — when the feedback store holds a
+            structurally matching profile for this (template, table,
+            member) — at the profile's observed maxima instead
             (streaming.adapt_schedule; a ceiling hint, ReplayMismatch
-            catches under-observation). Also seeds the per-decision
-            observation row from the record pass's RAW actuals."""
-            kinds = [k for k, _v in decisions_raw]
-            if not adaptive:
-                return streaming.inflate_schedule(decisions_raw,
-                                                  morsel_rows), kinds
-            state.setdefault("kinds", {})[member] = kinds
-            obs_row = [int(v) for _k, v in decisions_raw]
-            prev = state.setdefault("obs", {}).get(member)
-            if prev is not None and len(prev) == len(obs_row):
-                obs_row = [max(a, b) for a, b in zip(prev, obs_row)]
-            state["obs"][member] = obs_row
+            catches under-observation)."""
             caps = self._feedback.member_caps(
-                self._active_label, group.table, member, kinds,
-                morsel_rows, fuse, 0)
-            adapted = streaming.adapt_schedule(decisions_raw, morsel_rows,
-                                               caps)
-            if caps is not None:
-                state["adapted"] = True
-                before = after = 0
-                for (k, v), (_k2, a) in zip(
-                        streaming.inflate_schedule(decisions_raw,
-                                                   morsel_rows), adapted):
-                    if k == "cap":
-                        before += bucket(max(int(v), 1))
-                        after += bucket(max(int(a), 1))
-                _metrics.FEEDBACK_HITS.inc()
-                from ..obs.flight import FLIGHT
-                FLIGHT.record("feedback_hit", label=self._active_label,
-                              table=group.table, member=member,
-                              cells_before=before, cells_after=after)
-                self._feedback.note_applied(self._active_label, before,
-                                            after)
-            return adapted, kinds
+                self._active_label, group.table, member,
+                state["kinds"][member], morsel_rows, fuse, 0) \
+                if adaptive else None
+            inflated = streaming.inflate_schedule(decisions_raw, bound)
+            if caps is None:
+                return inflated
+            adapted = streaming.adapt_schedule(decisions_raw, bound, caps)
+            state["adapted"] = True
+            before, after = (
+                sum(c for k, c in streaming.schedule_shape(d) if k == "cap")
+                for d in (inflated, adapted))
+            _metrics.FEEDBACK_HITS.inc()
+            from ..obs.flight import FLIGHT
+            FLIGHT.record("feedback_hit", label=self._active_label,
+                          table=group.table, member=member,
+                          cells_before=before, cells_after=after)
+            self._feedback.note_applied(self._active_label, before, after)
+            return adapted
 
-        def record_first(morsel) -> bool:
-            if mesh is not None:
-                return record_first_sharded(morsel)
-            current["table"] = morsel
-            jexec.fallback_nodes = []
-            if fuse:
-                _outs, decisions, scan_keys = jexec.record_plans(group.plans)
-                if jexec.fallback_nodes:
-                    return False
-                decisions, _kinds = adapt(decisions, 0)
-                state["cqs"] = [CompiledQuery(
-                    list(group.plans), decisions, scan_keys,
-                    mesh=jexec._mesh,
-                    shard_min_rows=jexec._shard_min_rows,
-                    label=f"{self._active_label}/morsel:{group.table}",
-                    pallas_ops=jexec._pallas_ops,
-                    name_fingerprint=self._name_fingerprint(
-                        list(group.plans)))]
-                state["ents"] = [{"scan_keys": scan_keys}]
-            else:
-                # fusion over budget (or single member): per-member
-                # programs, each with its own schedule, all resolving the
-                # shared staged buffer through the same morsel scan key
-                cqs, ents = [], []
-                for bi, p in enumerate(group.plans):
-                    _out, decisions, scan_keys = jexec.record_plan(p)
-                    if jexec.fallback_nodes:
-                        return False
-                    decisions, _kinds = adapt(decisions, bi)
+        def build(schedules: list) -> list:
+            """The group's programs from one schedule per member (fused:
+            one program over every plan): shard_map-dispatched under a
+            mesh, each with its own schedule, all resolving the shared
+            staged buffer through the same morsel scan key."""
+            plans = [list(group.plans)] if fuse else list(group.plans)
+            cqs = []
+            for bi, (p, decisions) in enumerate(zip(plans, schedules)):
+                label = f"{self._active_label}/morsel:{group.table}" + \
+                    ("" if fuse else f"#{bi}")
+                scan_keys = state["ents"][bi]["scan_keys"]
+                fp = self._name_fingerprint(p)
+                if mesh is not None:
+                    from .jax_backend.shard_exec import ShardedMorselQuery
+                    cqs.append(ShardedMorselQuery(
+                        p, decisions, scan_keys, mesh, mkey, label=label,
+                        pallas_ops=jexec._pallas_ops, name_fingerprint=fp))
+                else:
                     cqs.append(CompiledQuery(
                         p, decisions, scan_keys, mesh=jexec._mesh,
-                        shard_min_rows=jexec._shard_min_rows,
-                        label=f"{self._active_label}/morsel:"
-                              f"{group.table}#{bi}",
-                        pallas_ops=jexec._pallas_ops,
-                        name_fingerprint=self._name_fingerprint(p)))
-                    ents.append({"scan_keys": scan_keys})
-                state["cqs"], state["ents"] = cqs, ents
-            state["fused"] = fuse
-            return True
+                        shard_min_rows=jexec._shard_min_rows, label=label,
+                        pallas_ops=jexec._pallas_ops, name_fingerprint=fp))
+            return cqs
 
-        def record_first_sharded(morsel) -> bool:
-            """Record the per-REPLICA schedule on a representative shard-
-            sized slice of the first morsel (shard-local gates: no data-
+        def record_first(morsel) -> bool:
+            """Record the schedule(s) on the first morsel and build the
+            group's first programs. Under a mesh the record pass runs on a
+            representative shard-sized slice (shard-local gates: no data-
             dependent tier probes, so later replicas/morsels verify against
-            capacity bounds only) and build the shard_map-dispatched
-            ShardedMorselQuery program(s)."""
-            from .jax_backend.shard_exec import ShardedMorselQuery
-            spans = streaming.partition_morsel_rows(morsel.num_rows,
-                                                    n_shards)
-            current["table"] = morsel.slice(0, spans[0][1])
+            capacity bounds only). The raw decisions stay in the state:
+            they seed the observed maxima and are what a later schedule is
+            built from (tighten)."""
+            kw = {}
+            current["table"] = morsel
+            if mesh is not None:
+                spans = streaming.partition_morsel_rows(morsel.num_rows,
+                                                        n_shards)
+                current["table"] = morsel.slice(0, spans[0][1])
+                kw = {"shard_local": True}
             jexec.fallback_nodes = []
-            ops = jexec._pallas_ops
             if fuse:
-                _o, decisions, scan_keys = jexec.record_plans(
-                    group.plans, shard_local=True)
-                if jexec.fallback_nodes:
-                    return False
-                decisions = streaming.inflate_schedule(decisions, shard_cap)
-                state["cqs"] = [ShardedMorselQuery(
-                    list(group.plans), decisions, scan_keys, mesh, mkey,
-                    label=f"{self._active_label}/morsel:{group.table}",
-                    pallas_ops=ops,
-                    name_fingerprint=self._name_fingerprint(
-                        list(group.plans)))]
-                state["ents"] = [{"scan_keys": scan_keys}]
+                recs = [jexec.record_plans(group.plans, **kw)]
             else:
-                cqs, ents = [], []
-                for bi, p in enumerate(group.plans):
-                    _o, decisions, scan_keys = jexec.record_plan(
-                        p, shard_local=True)
+                # fusion over budget (or single member): per-member programs
+                recs = []
+                for p in group.plans:
+                    recs.append(jexec.record_plan(p, **kw))
                     if jexec.fallback_nodes:
                         return False
-                    decisions = streaming.inflate_schedule(decisions,
-                                                           shard_cap)
-                    cqs.append(ShardedMorselQuery(
-                        p, decisions, scan_keys, mesh, mkey,
-                        label=f"{self._active_label}/morsel:"
-                              f"{group.table}#{bi}",
-                        pallas_ops=ops,
-                        name_fingerprint=self._name_fingerprint(p)))
-                    ents.append({"scan_keys": scan_keys})
-                state["cqs"], state["ents"] = cqs, ents
-            state["fused"] = fuse
+            if jexec.fallback_nodes:
+                return False
+            raws = [decisions for _out, decisions, _keys in recs]
+            ents = [{"scan_keys": keys} for _out, _decisions, keys in recs]
+            state["raw"], state["ents"], state["fused"] = raws, ents, fuse
+            state["kinds"] = [[k for k, _v in d] for d in raws]
+            state["obs"] = [[int(v) for _k, v in d] for d in raws]
+            state["cqs"] = build([first_schedule(d, bi)
+                                  for bi, d in enumerate(raws)])
             return True
+
+        def tighten() -> None:
+            """A whole clean pass has been seen: for as long as this cache
+            entry lives the statement meets the same rows, so the pass's
+            per-decision maxima are exact for every later replay. Replace
+            the programs by ones whose caps are those maxima
+            (streaming.adapt_schedule); they compile at the next sighting.
+            Where no capacity bucket would change the programs stay."""
+            schedules = [streaming.adapt_schedule(d, bound, o)
+                         for d, o in zip(state["raw"], state["obs"])]
+            state["tight"] = any(
+                streaming.schedule_shape(s) !=
+                streaming.schedule_shape(cq.decisions)
+                for s, cq in zip(schedules, state["cqs"]))
+            if state["tight"]:
+                state["cqs"] = build(schedules)
 
         def stage(morsel):
             """Pack + upload one union-column morsel into a fresh buffer
@@ -1730,23 +1726,21 @@ class Session:
         def merge_obs(member: int, actuals) -> None:
             """Elementwise max-merge one replay/record pass's per-decision
             actuals into the group's observation rows."""
-            if not adaptive or actuals is None:
-                return
             row = [int(a) for a in actuals]
-            prev = state.setdefault("obs", {}).get(member)
-            if prev is not None and len(prev) == len(row):
+            prev = state["obs"][member]
+            if len(prev) == len(row):
                 row = [max(a, b) for a, b in zip(prev, row)]
             state["obs"][member] = row
 
         def run_one(member: int, cq, ent):
-            """One member dispatch; under adaptation the pre-seeded
-            decision_rows sentinel pulls the replay's raw check scalars
-            back out (the per-decision actuals the feedback store merges)."""
-            if not adaptive:
-                return cq.run(jexec._scans_for(ent))
-            st = {"decision_rows": None}
+            """One member dispatch. The pre-seeded decision_rows key pulls
+            the replay's check scalars back out (under a mesh the max over
+            replicas): the host has fetched them for the schedule check
+            anyway, so observing costs no copy and no sync."""
+            st = shard_stats if mesh is not None else {}
+            st["decision_rows"] = None
             out = cq.run(jexec._scans_for(ent), stats=st)
-            merge_obs(member, st.get("decision_rows"))
+            merge_obs(member, st.pop("decision_rows"))
             return out
 
         def run_members():
@@ -1755,29 +1749,34 @@ class Session:
             group.plans order."""
             nonlocal re_records
             try:
-                if mesh is not None:
-                    if state["fused"]:
-                        return list(state["cqs"][0].run(
-                            jexec._scans_for(state["ents"][0]),
-                            stats=shard_stats))
-                    return [cq.run(jexec._scans_for(ent), stats=shard_stats)
-                            for cq, ent in zip(state["cqs"], state["ents"])]
                 if state["fused"]:
-                    return list(run_one(0, state["cqs"][0],
+                    outs = list(run_one(0, state["cqs"][0],
                                         state["ents"][0]))
-                return [run_one(bi, cq, ent)
-                        for bi, (cq, ent) in enumerate(zip(state["cqs"],
-                                                           state["ents"]))]
+                else:
+                    outs = [run_one(bi, cq, ent)
+                            for bi, (cq, ent) in enumerate(zip(
+                                state["cqs"], state["ents"]))]
+                if state["tight"]:
+                    _metrics.TIGHT_MORSEL_REPLAYS.inc()
+                return outs
             except ReplayMismatch:
                 # a morsel genuinely exceeded the schedule (the inflated
-                # bound, or an adapted ceiling hint a grown actual
-                # overflowed): run it eagerly after evicting stale
-                # record-side buffers — correctness never depends on the
-                # hint. The fresh record pass's actuals feed the store so
-                # the next sighting provisions for what was seen.
+                # bound, a tightened cap, or an adapted ceiling hint a
+                # grown actual overflowed): run it eagerly after evicting
+                # stale record-side buffers — correctness never depends on
+                # the schedule. The fresh record pass's actuals feed the
+                # store so the next sighting provisions for what was seen.
                 free_dtable(jexec._scan_cache_rec.pop(mkey, None))
                 re_records += 1
                 _metrics.REPLAY_MISMATCHES.inc()
+                if state["tight"]:
+                    # what the whole pass saw did not hold: back to the
+                    # bound for the rest of this cache entry's life
+                    state["cqs"] = build([
+                        streaming.inflate_schedule(d, bound)
+                        for d in state["raw"]])
+                # a pass with a mismatch sizes nothing, now or later
+                state["tight"] = False
                 if adaptive and state.get("adapted"):
                     _metrics.ADAPTIVE_REPLANS.inc()
                     from ..obs.flight import FLIGHT
@@ -1787,19 +1786,17 @@ class Session:
                                   reason="schedule_overflow")
                 if state["fused"]:
                     outs, d2, _ = jexec.record_plans(group.plans)
-                    if adaptive:
-                        state.setdefault("kinds", {})[0] = \
-                            [k for k, _v in d2]
-                    merge_obs(0, [int(v) for _k, v in d2])
-                    return outs
-                outs = []
-                for bi, p in enumerate(group.plans):
-                    out, d2, _ = jexec.record_plan(p)
-                    if adaptive:
-                        state.setdefault("kinds", {})[bi] = \
-                            [k for k, _v in d2]
-                    merge_obs(bi, [int(v) for _k, v in d2])
-                    outs.append(out)
+                    recorded = [d2]
+                else:
+                    outs, recorded = [], []
+                    for p in group.plans:
+                        out, d2, _ = jexec.record_plan(p)
+                        outs.append(out)
+                        recorded.append(d2)
+                if adaptive:
+                    for bi, d2 in enumerate(recorded):
+                        state["kinds"][bi] = [k for k, _v in d2]
+                        merge_obs(bi, [v for _k, v in d2])
                 return outs
 
         staged = {}
@@ -1881,17 +1878,17 @@ class Session:
             current.pop("table", None)
         if count == 0:
             return None   # empty source: the in-core path handles it
-        if adaptive and state.get("obs"):
+        if state["tight"] is None:      # a whole pass, and no mismatch
+            tighten()
+        if adaptive:
             # the group's observed schedule profile: per-member per-
             # decision maxima across every morsel of this pass (record
             # actuals + replay check scalars), keyed on the program
             # structure so only a like-for-like sighting consumes it
-            members = sorted(state["obs"])
             self._feedback.observe_group(
                 self._active_label, group.table, bound=morsel_rows,
                 fused=state["fused"], shards=0,
-                kinds=[state["kinds"][m] for m in members],
-                caps=[state["obs"][m] for m in members])
+                kinds=state["kinds"], caps=state["obs"])
         return (count, re_records, bytes_uploaded, mesh is not None,
                 host_ms, rows_streamed)
 

@@ -831,28 +831,46 @@ def inflate_schedule(decisions: list, morsel_cap: int) -> list:
     """Round every capacity decision up to the morsel bound so ONE compiled
     program serves every morsel (filters/joins against unique dimension keys
     cannot exceed the morsel row count; a genuine expansion beyond it is
-    caught by the schedule check and re-recorded)."""
+    caught by the schedule check and re-recorded). The schedule of a scan
+    group's FIRST sighting, when nothing is known of the later morsels;
+    once one whole pass has been seen, adapt_schedule takes over."""
     return [(kind, max(int(v), morsel_cap) if kind == "cap" else v)
             for kind, v in decisions]
 
 
 def adapt_schedule(decisions: list, morsel_cap: int,
                    observed) -> list:
-    """Feedback-driven inflate_schedule (EngineConfig.adaptive_plans):
-    each cap decision is clamped to the LARGER of its record-pass actual
-    and the feedback store's observed maximum for that decision, instead
-    of the morsel bound — a grouped aggregate of low cardinality (GROUP BY
-    a 5-value key) then provisions the minimal ladder bucket, not the
-    morsel bucket, and every downstream gather shrinks with it. (A keyless
-    aggregate records no cap at all: its one group is static.)
-    ``observed`` is the index-aligned per-decision maxima
-    (FeedbackStore.member_caps); None (or a length-drifted list — a
-    structurally different schedule) falls back to plain morsel-bound
-    inflation. An observed cap is a CEILING HINT:
-    a later morsel exceeding it fails the replay's schedule check
-    (ReplayMismatch) and re-records eagerly, so under-observation costs a
-    re-record, never a wrong answer."""
+    """inflate_schedule from what was seen: each cap decision is the
+    LARGER of its record-pass actual and the observed maximum for that
+    decision, instead of the morsel bound — a grouped aggregate of low
+    cardinality (GROUP BY a 5-value key) then provisions the minimal
+    ladder bucket, not the morsel bucket, a join behind a selective
+    filter its survivors, and every downstream gather and scatter shrinks
+    with them. (A keyless aggregate records no cap at all: its one group
+    is static.) Exact decisions, recorded branches among them, stay.
+    ``observed`` is the index-aligned per-decision maxima; None (or a
+    length-drifted list — a structurally different schedule) falls back to
+    plain morsel-bound inflation.
+
+    Two callers. Session._stream_group, always: the maxima of one whole
+    pass over a stream-cache entry's rows, which are EXACT for every later
+    replay from that entry (the entry dies with the catalog generation).
+    And the feedback store (EngineConfig.adaptive_plans,
+    FeedbackStore.member_caps), whose persisted maxima let a first
+    sighting start tight: there an observed cap is a CEILING HINT. Either
+    way a morsel exceeding a cap fails the replay's schedule check
+    (ReplayMismatch) and re-records eagerly, so a cap that is too small
+    costs a re-record, never a wrong answer."""
     if observed is None or len(observed) != len(decisions):
         return inflate_schedule(decisions, morsel_cap)
     return [(kind, max(int(v), int(o)) if kind == "cap" else v)
             for (kind, v), o in zip(decisions, observed)]
+
+
+def schedule_shape(decisions: list) -> list:
+    """What of a schedule a compiled program's shapes depend on: each
+    cap's ladder bucket, each exact value. Two schedules of one shape
+    compile to the same program."""
+    from .jax_backend.device import bucket
+    return [(kind, bucket(max(int(v), 1)) if kind == "cap" else int(v))
+            for kind, v in decisions]

@@ -606,6 +606,10 @@ MORSEL_RE_RECORDS = METRICS.counter(
     "morsel_re_records", "streamed morsels that overflowed their compiled "
     "schedule and were re-recorded eagerly by the host record pass: under "
     "mesh_shards that morsel ran on one chip, not on the mesh")
+TIGHT_MORSEL_REPLAYS = METRICS.counter(
+    "tight_morsel_replays", "streamed morsels replayed by programs whose "
+    "capacities are what the statement's first whole pass saw, not the "
+    "morsel bound (a second or later sighting)")
 COLLECTIVE_BYTES = METRICS.counter(
     "collective_bytes", "per-chip ingress of the sharded morsels' partial "
     "all_gathers by the ring model: (n-1)/n of the gathered total")

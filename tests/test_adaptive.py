@@ -1,5 +1,13 @@
-"""Adaptive execution (ISSUE 17): the feedback stats store closes the
-loop from observed actuals back into plans.
+"""Adaptive execution (ISSUE 17; restated by ISSUE 29): the feedback
+stats store closes the loop from observed actuals back into plans.
+
+Since ISSUE 29 a streamed statement's SECOND sighting is sized from what
+its first whole pass saw whether or not ``adaptive_plans`` is on
+(``Session._stream_group``; tests/test_streaming.py holds that). What the
+option adds, and what this file holds, is the store: a profile that
+outlives the stream-plan cache entry, so that a FIRST sighting (after a
+re-registration, in a second process) starts tight, and its drift
+sentinel.
 
 Acceptance-backed properties — all COUNT-shaped or bit-identity (no wall
 budgets: this host is 1-core and timing tests flake):
@@ -7,8 +15,9 @@ budgets: this host is 1-core and timing tests flake):
 - **low-cardinality right-sizing**: the first sighting of a streamed
   grouped aggregate (``Q`` groups by the 5-value ``k``) provisions every
   capacity decision at the morsel bucket; the second sighting re-records
-  from observed actuals and provisions the minimal ladder bucket instead — with the response hash-identical across every
-  sighting (right-sizing is provisioning, never results);
+  from the stored profile and provisions the minimal ladder bucket
+  instead — with the response hash-identical across every sighting
+  (right-sizing is provisioning, never results);
 - **ceiling hint, never a correctness input**: a profile observed on
   small data replayed against grown data overflows the adapted schedule,
   raises ReplayMismatch internally, re-records eagerly, and still
@@ -21,10 +30,12 @@ budgets: this host is 1-core and timing tests flake):
   session observed (the PR 15 ring<->JSONL property, one layer up);
 - **off is off**: adaptive_plans=False (the default) builds no store
   and moves feedback_hits / feedback_refreshes / adaptive_replans by
-  exactly zero on a streamed workload;
+  exactly zero on a streamed workload, while its second sighting is
+  tight all the same; on, the store is fed the very rows that sized it;
 - **crash-consistent persistence**: the store round-trips through its
-  atomic JSON document at session attach, and an unreadable document
-  degrades to an empty store instead of refusing to start;
+  atomic JSON document at session attach — a fresh session's first
+  sighting starts tight from it — and an unreadable document degrades
+  to an empty store instead of refusing to start;
 - **system.plan_feedback** serves the store's facts over plain SQL.
 """
 import json
@@ -40,7 +51,7 @@ from nds_tpu.engine.arrow_bridge import to_arrow
 from nds_tpu.engine.feedback import FeedbackStore
 from nds_tpu.engine.streaming import adapt_schedule, inflate_schedule
 from nds_tpu.obs.metrics import (ADAPTIVE_REPLANS, FEEDBACK_HITS,
-                                 FEEDBACK_REFRESHES)
+                                 FEEDBACK_REFRESHES, TIGHT_MORSEL_REPLAYS)
 from nds_tpu.obs.query_log import QUERY_LOG, read_jsonl
 
 Q = "SELECT k, SUM(v) AS sv FROM big GROUP BY k ORDER BY k"
@@ -210,15 +221,46 @@ def test_drift_sentinel_refreshes_stale_profile():
 
 # -- off is off ---------------------------------------------------------------
 
+def group_caps(s: Session) -> list:
+    """Cap values of the morsel programs the session holds for Q."""
+    (state,) = s._stream_cache[Q]["gstates"]
+    return [[v for k, v in cq.decisions if k == "cap"]
+            for cq in state["cqs"]]
+
+
 def test_disabled_mode_builds_no_store_and_moves_no_counters():
+    """Off: no store, the three feedback counters strictly still — and the
+    second sighting tight all the same (ISSUE 29: sized from the first
+    whole pass, which needs no store)."""
     before = counters()
+    t0 = TIGHT_MORSEL_REPLAYS.value
     s = make_session()                # adaptive_plans defaults False
     s.register_arrow("big", low_card())
     ref = arrow_rows(s.sql(Q, label="off"))
+    assert TIGHT_MORSEL_REPLAYS.value == t0
+    assert all(c <= 8 for row in group_caps(s) for c in row)
     assert arrow_rows(s.sql(Q, label="off")) == ref
+    assert TIGHT_MORSEL_REPLAYS.value == t0 + s.last_exec_stats["morsels"]
+    assert s.last_exec_stats.get("re_records", 0) == 0
     assert s._feedback is None
     assert counters() == before
     assert "decision_rows" not in s.last_exec_stats.get("extra", {})
+
+
+def test_the_store_is_fed_the_rows_that_size_the_second_sighting():
+    """On and off observe alike: the profile the store keeps after one
+    sighting is, cap for cap, what the off session's tight programs hold."""
+    off = make_session()
+    off.register_arrow("big", low_card())
+    off.sql(Q, label="same")
+    on = make_session(adaptive_plans=True)
+    on.register_arrow("big", low_card())
+    on.sql(Q, label="same")
+    assert cap_cells(on._feedback, "same", "big") == group_caps(off)
+    (state,) = on._stream_cache[Q]["gstates"]
+    assert cap_cells(on._feedback, "same", "big") == \
+        [[c for c, k in zip(row, ks) if k == "cap"]
+         for row, ks in zip(state["obs"], state["kinds"])]
 
 
 # -- log <-> store equivalence ------------------------------------------------
@@ -258,13 +300,17 @@ def test_store_roundtrips_at_attach_and_fails_soft(tmp_path):
     s._feedback.flush()
     doc = json.load(open(fbp))
     assert doc["version"] == 1 and "persist" in doc["templates"]
-    # a fresh session warm-starts: the FIRST sighting already adapts
+    # a fresh session warm-starts: the FIRST sighting already adapts —
+    # what the store still adds now that a second sighting is tight anyway
     h0 = FEEDBACK_HITS.value
     s2 = make_session(adaptive_plans=True, feedback_path=fbp)
     s2.register_arrow("big", low_card())
     ref = arrow_rows(s.sql(Q, label="persist"))
     assert arrow_rows(s2.sql(Q, label="persist")) == ref
     assert FEEDBACK_HITS.value > h0
+    assert all(c <= 8 for row in group_caps(s2) for c in row)
+    (state2,) = s2._stream_cache[Q]["gstates"]
+    assert state2["adapted"] and not state2["tight"]   # nothing left to gain
     # derived placement: beside the query log when only that is set
     ql = str(tmp_path / "logs" / "q.jsonl")
     s3 = make_session(adaptive_plans=True, query_log=True,

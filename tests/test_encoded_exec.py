@@ -262,7 +262,9 @@ def test_join_and_groupby_run_on_codes(bench_shape):
     materializes values at morsel scale — only the aggregate ARGUMENTS
     decode (qty/price sums at morsel capacity), so decode_rows stays a
     small multiple of the morsel cap instead of sites x morsels x cap,
-    and a full replay run decodes NOTHING."""
+    and a full replay run decodes NOTHING. The second sighting traces once
+    more (its programs are sized from what the first whole pass saw) and
+    decodes no more than the first; from the third on a run is pure replay."""
     s = _session(bench_shape, True)
     s.sql(Q_BENCH, backend="jax")
     st1 = dict(s.last_exec_stats)
@@ -275,8 +277,14 @@ def test_join_and_groupby_run_on_codes(bench_shape):
     assert st1["morsels"] * CHUNK > 6 * CHUNK
     s.sql(Q_BENCH, backend="jax")
     st2 = dict(s.last_exec_stats)
-    assert st2["decode_sites"] == 0 and st2["decode_rows"] == 0
+    # one jit trace of the tight program, no record pass
+    assert 0 < st2["decode_sites"] <= st1["decode_sites"]
+    assert 0 < st2["decode_rows"] <= st1["decode_rows"]
     assert st2["re_records"] == 0
+    s.sql(Q_BENCH, backend="jax")
+    st3 = dict(s.last_exec_stats)
+    assert st3["decode_sites"] == 0 and st3["decode_rows"] == 0
+    assert st3["re_records"] == 0
 
 
 def test_filter_literal_remap(bench_shape):

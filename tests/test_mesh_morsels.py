@@ -19,6 +19,9 @@ Contracts pinned here:
   force empty pallas_ops" restriction is lifted for the sharded morsel
   path); the GSPMD whole-plan mesh path still records
   pallas_fallback_reason="mesh";
+- a second sighting's programs are sized from the max over replicas and
+  morsels of the first whole pass's checks (tight_morsel_replays), and the
+  gathered partials shrink with them;
 - collective accounting (collective_bytes / collective_ms, the
   `collective_bytes` counter, a `collective` span that ends before the
   fetch begins), a morsel that overflows the sharded schedule re-recorded
@@ -252,6 +255,62 @@ def test_an_overflowing_sharded_morsel_is_re_recorded_and_counted(
     assert moved["replay_mismatches"] == 1
     # the re-recorded morsel gathered nothing: the others still did
     assert moved["collective_bytes"] == st["collective_bytes"] > 0
+
+
+@pytest.mark.parametrize("n", [2, 4])
+def test_a_second_sighting_is_sized_from_every_replicas_checks(
+        data, baseline, n, monkeypatch):
+    """The first sighting's programs hold every cap at the replica's bound;
+    the second's hold the largest value any replica of any morsel (or the
+    record pass) saw, so the gathered partials shrink and nothing
+    overflows. Rows stay the one-chip path's, bit for bit."""
+    from nds_tpu.engine.jax_backend.device import bucket
+    from nds_tpu.engine.jax_backend.shard_exec import ShardedMorselQuery
+    from nds_tpu.engine.streaming import shard_capacity
+    from nds_tpu.obs.metrics import METRICS
+    verify, seen = ShardedMorselQuery._verify, []
+
+    def spy(self, checks_host):
+        verify(self, checks_host)
+        seen.append([np.asarray(a).copy() for a in checks_host])
+    monkeypatch.setattr(ShardedMorselQuery, "_verify", spy)
+    s = make_session(data, mesh_shards=n)
+    morsels = -(-N_FACT // CHUNK)
+
+    def sight():
+        before = METRICS.snapshot()
+        t = s.sql(STAR, backend="jax", label=f"tight{n}")
+        assert rows_of(t) == baseline["star"]
+        (state,) = s._stream_cache[STAR]["gstates"]
+        return METRICS.delta(before), dict(s.last_exec_stats), state
+
+    first, st1, state = sight()
+    assert len(seen) == morsels and st1["mesh_shards"] == n
+    assert first.get("tight_morsel_replays", 0) == 0
+    (cq,), (raw,) = state["cqs"], state["raw"]
+    assert state["tight"] is True
+    want = [max(int(v), max(int(d[i].max()) for d in seen))
+            for i, (_k, v) in enumerate(raw)]
+    caps = [(v, w) for (k, v), w in zip(cq.decisions, want) if k == "cap"]
+    assert caps and all(v == w for v, w in caps)
+    assert all(v <= shard_capacity(CHUNK, n) for v, _w in caps)
+    assert bucket(caps[-1][0]) == bucket(13)        # the 13 groups of STAR
+    # replicas differ, so one replica's checks alone would not have done
+    assert any(int(d[i].min()) < int(d[i].max())
+               for d in seen for i in range(len(raw)))
+
+    second, st2, state2 = sight()
+    assert state2["cqs"] == [cq]
+    assert second["tight_morsel_replays"] == morsels
+    assert second.get("morsel_re_records", 0) == 0
+    assert st2.get("re_records", 0) == 0 and st2["mesh_shards"] == n
+    assert second["compiles"] == 2              # local + gather, once
+    assert st2["collective_bytes"] * 10 < st1["collective_bytes"]
+    assert second["collective_bytes"] == st2["collective_bytes"]
+    assert second["bytes_fetched"] < first["bytes_fetched"]
+    third, _st3, _state3 = sight()
+    assert third.get("compiles", 0) == 0
+    assert third["tight_morsel_replays"] == morsels
 
 
 def test_the_collective_span_ends_before_the_fetch_begins(data, baseline):

@@ -469,3 +469,226 @@ def test_keyless_float_aggregate_keeps_segment_sum_into_one_bucket(
     assert {t.capacity for _cq, _specs, out in dispatches
             for t in out} == {bucket(1)}
     assert [o for o in agg_apply_ops(dispatches) if "scatter" in o]
+
+
+# -- a second sighting replays programs sized from the first whole pass ------
+# A q3-shaped statement (fact ⋈ filtered date ⋈ filtered item, GROUP BY), two
+# branches over one streamed table so that a scan group has two members. The
+# first sighting provisions every capacity at the morsel bound; what its
+# replays saw sizes every later one (Session._stream_group, tighten).
+
+TIGHT_MORSELS = 4
+TIGHT_ROWS = TIGHT_MORSELS * CHUNK
+
+
+def tight_fact(dk=None, n=TIGHT_ROWS, seed=29) -> pa.Table:
+    rng = np.random.default_rng(seed)
+    if dk is None:
+        dk = rng.integers(0, 365, n)
+    return pa.table({
+        "dk": pa.array(dk, type=pa.int32()),
+        "ik": pa.array(rng.integers(0, 1000, n), type=pa.int32()),
+        "price": pa.array(rng.integers(1, 10_000, n).astype(np.int64))})
+
+
+def tight_session(fuse: bool, fact: pa.Table = None, **cfg) -> Session:
+    d = np.arange(365)
+    i = np.arange(1000)
+    cfg = {"out_of_core_min_rows": 10_000, **cfg}
+    s = Session(EngineConfig(
+        out_of_core=True, chunk_rows=CHUNK,
+        stream_fusion_max_branches=0 if fuse else 1, **cfg))
+    s.register_arrow("fact", tight_fact() if fact is None else fact)
+    s.register_arrow("date_dim", pa.table({
+        "d": pa.array(d, type=pa.int32()),
+        "moy": pa.array((d // 31 + 1).astype(np.int32)),
+        "yr": pa.array((d % 3 + 2000).astype(np.int32))}))
+    s.register_arrow("item", pa.table({
+        "i": pa.array(i, type=pa.int32()),
+        "man": pa.array((i % 40).astype(np.int32)),
+        "brand": pa.array((i % 16).astype(np.int32))}))
+    return s
+
+
+def _tight_branch(agg: str, moy: int) -> str:
+    return (f"SELECT yr, brand, {agg} AS v FROM fact JOIN date_dim ON dk = d "
+            f"JOIN item ON ik = i WHERE moy = {moy} AND man = 7 "
+            "GROUP BY yr, brand")
+
+
+TIGHT_Q = (f"SELECT a.yr, a.brand, a.v AS sp, b.v AS cnt "
+           f"FROM ({_tight_branch('SUM(price)', 11)}) a "
+           f"JOIN ({_tight_branch('COUNT(*)', 12)}) b "
+           "ON a.yr = b.yr AND a.brand = b.brand ORDER BY a.yr, a.brand")
+
+TIGHT_COUNTERS = ("compiles", "bytes_fetched", "tight_morsel_replays",
+                  "morsel_re_records", "replay_mismatches")
+
+
+def sighting(s: Session, monkeypatch, q: str = TIGHT_Q):
+    """One streamed run of `q` held against the numpy oracle. Returns what
+    the counters moved by and, per morsel dispatch, the program's cap
+    values and the capacities of the partial tables it returned."""
+    from nds_tpu.engine.jax_backend.executor import CompiledQuery
+    from nds_tpu.obs.metrics import METRICS
+    dispatches = []
+    run = CompiledQuery.run
+
+    def spy_run(self, scans, values=(), **kw):
+        out = run(self, scans, values, **kw)
+        if "/morsel:" in self.label:
+            caps = [int(v) for k, v in self.decisions if k == "cap"]
+            outs = out if isinstance(out, tuple) else (out,)
+            dispatches.append((caps, [t.capacity for t in outs]))
+        return out
+
+    oracle = rows_of(s.sql(q, backend="numpy"))
+    before = METRICS.snapshot()
+    with monkeypatch.context() as m:
+        m.setattr(CompiledQuery, "run", spy_run)
+        got = rows_of(s.sql(q, backend="jax", label="tight"))
+    moved = METRICS.delta(before)
+    assert s.last_exec_stats["mode"] == "streaming"
+    assert got == oracle and got
+    return {k: moved.get(k, 0) for k in TIGHT_COUNTERS}, dispatches
+
+
+def group_state(s: Session, q: str = TIGHT_Q) -> dict:
+    (state,) = s._stream_cache[q]["gstates"]
+    return state
+
+
+@pytest.mark.parametrize("fuse", [True, False], ids=["fused", "per_member"])
+def test_second_sighting_replays_what_the_first_pass_saw(fuse, monkeypatch):
+    from nds_tpu.engine.jax_backend.device import bucket
+    from nds_tpu.engine.streaming import adapt_schedule
+    s = tight_session(fuse)
+    programs = 1 if fuse else 2
+    first, d1 = sighting(s, monkeypatch)
+    assert len(d1) == TIGHT_MORSELS * programs
+    assert all(c == bucket(CHUNK) for caps, _outs in d1 for c in caps)
+    assert first["tight_morsel_replays"] == 0
+    assert first["compiles"] == programs
+
+    state = group_state(s)
+    assert state["tight"] is True and len(state["cqs"]) == programs
+    for cq, raw, obs in zip(state["cqs"], state["raw"], state["obs"]):
+        assert cq.decisions == adapt_schedule(raw, CHUNK, obs)
+        for (kind, v), (_k, actual), seen in zip(cq.decisions, raw, obs):
+            assert seen >= actual
+            assert v == (seen if kind == "cap" else actual)
+
+    second, d2 = sighting(s, monkeypatch)
+    assert len(d2) == len(d1)
+    assert all(c < CHUNK // 2 for caps, _outs in d2 for c in caps)
+    for caps, outs in d2:
+        # a partial table holds its aggregate's observed groups
+        if fuse:
+            assert set(outs) <= {bucket(c) for c in caps}
+        else:
+            assert outs == [bucket(caps[-1])]
+        assert max(outs) <= bucket(16 * 3)      # brands x years
+    assert second["tight_morsel_replays"] == TIGHT_MORSELS
+    assert second["morsel_re_records"] == second["replay_mismatches"] == 0
+    assert second["compiles"] == programs       # the tight programs, once
+    assert second["bytes_fetched"] * 20 < first["bytes_fetched"]
+
+    third, d3 = sighting(s, monkeypatch)
+    assert d3 == d2
+    assert third["compiles"] == 0
+    assert third["tight_morsel_replays"] == TIGHT_MORSELS
+    assert third["bytes_fetched"] == second["bytes_fetched"]
+    assert group_state(s)["cqs"] == state["cqs"]    # replaced once
+
+
+def test_only_the_last_morsel_has_survivors(monkeypatch):
+    """The recorded morsel joins nothing (every date key of the first three
+    morsels falls outside both filtered months), so its actuals are 0: the
+    tight caps come from what the last morsel's replay saw."""
+    rng = np.random.default_rng(31)
+    dk = np.concatenate([np.zeros(TIGHT_ROWS - CHUNK, dtype=np.int64),
+                         rng.integers(300, 365, CHUNK)])
+    s = tight_session(True, fact=tight_fact(dk=dk))
+    first, _d1 = sighting(s, monkeypatch)
+    state = group_state(s)
+    (raw,), (obs,) = state["raw"], state["obs"]
+    grew = [seen > actual for (kind, actual), seen in zip(raw, obs)
+            if kind == "cap"]
+    assert any(grew) and state["tight"] is True
+    second, d2 = sighting(s, monkeypatch)
+    assert second["morsel_re_records"] == second["replay_mismatches"] == 0
+    assert second["tight_morsel_replays"] == TIGHT_MORSELS
+    assert all(c < CHUNK // 2 for caps, _outs in d2 for c in caps)
+    assert second["bytes_fetched"] < first["bytes_fetched"]
+
+
+def test_reregistered_table_starts_at_the_bound_again(monkeypatch):
+    from nds_tpu.engine.jax_backend.device import bucket
+    s = tight_session(True)
+    sighting(s, monkeypatch)
+    second, _d = sighting(s, monkeypatch)
+    assert second["tight_morsel_replays"] == TIGHT_MORSELS
+    grown = tight_fact(n=TIGHT_ROWS + 2 * CHUNK, seed=37)
+    s.register_arrow("fact", grown)     # the generation moves: new entry
+    again, d = sighting(s, monkeypatch)
+    assert len(d) == TIGHT_MORSELS + 2
+    assert all(c == bucket(CHUNK) for caps, _outs in d for c in caps)
+    assert again["tight_morsel_replays"] == 0
+    assert again["morsel_re_records"] == 0
+    after, d2 = sighting(s, monkeypatch)
+    assert after["tight_morsel_replays"] == TIGHT_MORSELS + 2
+    assert all(c < CHUNK // 2 for caps, _outs in d2 for c in caps)
+
+
+@pytest.mark.parametrize("fuse", [True, False], ids=["fused", "per_member"])
+def test_an_overflowing_tight_replay_goes_back_to_the_bound(fuse,
+                                                            monkeypatch):
+    """Observed maxima patched down to nothing: the first tight replay
+    overflows, that morsel re-records eagerly, the rest of the pass and
+    every later sighting run at the bound, and nothing tightens again."""
+    from nds_tpu.engine.jax_backend.device import bucket
+    s = tight_session(fuse)
+    sighting(s, monkeypatch)
+    state = group_state(s)
+    for cq in state["cqs"]:             # built, not yet traced
+        cq.decisions = [(k, 0 if k == "cap" else v) for k, v in cq.decisions]
+    programs = len(state["cqs"])
+    second, d2 = sighting(s, monkeypatch)
+    assert second["morsel_re_records"] == second["replay_mismatches"] == 1
+    assert second["tight_morsel_replays"] == 0
+    assert s.last_exec_stats["re_records"] == 1
+    # the morsels after the overflow ran whole, at the bound
+    at_bound = [caps for caps, _outs in d2
+                if all(c == bucket(CHUNK) for c in caps)]
+    assert len(at_bound) == (TIGHT_MORSELS - 1) * programs
+    assert state["tight"] is False
+    third, d3 = sighting(s, monkeypatch)
+    assert len(d3) == TIGHT_MORSELS * programs
+    assert all(c == bucket(CHUNK) for caps, _outs in d3 for c in caps)
+    assert third["morsel_re_records"] == third["tight_morsel_replays"] == 0
+    assert third["compiles"] == 0 and state["tight"] is False
+
+
+def test_a_resident_statement_keeps_its_recorded_schedule():
+    """The in-core path is untouched: a resident statement's program is
+    built from its record pass's actuals under the name it had, whatever
+    the sighting."""
+    from nds_tpu.obs.metrics import METRICS
+    s = tight_session(True, out_of_core_min_rows=10 * TIGHT_ROWS)
+    q = _tight_branch("SUM(price)", 11) + " ORDER BY yr, brand"
+    oracle = rows_of(s.sql(q, backend="numpy"))
+    before = METRICS.snapshot()
+    seen = []
+    for _ in range(3):
+        assert rows_of(s.sql(q, backend="jax", label="res")) == oracle
+        assert s.last_exec_stats["mode"] != "streaming"
+        ents = [e for e in s._jax_executor()._plans.values()
+                if e.get("cq") is not None]
+        seen.append([(e["cq"].module_name, list(e["cq"].decisions))
+                     for e in ents])
+    assert seen[1] and seen[1] == seen[2]
+    (name, decisions), = seen[2]
+    assert name == "nds_res_root"
+    caps = [v for k, v in decisions if k == "cap"]
+    assert caps and max(caps) < CHUNK       # actuals, never a bound
+    assert METRICS.delta(before).get("tight_morsel_replays", 0) == 0

@@ -52,7 +52,7 @@ import time
 REPO = os.path.dirname(os.path.abspath(__file__))
 SCALE = "1"
 RNGSEED = "778"
-#: the five units bench.py and the pre-PR-1 chip records use
+#: the five units of ``power_resident_sf1`` and the pre-PR-1 chip records
 LEG_A_UNITS = ["query1", "query3", "query7", "query9", "query10"]
 LEG_B_UNITS = ["query3", "query9"]
 LEG_C_UNITS = ["query3", "query7", "query10"]

@@ -34,26 +34,24 @@
 #                stats sources (arrow/parquet/view/warehouse-manifest);
 #                the SF0.01 SQLite-oracle slice carries the slow marker
 #                and runs in the full `test` stage
-#   kernels    - Pallas kernel suite in INTERPRET mode (JAX_PLATFORMS=cpu
-#                exercises the real kernel bodies of
-#                engine/jax_backend/pallas_kernels.py): kernel-vs-XLA
-#                bit-identity properties + session-level on/off/oracle
-#                differentials; the SF0.01 NDS-query sweeps carry the slow
-#                marker and run in the full `test` stage instead, keeping
-#                this stage inside the tier-1 time budget
+#   kernels    - the public lowerings of engine/jax_backend/kernels.py
+#                held to numpy references written in the test, and eager
+#                output to jitted output (bit for bit on integer inputs:
+#                the record/replay contract), plus three session
+#                statements record vs compiled replay vs the ops.py
+#                oracle (tests/test_kernels_reference.py)
 #   mesh       - sharded morsel execution (EngineConfig.mesh_shards) on
 #                8 forced virtual CPU devices: sharded-vs-single-chip
-#                bit-identity differentials, skewed-morsel edge, pallas-
-#                inside-shard_map dispatch, collective accounting
-#                (tests/test_mesh_morsels.py); the GSPMD-compile-heavy
+#                bit-identity differentials, skewed-morsel edge,
+#                collective accounting (tests/test_mesh_morsels.py); the GSPMD-compile-heavy
 #                SF0.01 oracle sweep keeps the slow marker and runs in
 #                the full `test` stage so this stage stays in budget
 #   service    - concurrent query service (nds_tpu/service): admission
 #                control + typed rejection, per-tenant deadlines,
 #                batched-dispatch bit-identity vs serial, cross-client
 #                program adoption with flat compile counts, concurrent-
-#                client and live-config-toggle races, service-backed
-#                throughput streams (tests/test_service.py); plus the
+#                client races, service-backed throughput streams
+#                (tests/test_service.py); plus the
 #                service-grade observability suite (tests/
 #                test_obs_service.py): histogram quantile-error/merge
 #                properties, span parent-linkage across the service's
@@ -78,15 +76,6 @@
 #                100-client campaign and the real SF0.001 kill+resume /
 #                chaos lifecycle runs carry the slow marker and run in
 #                the full `test` stage
-#   adaptive   - adaptive execution tier-1 (tests/test_adaptive.py):
-#                feedback-store observation/right-sizing semantics, the
-#                q9-class capacity right-size with response-hash identity
-#                across sightings, under-observed ceiling-hint overflow
-#                re-recording (never mis-answering), the drift sentinel,
-#                query-log <-> feedback-store replay equivalence,
-#                crash-consistent persistence round trip, the
-#                system.plan_feedback surface, and the off-by-default
-#                strict-zero counter pins
 #   txn        - transactional warehouse tier-1: crash-consistent
 #                manifest writes (8-reader torn-read hunt), atomic
 #                multi-table commits + rollback + recovery over the
@@ -106,7 +95,6 @@
 #                is blind to (scripts/metrics_gate.py --update refreshes
 #                the baseline after intentional behavior changes)
 #   test       - full pytest suite on an 8-virtual-device CPU mesh
-#   bench      - quick bench slice (SF 0.01) to catch perf regressions early
 #   all        - every stage in order
 set -euo pipefail
 
@@ -115,8 +103,8 @@ export JAX_PLATFORMS=cpu
 export XLA_FLAGS="${XLA_FLAGS:---xla_force_host_platform_device_count=8}"
 export NDS_TPU_JIT_PLANS=1
 # CI default: verify the fully rewritten plan of every planned statement
-# (engine/verify.py). Bench runs measure with verification off; the static
-# stage exercises the stricter per-pass mode through the template sweep.
+# (engine/verify.py); the static stage exercises the stricter per-pass
+# mode through the template sweep.
 export NDS_TPU_VERIFY_PLANS="${NDS_TPU_VERIFY_PLANS:-final}"
 
 stage_native() {
@@ -174,11 +162,9 @@ stage_encoded() {
 }
 
 stage_kernels() {
-    # Pallas interpret-mode suite: the real kernel code paths (tiled
-    # bitonic sort, fused group-by partials, VMEM-staged gather) proven
-    # bit-identical to the XLA lowering before anything measures them
-    (cd "$REPO" && python -m pytest tests/test_pallas_kernels.py \
-        -q -m 'not slow')
+    # every public lowering against a numpy reference, eager against
+    # jitted: the net under a rewrite of kernels.py
+    (cd "$REPO" && python -m pytest tests/test_kernels_reference.py -q)
 }
 
 stage_mesh() {
@@ -237,15 +223,6 @@ stage_frontdoor() {
     (cd "$REPO" && python -m pytest tests/test_frontdoor.py -q)
 }
 
-stage_adaptive() {
-    # adaptive execution: observed actuals may right-size capacity
-    # schedules and flip planner decisions, but every adapted response
-    # must stay bit-identical to the unadapted one, an under-observed
-    # hint must cost a re-record (never a wrong answer), and the default
-    # (off) path must move zero feedback counters
-    (cd "$REPO" && python -m pytest tests/test_adaptive.py -q -m 'not slow')
-}
-
 stage_txn() {
     # the transactional warehouse's headline invariant, verified: no
     # torn manifest, no cross-table blend of two warehouse versions, and
@@ -265,16 +242,6 @@ stage_test() {
     (cd "$REPO" && python -m pytest tests/ -q --durations=15)
 }
 
-stage_bench() {
-    local d
-    d="$(mktemp -d)"
-    # bench measures raw engine time: plan verification off
-    (cd "$REPO" && NDS_TPU_BENCH_DIR="$d" NDS_TPU_BENCH_SF=0.01 \
-        NDS_TPU_VERIFY_PLANS=off \
-        NDS_TPU_BENCH_QUERIES=query3,query7 python bench.py)
-    rm -rf "$d"
-}
-
 # run one stage with wall-time accounting: every CI line ends with a
 # "stage <name>: <seconds>s" marker, so slow stages are attributable from
 # any runner's log without extra tooling
@@ -286,17 +253,16 @@ run_stage() {
 }
 
 case "${1:-all}" in
-    native|resilience|static|planner|encoded|kernels|mesh|service|cache|chaos|frontdoor|adaptive|txn|metrics_gate|test|bench)
+    native|resilience|static|planner|encoded|kernels|mesh|service|cache|chaos|frontdoor|txn|metrics_gate|test)
         run_stage "$1" ;;
     all)
         total0=$SECONDS
         for s in native resilience static planner encoded kernels mesh \
-                 service cache chaos frontdoor adaptive txn metrics_gate \
-                 test bench; do
+                 service cache chaos frontdoor txn metrics_gate test; do
             run_stage "$s"
         done
         echo "stage all: $((SECONDS - total0))s" ;;
-    --list)     echo "native resilience static planner encoded kernels mesh service cache chaos frontdoor adaptive txn metrics_gate test bench all" ;;
-    *) echo "usage: run_ci.sh [native|resilience|static|planner|encoded|kernels|mesh|service|cache|chaos|frontdoor|adaptive|txn|metrics_gate|test|bench|all|--list]" >&2
+    --list)     echo "native resilience static planner encoded kernels mesh service cache chaos frontdoor txn metrics_gate test all" ;;
+    *) echo "usage: run_ci.sh [native|resilience|static|planner|encoded|kernels|mesh|service|cache|chaos|frontdoor|txn|metrics_gate|test|all|--list]" >&2
        exit 2 ;;
 esac

@@ -30,10 +30,9 @@ The declared hierarchy (outer acquired first, LOWER level number):
   40  ``executor._SHARED_LOCK`` — cross-stream shared-program registry
   42  ``CompiledQuery._lock`` / ``BatchedQuery._lock`` — per-program state
   44  ``ShardedMorselQuery._lock`` — sharded stream bookkeeping
-  50  leaf stores: ``ResultCache._lock``, ``FeedbackStore._lock``,
-      ``QueryLog._lock``, ``FaultRegistry._lock``, ``CircuitBreaker._lock``,
-      ``DeviceMemTracker._lock``,
-      ``resilience._ABANDONED_LOCK``
+  50  leaf stores: ``ResultCache._lock``, ``QueryLog._lock``,
+      ``FaultRegistry._lock``, ``CircuitBreaker._lock``,
+      ``DeviceMemTracker._lock``, ``resilience._ABANDONED_LOCK``
   55  observability sinks callable from under any leaf store:
       ``FlightRecorder._lock``, ``Tracer._lock``
   60  ``MetricsRegistry._lock`` — metric registration
@@ -102,7 +101,6 @@ LOCK_LEVELS = {
     "BatchedQuery._lock": 42,
     "ShardedMorselQuery._lock": 44,
     "ResultCache._lock": 50,
-    "FeedbackStore._lock": 50,
     "QueryLog._lock": 50,
     "FaultRegistry._lock": 50,
     "CircuitBreaker._lock": 50,

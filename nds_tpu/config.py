@@ -99,8 +99,8 @@ class EngineConfig:
     # order-preserving dictionary); device.decode_col materializes values
     # only at arithmetic/aggregate/output sites. Bit-identical on/off;
     # requires narrow_lanes (encodings extend the packed layout). Property:
-    # nds.tpu.encoded_exec; the power runner exposes --no_encoded_exec and
-    # bench.py reads NDS_TPU_BENCH_ENCODED for A/B runs.
+    # nds.tpu.encoded_exec; the power runner exposes --no_encoded_exec for
+    # A/B runs.
     encoded_exec: bool = True
     # late materialization for join-heavy aggregates (planner.
     # _late_materialization): group by the dimension's surrogate join key and
@@ -112,21 +112,6 @@ class EngineConfig:
     # this big (small plans gain nothing and pay an extra small join + merge
     # aggregate). 0 fires unconditionally.
     late_mat_min_rows: int = 1 << 20
-    # TPU Pallas kernels for the sort/group-by/gather hot loops
-    # (engine/jax_backend/pallas_kernels.py): a subset of
-    # {"sort", "groupby", "gather"} enables the hand-tiled kernel for that
-    # op family — (a) VMEM-blocked bitonic segmented sort behind
-    # dense_rank/compaction/build-side, (b) fused tile-masked group-by
-    # partial aggregation replacing the factorize->scatter-add pipeline,
-    # (c) VMEM-staged batched multi-column gather for join/late-mat row
-    # materialization. Results are BIT-IDENTICAL to the XLA lowering (the
-    # default, empty = all off); program caches key on the choice. On a
-    # CPU backend the kernels run in Pallas interpret mode (CI exercises
-    # the real kernel bodies); a requested kernel that cannot lower on the
-    # backend at hand raises PallasLoweringError naming it — never a quiet
-    # XLA substitute. Property: nds.tpu.pallas_ops=sort,groupby,gather;
-    # power --pallas_ops.
-    pallas_ops: tuple[str, ...] = ()
     # EXPLAIN ANALYZE: profiled execution mode (obs/profile.py). When on,
     # every sql() statement executes node-by-node EAGERLY through the
     # existing executor (children memoized, so each node's wall is its
@@ -237,31 +222,6 @@ class EngineConfig:
     query_log_path: str = ""
     query_log_max_bytes: int = 64 << 20
     query_log_max_files: int = 4
-    # -- adaptive execution (engine/feedback.py) ---------------------------
-    # close the loop from observed actuals to plans: a per-template
-    # feedback store records per-node actual row counts (TypeName#k),
-    # exact streamed table rows, and per-decision schedule maxima; the
-    # NEXT sighting of a template right-sizes its capacity-ladder
-    # buckets from them (instead of inflating every cap to the morsel
-    # bound) and prefers observed table rows over static est_rows. An
-    # observed cap is a CEILING HINT: an under-observed actual raises
-    # ReplayMismatch at replay and re-records eagerly — never a wrong
-    # answer. OFF by default: no store is constructed, plans and
-    # schedules are bit-identical, zero new counters.
-    # Property: nds.tpu.adaptive_plans; bench exposes --adaptive /
-    # NDS_TPU_BENCH_ADAPTIVE.
-    adaptive_plans: bool = False
-    # crash-consistent JSON document the store persists to ("" = derive
-    # a plan_feedback.json beside query_log_path when that is set,
-    # otherwise in-memory only); loaded at session attach
-    # Property: nds.tpu.feedback_path
-    feedback_path: str = ""
-    # drift sentinel: when a template's observed profile diverges from
-    # its own history past this ratio (bucket scale, either direction),
-    # the store refreshes the history and the next sighting re-records
-    # instead of replaying a stale schedule
-    # Property: nds.tpu.feedback_drift_ratio
-    feedback_drift_ratio: float = 4.0
     # -- resilience (nds_tpu/resilience.py) --------------------------------
     # per-query wall-clock budget in seconds; an overrun abandons the query
     # and records Failed (DeadlineExceeded). 0 = unbounded.

@@ -43,7 +43,6 @@ from ..plan import (
     iter_plan_nodes, parameterize_plan, replace_plan_nodes,
 )
 from . import jexprs, kernels
-from . import pallas_kernels as _pallas
 from .device import (DCol, DTable, PackedTable, bucket, decode_col,
                      device_bytes, encode_against, free_dtable, phys_dtype,
                      rank_key, string_rank_lut, to_device, to_host,
@@ -217,20 +216,17 @@ def absolve_shared_program(fp: Optional[str]) -> None:
         _PROGRAM_STRIKES.pop(fp, None)
 
 
-def shared_fingerprint(pplan, shard_min_rows: int,
-                       pallas_ops: frozenset) -> str:
+def shared_fingerprint(pplan, shard_min_rows: int) -> str:
     """Registry key of a parameterized unit plan in _SHARED_PROGRAMS.
 
     Module-level so the query service's PLANNER stage (which must not touch
     the device-lane executor from its worker threads) computes the same key
     the executor publishes under: plan structure + the compile-relevant
-    engine configuration (x64 tier, shard threshold, kernel choice)."""
+    engine configuration (x64 tier, shard threshold)."""
     x64 = jax.config.read("jax_enable_x64")
     body = _plan_fingerprint(pplan)
-    pk = ",".join(sorted(pallas_ops))
     return hashlib.sha1(
-        f"{body}|x64={x64}|smr={shard_min_rows}|pallas={pk}"
-        .encode()).hexdigest()
+        f"{body}|x64={x64}|smr={shard_min_rows}".encode()).hexdigest()
 
 
 def _node_rows(decisions: list, node_labels: tuple, actuals: list) -> dict:
@@ -286,7 +282,6 @@ class CompiledQuery:
     def __init__(self, plan, decisions: list, scan_keys: tuple,
                  mesh=None, param_dtypes: tuple = (),
                  shard_min_rows: int = 1 << 18, label: str = "",
-                 pallas_ops: frozenset = frozenset(),
                  decision_nodes: Optional[tuple] = None,
                  name_fingerprint: Optional[str] = None):
         self.plan = plan
@@ -299,9 +294,6 @@ class CompiledQuery:
         self.mesh = mesh
         self.param_dtypes = param_dtypes
         self.shard_min_rows = shard_min_rows
-        # the kernel choice is part of the program's identity: replay must
-        # trace the same pallas/XLA sides the recording executor took
-        self.pallas_ops = frozenset(pallas_ops)
         # "<query>/<unit>": the label of this program's spans and host
         # annotations, and (through program_name) of its HLO module on the
         # device trace. name_fingerprint: see program_name
@@ -325,8 +317,7 @@ class CompiledQuery:
         # consume a differently-shaped schedule
         ex = JaxExecutor(_no_load, recorder=rec, scan_tables=scans,
                          mesh=self.mesh, params=params,
-                         shard_min_rows=self.shard_min_rows,
-                         pallas_ops=self.pallas_ops)
+                         shard_min_rows=self.shard_min_rows)
         out = ex.replay(self.plan)
         if rec.idx != len(rec.decisions):
             raise NotJittable("decision schedule length drift")
@@ -542,11 +533,10 @@ class CompiledQuery:
             checks_int = [int(c) for c in checks_host]
             if "decision_rows" in stats:
                 # raw index-aligned per-decision actuals, exported ONLY
-                # when the caller pre-seeded the key (the adaptive
-                # streaming loop feeding the feedback store) — an
+                # when the caller pre-seeded the key (the streaming loop,
+                # which sizes a second sighting from them) — an
                 # unconditional write would leak the list into every
-                # in-core ExecStats.extra and break the off-mode
-                # bit-identity contract
+                # in-core ExecStats.extra
                 stats["decision_rows"] = checks_int
             if self.decision_nodes:
                 rows = _node_rows(self.decisions, self.decision_nodes,
@@ -676,7 +666,6 @@ class JaxExecutor:
                  segment_cache_entries: int = 16,
                  scan_budget_bytes: int = 10 << 30,
                  params: Optional[tuple] = None,
-                 pallas_ops=frozenset(),
                  shard_local: bool = False):
         self._load_table = load_table
         # the plan node currently executing (execute() maintains it):
@@ -693,13 +682,6 @@ class JaxExecutor:
         # single-device (no in-plan collectives: the shard_map boundary IS
         # the collective).
         self._shard_local = bool(shard_local)
-        # per-op Pallas kernel activation (EngineConfig.pallas_ops): off
-        # under a GSPMD mesh — pack probes and in-plan shard_map
-        # partitioning assume the generic lowering there. Shard-LOCAL
-        # executors run the kernels: inside shard_map every operand is one
-        # replica's block, exactly the single-chip shapes the kernels tile.
-        self._pallas_ops = frozenset() if mesh is not None \
-            else _pallas.parse_ops(pallas_ops)
         # hoisted literal values for the in-flight execution: python scalars
         # under eager record, traced 0-d arrays under compiled replay
         self._params = params
@@ -1023,7 +1005,6 @@ class JaxExecutor:
                              param_dtypes=ent.get("param_dtypes", ()),
                              shard_min_rows=self._shard_min_rows,
                              label=ent.get("label", self._unit_label(key)),
-                             pallas_ops=self._pallas_ops,
                              decision_nodes=ent.get("decision_nodes"),
                              name_fingerprint=ent.get("name_fp"))
 
@@ -1128,8 +1109,7 @@ class JaxExecutor:
         is off (mesh runs lower against sharded args; jit disabled)."""
         if self._mesh is not None or not self._jit_plans:
             return None
-        return shared_fingerprint(pplan, self._shard_min_rows,
-                                  self._pallas_ops)
+        return shared_fingerprint(pplan, self._shard_min_rows)
 
     def _adopt_shared(self, key, fp, pvalues: tuple, pdtypes: tuple) -> bool:
         """Install another stream's entry (schedule + program) for `key`."""
@@ -1530,15 +1510,6 @@ class JaxExecutor:
         return out
 
     def execute(self, node: PlanNode) -> DTable:
-        # install this executor's kernel choice for every kernel dispatched
-        # below (thread-local: concurrent compile-pool traces don't race).
-        # An executor with a host eager device is the record/nojit side of
-        # an accelerator process: Mosaic kernels only lower for the chip,
-        # so it takes the XLA lowering (bit-identical by contract, and the
-        # schedule does not depend on the kernel choice) and the compiled
-        # replay alone runs the requested kernels.
-        _pallas.set_active(self._pallas_ops if self._eager_device is None
-                           else frozenset())
         key = id(node)
         if key in self._memo:
             return self._memo[key]
@@ -2068,20 +2039,11 @@ class JaxExecutor:
                 key_ops.append(jnp.where(v & alive, d,
                                          jnp.zeros((), d.dtype)))
             nkey_ops = len(key_ops)
-        if nkey_ops == 1 and _pallas.op_active("sort"):
-            # tiled segmented sort: the packed key rides the VMEM-blocked
-            # bitonic network with ONLY the row index as payload, and the
-            # agg payloads follow via one batched gather — instead of every
-            # payload riding every merge pass of the multi-operand lax.sort
-            skey, perm = kernels._sort1(key_ops[0], iota)
-            sorted_keys = (skey,)
-            sorted_pays = tuple(kernels.gather_many(list(payloads), perm))
-        else:
-            out = lax.sort(tuple(key_ops) + tuple(payloads) + (iota,),
-                           num_keys=nkey_ops, is_stable=True)
-            sorted_keys = out[:nkey_ops]
-            sorted_pays = out[nkey_ops:-1]
-            perm = out[-1]
+        out = lax.sort(tuple(key_ops) + tuple(payloads) + (iota,),
+                       num_keys=nkey_ops, is_stable=True)
+        sorted_keys = out[:nkey_ops]
+        sorted_pays = out[nkey_ops:-1]
+        perm = out[-1]
         iota_s = iota
         alive_sorted = iota_s < jnp.sum(alive.astype(_I32))
 
@@ -2968,37 +2930,8 @@ def _gather_col(c: DCol, idx: jax.Array) -> DCol:
 
 def _gather_cols(cols: list, idx: jax.Array) -> list:
     """Gather EVERY column of a table by one index vector — the join /
-    sort / late-materialization shape. With the "gather" pallas op active
-    the flattened (data, valid, parts...) arrays ride batched VMEM-staged
-    kernel passes (kernels.gather_many); otherwise per-column XLA gathers
-    exactly as before. Both sides are pure permutation reads."""
-    if not _pallas.op_active("gather"):
-        return [_gather_col(c, idx) for c in cols]
-    arrays: list = []
-    for c in cols:
-        arrays.append(c.data)
-        arrays.append(c.valid)
-        if c.parts is not None:
-            for p in c.parts:
-                arrays.append(p.data)
-                arrays.append(p.valid)
-    flat = kernels.gather_many(arrays, idx)
-    out: list = []
-    i = 0
-    for c in cols:
-        data, valid = flat[i], flat[i + 1]
-        i += 2
-        parts = None
-        if c.parts is not None:
-            ps = []
-            for p in c.parts:
-                ps.append(dataclasses.replace(p, data=flat[i],
-                                              valid=flat[i + 1]))
-                i += 2
-            parts = tuple(ps)
-        out.append(dataclasses.replace(c, data=data, valid=valid,
-                                       parts=parts))
-    return out
+    sort / late-materialization shape."""
+    return [_gather_col(c, idx) for c in cols]
 
 
 def _joinable_pair(a: DCol, b: DCol) -> tuple[jax.Array, jax.Array]:

@@ -14,14 +14,13 @@ reference nds/nds_power.py:124-134).
 """
 from __future__ import annotations
 
-from functools import partial
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ..plan import AggSpec, SortKey, WindowFunc
-from . import pallas_kernels as _pk
 
 _I32 = jnp.int32
 
@@ -30,60 +29,35 @@ def _iota(n: int) -> jax.Array:
     return jnp.arange(n, dtype=_I32)
 
 
-#: every public lowering below opens a named scope under its own name
-_scoped = _pk.scoped
+def _scoped(fn):
+    """Every public lowering below opens a named scope under its own name.
 
+    Run a kernel entry point under ``jax.named_scope(<its name>)``:
+    inside a plan program every instruction it emits then carries
+    ``jit(nds_<query>_<unit>)/.../<TypeName#k>/<kernel>/...`` as its
+    ``op_name``, what a profile groups device time by. A scope is a debug
+    location: it changes no instruction and is stripped from the compile
+    cache's key. (A new context manager per call: ``jax.named_scope`` used
+    as a decorator shares one between the compile pool's threads.)"""
+    name = fn.__name__
 
-# ---------------------------------------------------------------------------
-# Pallas dispatch seams (ISSUE 7): each helper swaps in the hand-tiled
-# pallas_kernels implementation when its op flag is active for the in-flight
-# executor (EngineConfig.pallas_ops via pallas_kernels.set_active) and keeps
-# the existing XLA lowering — bit-identically — otherwise. No schedule
-# decision may depend on which side runs: both sides return identical bits.
-# ---------------------------------------------------------------------------
+    @functools.wraps(fn)
+    def scoped_fn(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+    return scoped_fn
+
 
 def _sort1(key: jax.Array, idx: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Single-integer-key stable sort carrying an iota/permutation payload:
-    the (key, idx) comparator is a total order, so the tiled bitonic
-    network reproduces `lax.sort(..., is_stable=True)` exactly. Fact-scale
-    arrays only (SORT_MIN_ROWS): each pallas call SITE is one kernel
-    compile, and dimension-scale sorts never earn it back."""
-    if int(key.shape[0]) >= _pk.SORT_MIN_ROWS and _pk.op_active("sort"):
-        return _pk.sort_pairs(key, idx)
+    """Single-integer-key stable sort carrying an iota/permutation payload."""
     return lax.sort((key, idx), num_keys=1, is_stable=True)
 
 
 @_scoped
 def gather_many(arrays: list, idx: jax.Array) -> list:
-    """Batched same-index gather (multi-column join/late-mat shape): one
-    VMEM-staged pallas pass over all stageable columns when "gather" is
-    active and the index vector is fact-scale, else the plain XLA gathers.
-    Pure permutation reads — always bit-identical."""
-    if int(idx.shape[0]) >= _pk.GATHER_MIN_ROWS and _pk.op_active("gather"):
-        return _pk.take_many(list(arrays), idx)
+    """Same-index gather of several columns (multi-column join /
+    late-materialization shape)."""
     return [a[idx] for a in arrays]
-
-
-def _seg_multi(pairs: list, gid: jax.Array, num_segments: int) -> list:
-    """Several segment reductions over ONE gid vector. With "groupby"
-    active, every eligible operand rides one fused pallas pass (a single
-    per-tile membership mask serves them all); the rest — and the whole
-    list when inactive — keep the per-operand `_seg` path."""
-    out: list = [None] * len(pairs)
-    fused: list[int] = []
-    if int(gid.shape[0]) >= _pk.GROUPBY_MIN_ROWS and \
-            _pk.op_active("groupby"):
-        fused = [i for i, (d, op) in enumerate(pairs)
-                 if _pk.seg_supported(d, num_segments, op)]
-        if fused:
-            res = _pk.seg_reduce_multi([pairs[i] for i in fused], gid,
-                                       num_segments)
-            for i, r in zip(fused, res):
-                out[i] = r
-    for i, (d, op) in enumerate(pairs):
-        if out[i] is None:
-            out[i] = _seg(d, gid, num_segments, op)
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -131,15 +105,7 @@ def unscatter(perm: jax.Array, values: tuple) -> tuple:
     `values` as payload operands. Measured on TPU: an n-sized scatter costs
     ~60x a 2-operand sort — .at[perm].set() is the single most expensive
     way to invert a permutation on this hardware.
-
-    Pallas tier: sort only (perm, iota) — yielding argsort(perm), i.e. the
-    inverse permutation — then gather the payloads through it in one
-    batched pass instead of carrying every payload through the merge
-    network. perm's values are distinct, so both forms are bit-identical.
     """
-    if int(perm.shape[0]) >= _pk.SORT_MIN_ROWS and _pk.op_active("sort"):
-        _, inv = _pk.sort_pairs(perm, _iota(perm.shape[0]))
-        return tuple(gather_many(list(values), inv))
     out = lax.sort((perm,) + tuple(values), num_keys=1, is_stable=True)
     return out[1:]
 
@@ -336,14 +302,12 @@ def sort_specs(keys: list[SortKey]) -> tuple:
 _MASKED_SEG_MAX = 64
 
 
+def _seg_multi(pairs: list, gid: jax.Array, num_segments: int) -> list:
+    """Several segment reductions over ONE gid vector."""
+    return [_seg(d, gid, num_segments, op) for d, op in pairs]
+
+
 def _seg(data: jax.Array, gid: jax.Array, num_segments: int, op: str) -> jax.Array:
-    # pallas tier first: the fused tile-masked partial-agg kernel replaces
-    # the serialized scatter-add for bounded segment counts; eligibility is
-    # static (dtype/op/cap/rows), so one compiled program is consistent
-    if int(gid.shape[0]) >= _pk.GROUPBY_MIN_ROWS \
-            and _pk.op_active("groupby") \
-            and _pk.seg_supported(data, num_segments, op):
-        return _pk.seg_reduce(data, gid, num_segments, op)
     if (num_segments <= _MASKED_SEG_MAX and isinstance(data, jax.core.Tracer)
             and jnp.issubdtype(data.dtype, jnp.integer)):
         seg_ids = jnp.arange(num_segments, dtype=gid.dtype)
@@ -378,9 +342,7 @@ def agg_apply(gid: jax.Array, alive: jax.Array, func: str, arg,
     data, valid = arg
     contrib = alive & valid
     # every aggregate needs the per-group contribution count alongside its
-    # value reduction: batching both through _seg_multi lets the pallas
-    # groupby tier compute them in ONE fused tile pass (one membership
-    # mask, several operands) instead of one scatter pipeline each
+    # value reduction
     cnt_op = contrib.astype(int_out)
     if func == "count":
         return _seg(cnt_op, gid, cap_out, "sum"), jnp.ones(cap_out, bool)

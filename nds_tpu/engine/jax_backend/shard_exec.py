@@ -169,7 +169,6 @@ class ShardedMorselQuery:
 
     def __init__(self, plan, decisions: list, scan_keys: tuple, mesh,
                  morsel_key: str, label: str = "",
-                 pallas_ops: frozenset = frozenset(),
                  name_fingerprint: Optional[str] = None):
         self.plan = plan
         self.decisions = decisions
@@ -177,7 +176,6 @@ class ShardedMorselQuery:
         self.mesh = mesh
         self.n_shards = int(mesh.devices.size)
         self.morsel_key = morsel_key
-        self.pallas_ops = frozenset(pallas_ops)
         base = label or "program"
         self.label = f"{base}@mesh{self.n_shards}"
         self.gather_label = base.replace("/morsel:", "/gather:", 1) \
@@ -194,8 +192,7 @@ class ShardedMorselQuery:
         scans[self.morsel_key] = morsel
         rec = _Recorder("replay", self.decisions)
         ex = JaxExecutor(_no_load, recorder=rec, scan_tables=scans,
-                         mesh=None, shard_local=True,
-                         pallas_ops=self.pallas_ops)
+                         mesh=None, shard_local=True)
         out = ex.replay(self.plan)
         if rec.idx != len(rec.decisions):
             raise ReplayMismatch("decision schedule length drift (sharded)")

@@ -563,12 +563,10 @@ def plan_for_cache(session, sql: str, backend: Optional[str] = None):
     fp = None
     pvalues: tuple = ()
     if use_jax and not streams and cfg.jit_plans and not cfg.mesh_shape:
-        from .jax_backend import pallas_kernels as _pk
         from .jax_backend.executor import shared_fingerprint
         pplan, pvals, pdts = P.parameterize_plan(plan)
         if pdts:
-            fp = shared_fingerprint(pplan, cfg.shard_min_rows,
-                                    _pk.parse_ops(cfg.pallas_ops))
+            fp = shared_fingerprint(pplan, cfg.shard_min_rows)
             pvalues = tuple(pvals)
     return plan, fp, pvalues, use_jax
 

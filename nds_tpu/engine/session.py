@@ -110,23 +110,6 @@ class Session:
                 path=self.config.query_log_path or None,
                 max_bytes=self.config.query_log_max_bytes,
                 max_files=self.config.query_log_max_files, clear=False)
-        # -- adaptive execution (engine/feedback.py) ------------------------
-        # the feedback stats store closing the loop from observed actuals
-        # back into plans: armed only by config.adaptive_plans (default off
-        # = no store, no counters, bit-identical plans). Persists beside
-        # the query log when one is configured (crash-consistent JSON), or
-        # at config.feedback_path; otherwise in-memory for the session.
-        self._feedback = None
-        if self.config.adaptive_plans:
-            from .feedback import FeedbackStore
-            fb_path = self.config.feedback_path
-            if not fb_path and self.config.query_log_path:
-                fb_path = os.path.join(
-                    os.path.dirname(self.config.query_log_path) or ".",
-                    "plan_feedback.json")
-            self._feedback = FeedbackStore(
-                path=fb_path or None,
-                drift_ratio=self.config.feedback_drift_ratio)
         self.warehouse = None  # attached via attach_warehouse for DML
         self._loaders: dict[str, Callable[[], Table]] = {}
         self._schemas: dict[str, tuple[list[str], list[str]]] = {}
@@ -273,12 +256,8 @@ class Session:
         """The session-held device executor: device-resident scan cache and
         compiled plans persist across the whole query stream (the reference
         keeps tables hot on the executors across the 103-query power run)."""
-        # invalidation key includes the kernel choice: toggling pallas_ops
-        # on a live session (A/B runs) must rebuild the executor — its
-        # cached programs/schedules embed which kernels they traced
         cfg = self.config
-        exec_key = (self._generation, tuple(sorted(cfg.pallas_ops)))
-        if self._jax_exec is None or self._jax_exec_gen != exec_key:
+        if self._jax_exec is None or self._jax_exec_gen != self._generation:
             from .jax_backend import JaxExecutor
             self._jax_exec = JaxExecutor(
                 self.load_table, jit_plans=cfg.jit_plans,
@@ -287,9 +266,8 @@ class Session:
                 segment_plan_nodes=cfg.segment_plan_nodes,
                 segment_min_cte_nodes=cfg.segment_min_cte_nodes,
                 segment_cache_entries=cfg.segment_cache_entries,
-                scan_budget_bytes=int(cfg.scan_budget_gb * (1 << 30)),
-                pallas_ops=cfg.pallas_ops)
-            self._jax_exec_gen = exec_key
+                scan_budget_bytes=int(cfg.scan_budget_gb * (1 << 30)))
+            self._jax_exec_gen = self._generation
         return self._jax_exec
 
     def _dec_as_int(self) -> bool:
@@ -645,26 +623,9 @@ class Session:
             return self._cache[key]
 
     # -- query --------------------------------------------------------------
-    def _est_rows_for(self, name: str, default: int,
-                      label: Optional[str] = None) -> int:
-        """Planning-time row estimate for ``name``: the registered static
-        estimate, unless adaptive execution has OBSERVED this table's
-        streamed row count under the same query template — the feedback
-        store's ground truth then replaces the catalog guess, flipping
-        streamed-vs-in-core and late-materialization decisions from what
-        actually happened last time. ``label`` scopes the lookup (service
-        planner threads pass the ticket's label explicitly — they run
-        outside _sql_lock, so _active_label belongs to someone else)."""
-        if self._feedback is not None:
-            key = self._active_label if label is None else label
-            observed = self._feedback.table_rows(key).get(name)
-            if observed is not None:
-                return int(observed)
-        return self._est_rows.get(name, default)
-
-    def _catalog(self, label: Optional[str] = None) -> Catalog:
+    def _catalog(self) -> Catalog:
         return Catalog({name: (sch[0], sch[1],
-                               self._est_rows_for(name, 1000, label))
+                               self._est_rows.get(name, 1000))
                         for name, sch in self._schemas.items()},
                        dec_enabled=self._dec_as_int(),
                        unique_cols=dict(self._unique_cols),
@@ -1136,25 +1097,8 @@ class Session:
             stats.mem_headroom_bytes = \
                 int(self.config.scan_budget_gb * (1 << 30)) - \
                 stats.mem_peak_bytes
-        if self.config.pallas_ops:
-            from .jax_backend import pallas_kernels as _pk
-            ops = sorted(_pk.parse_ops(self.config.pallas_ops))
-            if self._device_mesh() is not None:
-                # the GSPMD whole-plan mesh path still forces the XLA
-                # lowering (kernels are not partitionable operands); the
-                # sharded-MORSEL path (mesh_shards) runs them shard-local
-                # inside shard_map, so only mesh_shape lands here
-                stats.pallas_fallback_reason = "mesh"
-            else:
-                stats.pallas_ops = ops
         self.last_exec_stats_typed = stats
         self.last_exec_stats = stats.to_dict()
-        if self._feedback is not None:
-            # every completed statement's per-node actuals feed the
-            # template's profile (the query log records the same map, so
-            # replay_log over a saved JSONL reconstructs this store)
-            self._feedback.observe_nodes(self._active_label,
-                                         stats.node_stats)
         from ..obs.query_log import QUERY_LOG
         if QUERY_LOG.enabled and \
                 (self._stmt_log if log is None else log):
@@ -1196,8 +1140,7 @@ class Session:
                 cfg.stream_fusion_max_branches, cfg.late_materialization,
                 cfg.late_mat_min_rows, cfg.decimal_physical, cfg.use_jax,
                 cfg.narrow_lanes, cfg.encoded_exec, tuple(cfg.mesh_shape),
-                int(cfg.mesh_shards or 0),
-                tuple(sorted(cfg.pallas_ops)), bool(cfg.adaptive_plans))
+                int(cfg.mesh_shards or 0))
 
     def _sql_streaming(self, query: str):  # lint: thread-entry (called under _sql_lock; stream-cache writes additionally take the state lock)
         """Out-of-core execution (generalized round 5, shared-scan round 7):
@@ -1225,29 +1168,13 @@ class Session:
             sent = self._stream_cache.get(query, "miss")
         if sent is None:          # known not-streamable: skip the re-plan
             return None
-        if sent != "miss" and self._feedback is not None and \
-                sent.get("fb_stamp") != \
-                self._feedback.stamp(self._active_label):
-            # drift sentinel: the feedback store's profile generation for
-            # this template moved since the cached streaming state was
-            # built (new observations at bucket scale, or a drift
-            # refresh) — replaying the stale schedule would either keep
-            # the overprovision or trip ReplayMismatch per morsel.
-            # Re-plan from the moved profile instead.
-            _metrics.ADAPTIVE_REPLANS.inc()
-            from ..obs.flight import FLIGHT
-            FLIGHT.record("adaptive_replan", label=self._active_label,
-                          reason="profile_generation")
-            with self._lock:
-                self._stream_cache.pop(query, None)
-            sent = "miss"
         if sent == "miss":
             with TRACER.span("plan", label=self._active_label):
                 with TRACER.span("parse"):
                     ast = parse_sql(query)
                 plan = Planner(self._catalog()).plan_query(ast)
             jobs = streaming.find_streaming_jobs(
-                plan, lambda t: self._est_rows_for(t, 0),
+                plan, lambda t: self._est_rows.get(t, 0),
                 self.config.out_of_core_min_rows)
             if not jobs:
                 with self._lock:
@@ -1292,11 +1219,7 @@ class Session:
                     # "tight": None until a whole pass has been seen, then
                     # whether the programs are sized from it (_stream_group)
                     "gstates": [{"cqs": None, "ents": None, "fused": False,
-                                 "tight": None} for _ in groups],
-                    # profile generation this state was planned from: a
-                    # later generation move invalidates it (drift sentinel)
-                    "fb_stamp": self._feedback.stamp(self._active_label)
-                    if self._feedback is not None else 0}
+                                 "tight": None} for _ in groups]}
             with self._lock:
                 self._stream_cache[query] = sent
 
@@ -1366,12 +1289,6 @@ class Session:
                 enc_bytes_saved += morsels_run * (
                     lane_bytes(group.plain_lanes, cap) -
                     enc_lane_bytes(group.lanes, cap, group.encodings))
-        if self._feedback is not None:
-            # exact rows streamed per big table: ground truth the next
-            # sighting's catalog prefers over the static est_rows
-            self._feedback.observe_tables(
-                self._active_label,
-                {g["table"]: g["rows"] for g in stream_rec["groups"]})
         for ji, job in enumerate(jobs):
             if not partials[ji]:
                 with self._lock:
@@ -1496,8 +1413,7 @@ class Session:
             segment_plan_nodes=cfg.segment_plan_nodes,
             segment_min_cte_nodes=cfg.segment_min_cte_nodes,
             segment_cache_entries=cfg.segment_cache_entries,
-            scan_budget_bytes=int(cfg.scan_budget_gb * (1 << 30)),
-            pallas_ops=cfg.pallas_ops)
+            scan_budget_bytes=int(cfg.scan_budget_gb * (1 << 30)))
         return {"jexec": jexec, "current": current}
 
     def _incore_partial(self, shared: dict, branch):
@@ -1594,36 +1510,8 @@ class Session:
         bytes_uploaded = 0
         rows_streamed = 0
 
-        adaptive = self._feedback is not None and mesh is None
         # what a replica's (one chip: the morsel's) capacities are bounded by
         bound = shard_cap if mesh is not None else morsel_rows
-
-        def first_schedule(decisions_raw, member: int):
-            """One member's schedule on the group's first sighting: every
-            cap at the bound, or — when the feedback store holds a
-            structurally matching profile for this (template, table,
-            member) — at the profile's observed maxima instead
-            (streaming.adapt_schedule; a ceiling hint, ReplayMismatch
-            catches under-observation)."""
-            caps = self._feedback.member_caps(
-                self._active_label, group.table, member,
-                state["kinds"][member], morsel_rows, fuse, 0) \
-                if adaptive else None
-            inflated = streaming.inflate_schedule(decisions_raw, bound)
-            if caps is None:
-                return inflated
-            adapted = streaming.adapt_schedule(decisions_raw, bound, caps)
-            state["adapted"] = True
-            before, after = (
-                sum(c for k, c in streaming.schedule_shape(d) if k == "cap")
-                for d in (inflated, adapted))
-            _metrics.FEEDBACK_HITS.inc()
-            from ..obs.flight import FLIGHT
-            FLIGHT.record("feedback_hit", label=self._active_label,
-                          table=group.table, member=member,
-                          cells_before=before, cells_after=after)
-            self._feedback.note_applied(self._active_label, before, after)
-            return adapted
 
         def build(schedules: list) -> list:
             """The group's programs from one schedule per member (fused:
@@ -1641,12 +1529,12 @@ class Session:
                     from .jax_backend.shard_exec import ShardedMorselQuery
                     cqs.append(ShardedMorselQuery(
                         p, decisions, scan_keys, mesh, mkey, label=label,
-                        pallas_ops=jexec._pallas_ops, name_fingerprint=fp))
+                        name_fingerprint=fp))
                 else:
                     cqs.append(CompiledQuery(
                         p, decisions, scan_keys, mesh=jexec._mesh,
                         shard_min_rows=jexec._shard_min_rows, label=label,
-                        pallas_ops=jexec._pallas_ops, name_fingerprint=fp))
+                        name_fingerprint=fp))
             return cqs
 
         def record_first(morsel) -> bool:
@@ -1679,10 +1567,9 @@ class Session:
             raws = [decisions for _out, decisions, _keys in recs]
             ents = [{"scan_keys": keys} for _out, _decisions, keys in recs]
             state["raw"], state["ents"], state["fused"] = raws, ents, fuse
-            state["kinds"] = [[k for k, _v in d] for d in raws]
             state["obs"] = [[int(v) for _k, v in d] for d in raws]
-            state["cqs"] = build([first_schedule(d, bi)
-                                  for bi, d in enumerate(raws)])
+            state["cqs"] = build([streaming.inflate_schedule(d, bound)
+                                  for d in raws])
             return True
 
         def tighten() -> None:
@@ -1761,11 +1648,9 @@ class Session:
                 return outs
             except ReplayMismatch:
                 # a morsel genuinely exceeded the schedule (the inflated
-                # bound, a tightened cap, or an adapted ceiling hint a
-                # grown actual overflowed): run it eagerly after evicting
+                # bound or a tightened cap): run it eagerly after evicting
                 # stale record-side buffers — correctness never depends on
-                # the schedule. The fresh record pass's actuals feed the
-                # store so the next sighting provisions for what was seen.
+                # the schedule.
                 free_dtable(jexec._scan_cache_rec.pop(mkey, None))
                 re_records += 1
                 _metrics.REPLAY_MISMATCHES.inc()
@@ -1777,27 +1662,9 @@ class Session:
                         for d in state["raw"]])
                 # a pass with a mismatch sizes nothing, now or later
                 state["tight"] = False
-                if adaptive and state.get("adapted"):
-                    _metrics.ADAPTIVE_REPLANS.inc()
-                    from ..obs.flight import FLIGHT
-                    FLIGHT.record("adaptive_replan",
-                                  label=self._active_label,
-                                  table=group.table,
-                                  reason="schedule_overflow")
                 if state["fused"]:
-                    outs, d2, _ = jexec.record_plans(group.plans)
-                    recorded = [d2]
-                else:
-                    outs, recorded = [], []
-                    for p in group.plans:
-                        out, d2, _ = jexec.record_plan(p)
-                        outs.append(out)
-                        recorded.append(d2)
-                if adaptive:
-                    for bi, d2 in enumerate(recorded):
-                        state["kinds"][bi] = [k for k, _v in d2]
-                        merge_obs(bi, [v for _k, v in d2])
-                return outs
+                    return jexec.record_plans(group.plans)[0]
+                return [jexec.record_plan(p)[0] for p in group.plans]
 
         staged = {}
         stage_thread = None
@@ -1880,15 +1747,6 @@ class Session:
             return None   # empty source: the in-core path handles it
         if state["tight"] is None:      # a whole pass, and no mismatch
             tighten()
-        if adaptive:
-            # the group's observed schedule profile: per-member per-
-            # decision maxima across every morsel of this pass (record
-            # actuals + replay check scalars), keyed on the program
-            # structure so only a like-for-like sighting consumes it
-            self._feedback.observe_group(
-                self._active_label, group.table, bound=morsel_rows,
-                fused=state["fused"], shards=0,
-                kinds=state["kinds"], caps=state["obs"])
         return (count, re_records, bytes_uploaded, mesh is not None,
                 host_ms, rows_streamed)
 

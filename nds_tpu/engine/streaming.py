@@ -852,15 +852,12 @@ def adapt_schedule(decisions: list, morsel_cap: int,
     length-drifted list — a structurally different schedule) falls back to
     plain morsel-bound inflation.
 
-    Two callers. Session._stream_group, always: the maxima of one whole
-    pass over a stream-cache entry's rows, which are EXACT for every later
+    The caller is Session._stream_group: the maxima of one whole pass
+    over a stream-cache entry's rows, which are EXACT for every later
     replay from that entry (the entry dies with the catalog generation).
-    And the feedback store (EngineConfig.adaptive_plans,
-    FeedbackStore.member_caps), whose persisted maxima let a first
-    sighting start tight: there an observed cap is a CEILING HINT. Either
-    way a morsel exceeding a cap fails the replay's schedule check
-    (ReplayMismatch) and re-records eagerly, so a cap that is too small
-    costs a re-record, never a wrong answer."""
+    A morsel exceeding a cap all the same fails the replay's schedule
+    check (ReplayMismatch) and re-records eagerly, so a cap that is too
+    small costs a re-record, never a wrong answer."""
     if observed is None or len(observed) != len(decisions):
         return inflate_schedule(decisions, morsel_cap)
     return [(kind, max(int(v), int(o)) if kind == "cap" else v)

@@ -30,8 +30,6 @@ engine could not say which operator in which query burns the chip's time
   One merged picture: ``power --trace T.json --profile_folder P`` (the
   front-door server takes the same two flags), then
   ``scripts/trace_report.py --xplane P/.../*.xplane.pb T.json``.
-- :mod:`.device_time` — the published device peaks (``DEVICE_PEAKS``),
-  the denominators of a roofline share.
 - :mod:`.stats`   — the typed ``ExecStats`` replacing the untyped
   ``last_exec_stats`` dict (dict view preserved).
 - :mod:`.profile` — EXPLAIN ANALYZE: per-plan-node runtime profiles

@@ -36,12 +36,7 @@ warehouse commit landing (committer, published version, tables touched),
 ``txn_rollback`` a transaction aborting back to its base snapshot
 (``clean`` records whether the intent record was retired or left for
 recovery), and ``txn_recover`` a reopened warehouse discarding a dead
-writer's orphaned partial commit. The adaptive-execution vocabulary
-(``engine/feedback.py``): ``feedback_hit`` a streamed group's capacity
-schedule right-sized from observed actuals, ``feedback_refresh`` the
-drift sentinel replacing a stale profile, and ``adaptive_replan`` a
-feedback-driven re-record (moved profile generation, or an adapted
-schedule overflowed by an under-observed actual).
+writer's orphaned partial commit.
 
 Disabled (the default outside the service) a record() is one attribute
 read — the same near-zero contract as the span tracer. Enable with

@@ -5,7 +5,7 @@ keys per PR, ``last_exec_stats`` dict entries, stderr one-liners. One
 registry gives every layer (session, device, executor, streaming,
 resilience, throughput, runners) a single place to write and every report
 a single place to read: ``METRICS.snapshot()`` lands verbatim in
-``bench.py`` / ``power.py`` JSON and ``scripts/trace_report.py``.
+``power.py`` JSON and ``scripts/trace_report.py``.
 
 Counters are monotonic per process; runners take a snapshot before a unit
 of work and report the ``delta`` so per-query/per-phase numbers come out
@@ -613,14 +613,6 @@ TIGHT_MORSEL_REPLAYS = METRICS.counter(
 COLLECTIVE_BYTES = METRICS.counter(
     "collective_bytes", "per-chip ingress of the sharded morsels' partial "
     "all_gathers by the ring model: (n-1)/n of the gathered total")
-# Pallas kernel dispatches (pallas_kernels): counted at build time — once
-# per kernel instantiation under a jit trace, once per call in eager record
-PALLAS_SORT_CALLS = METRICS.counter(
-    "pallas_sort_calls", "tiled bitonic sort_pairs dispatches (pallas)")
-PALLAS_GROUPBY_CALLS = METRICS.counter(
-    "pallas_groupby_calls", "fused seg_reduce partial-agg dispatches (pallas)")
-PALLAS_GATHER_CALLS = METRICS.counter(
-    "pallas_gather_calls", "VMEM-staged take_many dispatches (pallas)")
 # Encoded execution (device.plan_encodings): dictionary/RLE wire encodings
 DICT_UPLOADS_SAVED = METRICS.counter(
     "dict_uploads_saved", "device codebook uploads served from the "
@@ -752,26 +744,6 @@ TXN_RECOVERIES = METRICS.counter(
     "txn_recoveries", "orphaned in-progress transactions discarded at "
     "warehouse open (crash recovery: each table back to max(base, "
     "published) — never a blend of pre- and post-commit state)")
-# Adaptive execution (engine/feedback.py): the feedback stats store
-# closing the loop from observed actuals to the next sighting's plans —
-# all exactly zero when EngineConfig.adaptive_plans is off (no store is
-# constructed; the metrics gate pins all three strict-zero on its clean,
-# adaptation-off workload)
-FEEDBACK_HITS = METRICS.counter(
-    "feedback_hits", "streamed scan groups whose capacity schedule was "
-    "right-sized from the feedback store's observed per-decision maxima "
-    "instead of morsel-bound inflation (a ceiling hint: an "
-    "under-observed actual re-records, never mis-answers)")
-FEEDBACK_REFRESHES = METRICS.counter(
-    "feedback_refreshes", "drift-sentinel refreshes: a template's "
-    "observed profile diverged from its own history past the drift "
-    "ratio, so the stale history was replaced and the generation bumped "
-    "(the next sighting re-records instead of replaying stale caps)")
-ADAPTIVE_REPLANS = METRICS.counter(
-    "adaptive_replans", "streamed re-records driven by feedback: a "
-    "cached schedule invalidated by a moved profile generation, or an "
-    "adapted (right-sized) schedule overflowed by an under-observed "
-    "actual (ReplayMismatch fallback — correctness preserved)")
 # Distributed serving (service/frontdoor.py + fair scheduling in
 # service/service.py): all exactly zero when the front door is not
 # started and fair_queue/preemption/inflight_dedup are off (the

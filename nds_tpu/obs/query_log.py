@@ -61,8 +61,7 @@ COLUMNS = (
     ("mem_peak_bytes", "int"),  # device-memory high-water mark
     ("node_stats", "str"),      # {TypeName#k: actual rows} as JSON —
     #                             offline tooling (slo_report,
-    #                             explain_report --audit, the feedback
-    #                             store's replay_log) reconstructs
+    #                             explain_report --audit) reconstructs
     #                             per-node actuals without explain folders
     ("preempted", "int"),       # interactive tickets served at this
     #                             streamed query's morsel-boundary yield

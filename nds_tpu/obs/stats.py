@@ -87,11 +87,6 @@ class ExecStats:
     #: wall from each partial gather's dispatch to its result on the host
     #: (the `collective` span plus the `exec.fetch` after it)
     collective_ms: Optional[float] = None
-    # -- pallas kernels (EngineConfig.pallas_ops) ----------------------------
-    #: the validated op subset active for this execution (None = flag off)
-    pallas_ops: Optional[list] = None
-    #: why the XLA lowering served despite the flag (platform/import/mesh)
-    pallas_fallback_reason: Optional[str] = None
     # -- query service (nds_tpu/service) -------------------------------------
     #: wall spent between service admission and execution start (ms) — the
     #: service-mode latency decomposition: latency = queue_wait + execute
@@ -201,7 +196,6 @@ class ExecStats:
                   "enc_bytes_saved", "decode_sites", "decode_rows",
                   "host_decode_ms", "mesh_shards", "sharded_groups",
                   "collective_bytes", "collective_ms",
-                  "pallas_ops", "pallas_fallback_reason",
                   "queue_wait_ms", "batched_with", "trace_id",
                   "node_stats", "mem_peak_bytes", "mem_live_bytes",
                   "mem_headroom_bytes"):

@@ -86,10 +86,6 @@ SYSTEM_SCHEMAS: dict[str, tuple[tuple, tuple]] = {
         ("version", "timestamp_ms", "committer", "tables",
          "table_count", "current", "pinned"),
         ("int", "int", "str", "str", "int", "bool", "bool")),
-    "system.plan_feedback": (
-        ("template", "kind", "node", "table", "rows", "sightings",
-         "refreshes", "gen"),
-        ("str", "str", "str", "str", "int", "int", "int", "int")),
 }
 
 
@@ -227,18 +223,6 @@ def _snapshot_rows(session) -> list[dict]:
             for rec in wh.snapshot_records()]
 
 
-def _plan_feedback_rows(session) -> list[dict]:
-    """The adaptive-execution feedback store's observed actuals (one row
-    per fact: per-node TypeName#k maxima, per-table streamed rows, and
-    per-decision schedule caps). Empty when adaptive_plans is off — no
-    store exists then."""
-    fb = getattr(session, "_feedback", None) if session is not None \
-        else None
-    if fb is None:
-        return []
-    return fb.snapshot_rows()
-
-
 PROVIDERS: dict[str, Callable] = {
     "system.query_log": _query_log_rows,
     "system.metrics": _metrics_rows,
@@ -249,7 +233,6 @@ PROVIDERS: dict[str, Callable] = {
     "system.flight": _flight_rows,
     "system.tables": _tables_rows,
     "system.snapshots": _snapshot_rows,
-    "system.plan_feedback": _plan_feedback_rows,
 }
 
 

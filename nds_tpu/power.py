@@ -171,7 +171,6 @@ def run_query_stream(input_prefix: str, stream_path: str, time_log: str,
                      narrow_lanes: bool | None = None,
                      encoded_exec: bool | None = None,
                      verify_plans: str | None = None,
-                     pallas_ops: str | None = None,
                      mesh_shards: int | None = None,
                      trace: str | None = None,
                      explain: bool = False,
@@ -211,9 +210,6 @@ def run_query_stream(input_prefix: str, stream_path: str, time_log: str,
     encoded_exec: --no_encoded_exec A/B override (None = config): False
     disables the dictionary/RLE wire encodings (streamed morsels ride the
     plain narrow-lane layout), bit-identical results.
-    pallas_ops: comma list of {sort,groupby,gather} enabling the TPU
-    Pallas kernel for that op family (None = take EngineConfig.pallas_ops;
-    results are bit-identical to the XLA lowering either way).
     mesh_shards: partition every streamed scan group's morsels across this
     many data-parallel mesh replicas (shard_map per-morsel programs +
     one partial all_gather; None = take EngineConfig.mesh_shards, 0/1 =
@@ -266,9 +262,6 @@ def run_query_stream(input_prefix: str, stream_path: str, time_log: str,
         config.encoded_exec = encoded_exec
     if verify_plans is not None:  # --verify_plans override
         config.verify_plans = verify_plans
-    if pallas_ops is not None:   # --pallas_ops A/B override
-        config.pallas_ops = tuple(
-            x.strip() for x in pallas_ops.split(",") if x.strip())
     if mesh_shards is not None:  # --mesh_shards override
         config.mesh_shards = mesh_shards
     if explain:                  # --explain: profiled timed runs
@@ -581,14 +574,6 @@ def main(argv: list[str] | None = None) -> int:
                         "decode) for A/B runs — streamed morsels then "
                         "ride the plain narrow-lane layout, bit-identical "
                         "results; property: nds.tpu.encoded_exec")
-    p.add_argument("--pallas_ops", default=None, metavar="OPS",
-                   help="comma list of {sort,groupby,gather}: enable the "
-                        "hand-tiled TPU Pallas kernel for that op family "
-                        "(engine/jax_backend/pallas_kernels.py), bit-"
-                        "identical to the default XLA lowering; on the cpu "
-                        "backend kernels run in interpret mode, and a "
-                        "requested kernel that cannot lower is an error "
-                        "naming it; property: nds.tpu.pallas_ops")
     p.add_argument("--mesh_shards", type=int, default=None, metavar="N",
                    help="multi-chip sharded morsel execution: partition "
                         "every streamed scan group's morsels across N "
@@ -634,7 +619,6 @@ def main(argv: list[str] | None = None) -> int:
                      narrow_lanes=False if a.no_narrow_lanes else None,
                      encoded_exec=False if a.no_encoded_exec else None,
                      verify_plans=a.verify_plans,
-                     pallas_ops=a.pallas_ops,
                      mesh_shards=a.mesh_shards,
                      trace=a.trace,
                      explain=a.explain,

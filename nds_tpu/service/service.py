@@ -568,11 +568,6 @@ class QueryService:
         if self.metrics_server is not None:
             self.metrics_server.stop()
             self.metrics_server = None
-        fb = getattr(self.session, "_feedback", None)
-        if fb is not None:
-            # persist observations accumulated since the last periodic
-            # flush — the next attach loads them (adaptive warm start)
-            fb.flush()
 
     def __enter__(self) -> "QueryService":
         return self.start()
@@ -826,7 +821,6 @@ class QueryService:
         from ..sql import parse_sql
         from ..engine.planner import Planner
         from ..engine import streaming
-        from ..engine.jax_backend import pallas_kernels as _pk
         from ..engine.jax_backend.executor import shared_fingerprint
         from ..engine.plan import parameterize_plan
 
@@ -843,19 +837,12 @@ class QueryService:
             if entry is not None:
                 self._plan_cache.move_to_end(ticket.query)
         if entry is None:
-            # label passed EXPLICITLY: planner threads run outside the
-            # session's statement lock, so _active_label belongs to
-            # whatever statement the device lane is executing — the
-            # adaptive catalog must scope observed-row lookups to THIS
-            # ticket's template
-            plan = Planner(session._catalog(ticket.label or "")).plan_query(
+            plan = Planner(session._catalog()).plan_query(
                 parse_sql(ticket.query))
             streams = False
             if use_jax and cfg.out_of_core:
                 jobs = streaming.find_streaming_jobs(
-                    plan,
-                    lambda t: session._est_rows_for(t, 0,
-                                                    ticket.label or ""),
+                    plan, lambda t: session._est_rows.get(t, 0),
                     cfg.out_of_core_min_rows)
                 streams = bool(jobs)
             fp = None
@@ -867,9 +854,7 @@ class QueryService:
                 # literal VALUES — one compiled program serves both
                 pplan, pvals, pdts = parameterize_plan(plan)
                 if pdts:
-                    fp = shared_fingerprint(
-                        pplan, cfg.shard_min_rows,
-                        _pk.parse_ops(cfg.pallas_ops))
+                    fp = shared_fingerprint(pplan, cfg.shard_min_rows)
                     pvalues = tuple(pvals)
             entry = _PlannedQuery(plan, fp, pvalues, streams)
             with self._cv:

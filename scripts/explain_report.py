@@ -25,9 +25,7 @@ pairs, power summaries' and query-log JSONLs' ``node_stats`` maps) merge
 into one table ranked by capacity overprovision — the bucket-drift
 factor between what a schedule provisioned (the static estimate, or the
 ``--chunk_rows`` morsel bucket for streamed nodes) and the LARGEST
-actual any run observed. The top of that list is the feedback store's
-shopping list (``EngineConfig.adaptive_plans`` closes the same loop
-online).
+actual any run observed.
 
 Usage:
   python scripts/explain_report.py summary/explain/query9.json

@@ -73,11 +73,6 @@ STRICT_ZERO = (
     # sweep here means the read path started opening transactions — the
     # pinning-disabled/bit-identical contract broke
     "txn_commits", "txn_rollbacks", "txn_recoveries",
-    # adaptive execution: the gate workload runs with adaptive_plans OFF
-    # (the default), so a feedback hit, profile refresh, or feedback-
-    # driven re-record here means the disabled path built a store or
-    # consulted one — the bit-identical off contract broke
-    "feedback_hits", "feedback_refreshes", "adaptive_replans",
     # distributed serving front door: the gate workload is in-process
     # (no FrontDoorServer, fair_queue/preemption/inflight_dedup all at
     # their off defaults), so any wire request, preemption, dedup share,

@@ -182,11 +182,9 @@ def make_session(wh_dir: str):
     from nds_tpu.engine import Session
     from nds_tpu.power import setup_tables
 
-    decimal = os.environ.get("NDS_TPU_BENCH_DECIMAL", "i64")
-    if decimal == "i64":
-        from nds_tpu.config import enable_x64
-        enable_x64()
-    session = Session(EngineConfig(decimal_physical=decimal))
+    from nds_tpu.config import enable_x64
+    enable_x64()
+    session = Session(EngineConfig(decimal_physical="i64"))
     setup_tables(session, wh_dir, "parquet")
     return session
 
@@ -659,19 +657,17 @@ def main(argv=None) -> int:
                         "scripts/slo_report.py reproduces the SLO "
                         "numbers offline from it")
     p.add_argument("--out", default=os.path.join(REPO, "SERVICE_r01.json"))
-    p.add_argument("--sf", default=os.environ.get("NDS_TPU_BENCH_SF",
-                                                  "0.01"))
+    p.add_argument("--sf", default="0.01")
     a = p.parse_args(argv)
 
-    os.environ["NDS_TPU_BENCH_SF"] = a.sf
-    import bench  # noqa: E402  (repo root; reads NDS_TPU_BENCH_* at import)
+    from benchmark.run import ensure_warehouse
     from nds_tpu.config import maybe_enable_compile_cache
     maybe_enable_compile_cache()
 
     def log(msg):
         print(msg, file=sys.stderr, flush=True)
 
-    wh_dir, _stream = bench.ensure_data()
+    wh_dir = ensure_warehouse(a.sf)
     pool = build_pool()
     counts = [int(x) for x in a.clients.split(",") if x.strip()]
 

@@ -4,7 +4,7 @@
 Accepts any of the formats the obs layer emits and prints the aggregate
 view a Perfetto session would start from:
 
-- Chrome trace-event JSON (``bench.py --trace`` / ``power --trace`` /
+- Chrome trace-event JSON (``power --trace`` /
   ``service_bench.py --trace``): per-span-name rollup (count / total /
   mean / max ms) plus the slowest individual spans with their attributes;
   traces containing ``service/*`` spans additionally get a per-tenant
@@ -13,8 +13,9 @@ view a Perfetto session would start from:
 - JSONL event logs (one event per line, same rollup);
 - flight-recorder JSONL dumps (``obs.flight``): per-event-type counts,
   per-tenant rollup, and the slowest completed tickets;
-- bench JSON lines (the ``bench.py`` stdout object): the engine metrics
-  snapshot, the span rollup, and (schema >= 3) histogram quantile tables;
+- bench JSON lines (the committed ``BENCH_r*.json`` records): the engine
+  metrics snapshot, the span rollup, and (schema >= 3) histogram quantile
+  tables;
 - ``--xplane FILE``: a ``jax.profiler`` trace (``*.xplane.pb``, e.g. from
   ``power --profile_folder``): device time per program from the device's
   own clock (the ``XLA Modules`` line; plan programs are

@@ -108,7 +108,7 @@ def test_untraceable_plan_is_a_visible_fallback(recorded, monkeypatch):
         assert s.sql(q, backend="jax").num_rows == 3
         assert s.last_exec_stats["mode"] == "eager"
         assert "needs host data" in s.last_exec_stats["nojit_reason"]
-        # what --strict and bench.py look at
+        # what --strict looks at
         assert any(f.startswith("nojit:") for f in s.last_fallbacks)
 
 

@@ -15,10 +15,6 @@ Contracts pinned here:
   holds fewer rows than the shard count (whole replicas all-dead);
 - mesh_shards unset/1 leaves the single-chip path untouched (no mesh
   stats, no sharded programs);
-- Pallas kernels dispatch INSIDE shard_map (the PR-7 "mesh executors
-  force empty pallas_ops" restriction is lifted for the sharded morsel
-  path); the GSPMD whole-plan mesh path still records
-  pallas_fallback_reason="mesh";
 - a second sighting's programs are sized from the max over replicas and
   morsels of the first whole pass's checks (tight_morsel_replays), and the
   gathered partials shrink with them;
@@ -176,31 +172,6 @@ def test_wide_layout_shards(data, baseline):
                 label="star4wide")
     assert rows_of(t) == baseline["star"]
     assert st["mesh_shards"] == 4
-
-
-def test_pallas_dispatches_inside_shard_map(data, baseline):
-    """The PR-7 restriction is lifted for the sharded morsel path: with
-    pallas_ops enabled the shard-local replay traces the kernels (cpu =
-    interpret mode runs the real bodies), results stay bit-identical, and
-    the flag is NOT silently dropped."""
-    t, st = run(data, STAR, mesh_shards=8,
-                pallas_ops=("sort", "groupby", "gather"), label="star8pk")
-    assert rows_of(t) == baseline["star"]
-    assert st["mesh_shards"] == 8
-    assert st.get("pallas_ops") == ["gather", "groupby", "sort"]
-    assert "pallas_fallback_reason" not in st
-
-
-def test_gspmd_mesh_records_pallas_fallback_reason(data):
-    """The GSPMD whole-plan mesh path (mesh_shape) still keeps the XLA
-    lowering, but now records WHY: pallas_fallback_reason == "mesh"."""
-    s = make_session(data, mesh_shape=(2,),
-                     pallas_ops=("sort", "groupby", "gather"))
-    s.config.out_of_core = False      # force the in-core GSPMD path
-    s.sql(STAR, backend="jax", label="gspmd")
-    st = s.last_exec_stats
-    assert st.get("pallas_fallback_reason") == "mesh"
-    assert "pallas_ops" not in st
 
 
 def test_device_time_attribution_labels(data, baseline):

@@ -32,7 +32,6 @@ import pytest
 
 from nds_tpu.config import EngineConfig
 from nds_tpu.engine import Session
-from nds_tpu.obs import device_time as peaks
 from nds_tpu.obs import log as obs_log
 from nds_tpu.obs import metrics as om
 from nds_tpu.obs.stats import ExecStats
@@ -382,14 +381,6 @@ def test_program_name_charset_and_length():
     assert name != program_name("weird label: SELECT * FROM t/" + "x" * 201)
     assert program_name("peak of the \u00e9t\u00e9/root") == \
         "nds_peak_of_the_t_root"
-
-
-def test_device_peaks_table_refuses_an_unknown_device():
-    assert peaks.peak_hbm_gbps("TPU v5 lite") == 819.0
-    with pytest.raises(peaks.UnknownDeviceError, match="cpu"):
-        peaks.peak_hbm_gbps("cpu")
-    assert peaks.roofline_bw_gbps({"platform": "cpu",
-                                   "device_kind": "cpu"}) is None
 
 
 def _module_names(session) -> list:

@@ -220,37 +220,6 @@ def test_concurrent_clients_bit_identical(data, serial_ref):
         assert got == want[sql], f"client {i} drifted on {sql!r}"
 
 
-def test_live_config_toggle_races_inflight_queries(data, serial_ref):
-    """EngineConfig.pallas_ops flipped while clients are in flight: the
-    executor invalidates per generation key and every result stays exact
-    (the kernels are bit-identical to XLA by contract)."""
-    session = make_session(data)
-    texts = [q1(5 + i % 3, 60 + i % 3) for i in range(6)]
-    want = {s: serial_ref(s) for s in texts}
-    errors: list = []
-    with QueryService(session, ServiceConfig()) as svc:
-        warm(svc, texts[0])
-
-        def client(i, sql):
-            try:
-                got = svc.sql(sql, label=f"tog{i}", timeout=120).to_pylist()
-                if got != want[sql]:
-                    errors.append((i, sql, "drift"))
-            except Exception as e:
-                errors.append((i, sql, e))
-
-        threads = [threading.Thread(target=client, args=(i, s))
-                   for i, s in enumerate(texts)]
-        for t in threads:
-            t.start()
-        for flip in (("gather",), (), ("gather", "groupby"), ()):
-            session.config.pallas_ops = flip
-            time.sleep(0.05)
-        for t in threads:
-            t.join(timeout=120)
-    assert not errors, errors
-
-
 def test_streamed_query_through_service(data, tmp_path):
     """Out-of-core queries take the serial lane (session streaming path)
     and stay exact vs a fresh single-caller session under the SAME

@@ -10,7 +10,8 @@ import pytest
 
 from nds_tpu.config import EngineConfig
 from nds_tpu.engine import Session
-from nds_tpu.engine.streaming import try_streaming_plan
+from nds_tpu.engine.streaming import (adapt_schedule, inflate_schedule,
+                                      try_streaming_plan)
 
 N_FACT, N_DIM = 50_000, 300
 CHUNK = 4_096  # forces ~13 morsels
@@ -692,3 +693,15 @@ def test_a_resident_statement_keeps_its_recorded_schedule():
     caps = [v for k, v in decisions if k == "cap"]
     assert caps and max(caps) < CHUNK       # actuals, never a bound
     assert METRICS.delta(before).get("tight_morsel_replays", 0) == 0
+
+
+# -- schedule adaptation unit ------------------------------------------------
+
+def test_adapt_schedule_falls_back_and_clamps():
+    dec = [("exact", 3), ("cap", 7), ("cap", 2)]
+    # no observations / structural drift -> plain morsel inflation
+    assert adapt_schedule(dec, 4096, None) == inflate_schedule(dec, 4096)
+    assert adapt_schedule(dec, 4096, [3, 7]) == inflate_schedule(dec, 4096)
+    # observed maxima replace the morsel bound, record actual still floors
+    adapted = adapt_schedule(dec, 4096, [3, 100, 1])
+    assert adapted == [("exact", 3), ("cap", 100), ("cap", 2)]

@@ -112,10 +112,6 @@ def test_system_table_schemas_frozen():
             ("version", "timestamp_ms", "committer", "tables",
              "table_count", "current", "pinned"),
             ("int", "int", "str", "str", "int", "bool", "bool")),
-        "system.plan_feedback": (
-            ("template", "kind", "node", "table", "rows", "sightings",
-             "refreshes", "gen"),
-            ("str", "str", "str", "str", "int", "int", "int", "int")),
     }
     assert set(st.SYSTEM_SCHEMAS) == set(expect)
     for name, (cols, dts) in expect.items():

@@ -62,8 +62,8 @@ def test_template_differential(sessions, number):
 # whole-plan XLA compile is 15-60s/template on the CPU test backend, so the
 # compiled-replay differential runs on a representative spread of plan shapes
 # (correlated subquery, star agg, rollup, window, set op, outer join, union
-# CTE) rather than all 103 units; bench.py exercises the compiled path on the
-# real chip and test_compiled_plans.py covers the machinery.
+# CTE) rather than all 103 units; the benchmark's cells run the compiled path
+# on the real chip and test_compiled_plans.py covers the machinery.
 COMPILED_SUBSET = (1, 5, 12, 22, 51, 93)
 
 

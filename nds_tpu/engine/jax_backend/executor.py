@@ -299,6 +299,10 @@ class CompiledQuery:
         # device trace. name_fingerprint: see program_name
         self.label = label or "program"
         self.module_name = program_name(self.label, name_fingerprint)
+        # FilterNodes of this program that carry their mask instead of
+        # compacting (JaxExecutor._maybe_compact): fixed by the trace, 0
+        # under a mesh; run() moves mask_carried_filters by it
+        self.mask_carried = 0
         self._fn = None
         self._aot = None     # AOT executable from precompile()
         self._aot_specs = None  # flat (shape, dtype) list the AOT was lowered for
@@ -319,6 +323,7 @@ class CompiledQuery:
                          mesh=self.mesh, params=params,
                          shard_min_rows=self.shard_min_rows)
         out = ex.replay(self.plan)
+        self.mask_carried = ex.mask_carried
         if rec.idx != len(rec.decisions):
             raise NotJittable("decision schedule length drift")
         if ex.fallback_nodes:
@@ -512,6 +517,7 @@ class CompiledQuery:
                                 raise aot_err
                     else:
                         out, checks = fn(*args)
+                    _metrics.MASK_CARRIED_FILTERS.inc(self.mask_carried)
                     if TRACER.enabled:
                         jax.block_until_ready((out, checks))
                 with TRACER.span("exec.fetch", cat="device"):
@@ -628,6 +634,7 @@ class BatchedQuery:
             with jax.profiler.TraceAnnotation(self.label):
                 with TRACER.span("exec.wait", cat="device"):
                     out, checks = fn(scan_tuple, stacked)
+                    _metrics.MASK_CARRIED_FILTERS.inc(self.cq.mask_carried)
                     if TRACER.enabled:
                         jax.block_until_ready((out, checks))
                 with TRACER.span("exec.fetch", cat="device"):
@@ -686,6 +693,11 @@ class JaxExecutor:
         # under eager record, traced 0-d arrays under compiled replay
         self._params = params
         self._memo: dict[int, DTable] = {}
+        # ids of the in-flight plan's FilterNodes that carry their mask
+        # instead of compacting (_mask_carrying_filters; _begin sets it per
+        # plan), and how many of them this executor has run
+        self._mask_carry: frozenset = frozenset()
+        self.mask_carried = 0
         self._scan_cache: dict[str, DTable] = scan_tables if scan_tables \
             is not None else {}           # accelerator-resident tables
         self._trace = trace
@@ -1434,8 +1446,15 @@ class JaxExecutor:
         finally:
             self._params = old
 
-    def _eager(self, plan: PlanNode) -> DTable:
+    def _begin(self, plan: PlanNode) -> None:
+        """A fresh memo for one plan, and which of its filters carry their
+        mask: record pass, replay and every member plan of a fused morsel
+        group decide it from the same plan the same way."""
         self._memo = {}
+        self._mask_carry = _mask_carrying_filters(plan)
+
+    def _eager(self, plan: PlanNode) -> DTable:
+        self._begin(plan)
         if self._eager_device is not None:
             with jax.default_device(self._eager_device):
                 return self.execute(plan)
@@ -1540,6 +1559,7 @@ class JaxExecutor:
         node; a replay executor's only entry point."""
         from ..verify import node_labels
         if not isinstance(plan, (list, tuple)):
+            self._begin(plan)
             self._scope_labels = node_labels(plan)
             return self.execute(plan)
         outs = []
@@ -1547,7 +1567,7 @@ class JaxExecutor:
             # memo resets between member plans, mirroring the per-plan
             # record passes (record_plans) so both consume the shared
             # decision schedule identically
-            self._memo = {}
+            self._begin(p)
             self._scope_labels = node_labels(p)
             with jax.named_scope(f"member{i}"):
                 outs.append(self.execute(p))
@@ -1735,7 +1755,34 @@ class JaxExecutor:
         host = HostExecutor(self._load_table)
         return to_device(host.execute(host_node))
 
-    def _maybe_compact(self, t: DTable) -> DTable:
+    def _maybe_compact(self, t: DTable, carry_mask: bool = False) -> DTable:
+        """Compact ``t`` to its survivors' bucket when that is under half its
+        capacity: a 2-operand sort over the capacity and a gather of every
+        column, repaid by every later kernel that is sized by the capacity.
+
+        ``carry_mask`` (a FilterNode that _mask_carrying_filters exempts)
+        returns ``t`` with its narrowed alive mask instead. The rule, read
+        from the plan and the process's x64 setting before anything runs:
+        every consumer of the filter's result, through ProjectNodes only, is
+        a keyless AggregateNode (no group_exprs, no rollup) whose aggregates
+        are non-distinct count_star / count / sum / min / max / avg over
+        operands of an INTEGER physical dtype (int, date, scaled-int
+        decimals; avg only under x64). kernels._seg reduces those by an
+        alive-masked reduce into the ONE static group at 0.1-0.5 ms per 1M
+        rows on a v5e; the sort and gather it would repay cost 39 ms at 4M
+        rows. Outside the rule, and compacting as ever: a float operand (and
+        avg without x64, which sums in float) keeps the segment path in both
+        modes, because its reduction order differs between the paths in the
+        last ULPs, and an n-row scatter into bucket(1) segments is the one
+        consumer a compaction does repay; a distinct aggregate ranks its
+        operand (distinct_within_group sorts the capacity); a string operand
+        goes through _agg_string. Any other parent — a join, a sort, a
+        window, a keyed aggregate, the root, an expression — wants the
+        compacted table.
+
+        The cap decision is recorded either way, so every schedule (record,
+        replay, inflate_schedule, adapt_schedule, the mesh replay) keeps its
+        positions."""
         count_t = t.count()
         count = self._decide_cap(count_t)
         cap = bucket(count)
@@ -1748,6 +1795,9 @@ class JaxExecutor:
             # Shard-local replays skip it for the same schedule shape: the
             # record pass sees one replica-sized slice, and a capacity-
             # relative branch would drift per shard.
+            return t
+        if carry_mask:
+            self.mask_carried += 1
             return t
         if t.capacity <= 2 * cap:
             return t
@@ -1769,8 +1819,9 @@ class JaxExecutor:
             child = self.execute(node.child)
             mask = self._eval(node.predicate, child)
             alive = kernels.filter_alive(child.alive, mask.data, mask.valid)
-            return self._maybe_compact(DTable(list(node.out_names),
-                                              child.cols, alive))
+            return self._maybe_compact(
+                DTable(list(node.out_names), child.cols, alive),
+                carry_mask=id(node) in self._mask_carry)
         if isinstance(node, ProjectNode):
             child = self.execute(node.child)
             cols = [self._eval(e, child) for e in node.exprs]
@@ -2858,6 +2909,65 @@ class JaxExecutor:
 
 
 # -- plan utilities -----------------------------------------------------------
+
+_MASKED_AGG_FUNCS = frozenset(
+    {"count_star", "count", "sum", "min", "max", "avg"})
+
+
+def _masked_reduction(node: AggregateNode) -> bool:
+    """A keyless aggregate that kernels._seg reduces by the alive-masked
+    path alone: ONE static group, integer operands (_maybe_compact)."""
+    if node.group_exprs or node.rollup or node.rollup_levels is not None:
+        return False
+    x64 = jax.config.read("jax_enable_x64")
+    for s in node.aggs:
+        if s.distinct or s.func not in _MASKED_AGG_FUNCS:
+            return False
+        if s.func == "avg" and not x64:
+            return False          # sums in float: the segment path
+        if s.arg is not None and (
+                s.arg.dtype == "str" or not jnp.issubdtype(
+                    phys_dtype(s.arg.dtype), jnp.integer)):
+            return False
+    return True
+
+
+def _mask_carrying_filters(root: PlanNode) -> frozenset:
+    """ids of the FilterNodes under ``root`` (subquery plans included) whose
+    EVERY consumer, through ProjectNodes only, is a _masked_reduction: they
+    hand on their child's columns under the narrowed alive mask instead of
+    compacting (_maybe_compact). execute() memoises by id(node), so a
+    filter or a projection two parents share runs once: one parent of
+    another kind — the root and an expression count as such — and the
+    filter compacts."""
+    from ..streaming import _expr_subplans
+    consumers: dict[int, list] = {id(root): [None]}
+    filters = []
+    for n in iter_plan_nodes(root):
+        if isinstance(n, MaterializedNode):
+            continue
+        if isinstance(n, FilterNode):
+            filters.append(n)
+        for f in ("child", "left", "right"):
+            sub = getattr(n, f, None)
+            if isinstance(sub, PlanNode):
+                consumers.setdefault(id(sub), []).append(n)
+        for sub in _expr_subplans(n):      # a BScalarSubquery's plan
+            consumers.setdefault(id(sub), []).append(None)
+
+    memo: dict[int, bool] = {}
+
+    def reduced(n: PlanNode) -> bool:
+        got = memo.get(id(n))
+        if got is None:
+            got = memo[id(n)] = all(
+                (isinstance(c, AggregateNode) and _masked_reduction(c)) or
+                (isinstance(c, ProjectNode) and reduced(c))
+                for c in consumers.get(id(n), (None,)))
+        return got
+
+    return frozenset(id(f) for f in filters if reduced(f))
+
 
 def _plan_fingerprint(node, mat_by_identity: bool = True) -> str:
     """Stable structural hash of a plan subtree (for executor-synthesized

@@ -975,7 +975,7 @@ class Session:
             jexec.query_label = self._active_label
             jexec.query_label_auto = self._label_auto
             jexec.fallback_nodes = []
-            jexec._memo = {}
+            jexec._begin(plan)
             ctx = _jax.default_device(jexec._eager_device) \
                 if jexec._eager_device is not None \
                 else contextlib.nullcontext()

@@ -610,6 +610,11 @@ TIGHT_MORSEL_REPLAYS = METRICS.counter(
     "tight_morsel_replays", "streamed morsels replayed by programs whose "
     "capacities are what the statement's first whole pass saw, not the "
     "morsel bound (a second or later sighting)")
+MASK_CARRIED_FILTERS = METRICS.counter(
+    "mask_carried_filters", "filters of dispatched programs that handed on "
+    "their narrowed alive mask instead of compacting, because only keyless "
+    "integer aggregates consume them (a static count per program; 0 under "
+    "a mesh, where nothing compacts)")
 COLLECTIVE_BYTES = METRICS.counter(
     "collective_bytes", "per-chip ingress of the sharded morsels' partial "
     "all_gathers by the ring model: (n-1)/n of the gathered total")

@@ -86,9 +86,11 @@ STRICT_ZERO = (
 #: report-only name suffixes: wall-clock and byte-volume metrics flake
 #: with host load / layout evolution — printed for the log, never gated.
 #: tight_morsel_replays counts how often a streamed statement was seen
-#: again, which is the workload's choice and no behaviour of the engine
+#: again, which is the workload's choice and no behaviour of the engine;
+#: mask_carried_filters counts dispatches of programs whose plan holds a
+#: filter under a keyless integer aggregate, the workload's choice too
 REPORT_ONLY_SUFFIXES = ("_ms", "_bytes", "bytes_uploaded", "bytes_fetched",
-                        "tight_morsel_replays")
+                        "tight_morsel_replays", "mask_carried_filters")
 
 RATIO_LO, RATIO_HI = 0.5, 2.0
 ABS_SLACK = 2

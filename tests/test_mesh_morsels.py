@@ -284,6 +284,60 @@ def test_a_second_sighting_is_sized_from_every_replicas_checks(
     assert third["tight_morsel_replays"] == morsels
 
 
+# query9's shape over the streamed table: every member a keyless count / sum /
+# avg over one `between` filter. On one chip such a filter carries its mask
+# instead of compacting (JaxExecutor._maybe_compact); a replica never
+# compacted, so the rule takes nothing out there and its counter stays.
+MASKQ = ("SELECT (SELECT COUNT(*) FROM fact WHERE day BETWEEN 0 AND 60) AS a, "
+         "(SELECT AVG(amt) FROM fact WHERE day BETWEEN 0 AND 60) AS b, "
+         "(SELECT SUM(qty) FROM fact WHERE day BETWEEN 61 AND 120) AS c "
+         "FROM dim WHERE dk = 0")
+
+
+def _mask_sightings(data, n: int, label: str):
+    """Two sightings of MASKQ at `n` shards: rows, the group's schedule
+    shapes (first sighting's, tight) and what mask_carried_filters moved."""
+    from nds_tpu.engine.streaming import schedule_shape
+    from nds_tpu.obs.metrics import METRICS
+    s = make_session(data, mesh_shards=n)
+    before = METRICS.snapshot()
+    rows, shapes = [], []
+    for _ in range(2):
+        rows.append(rows_of(s.sql(MASKQ, backend="jax", label=label)))
+        st = dict(s.last_exec_stats)
+        assert st["mode"] == "streaming" and st.get("re_records", 0) == 0
+        assert st.get("mesh_shards", 1) == max(n, 1)
+        (state,) = s._stream_cache[MASKQ]["gstates"]
+        shapes.append([schedule_shape(cq.decisions) for cq in state["cqs"]])
+    assert state["tight"] is True and rows[0] == rows[1]
+    moved = METRICS.delta(before)
+    assert moved.get("morsel_re_records", 0) == 0
+    return rows[0], shapes, moved.get("mask_carried_filters", 0)
+
+
+@pytest.mark.parametrize("n", [2, 4])
+def test_a_replica_never_compacted_so_the_mask_rule_takes_nothing_out(
+        data, n, monkeypatch):
+    """Rows bit-identical to the one-chip run, whose three exempt filters a
+    morsel move the counter; at `n` shards it stays 0, and the schedule has
+    the shape it has with the rule held off, position for position."""
+    from nds_tpu.engine.jax_backend import executor as X
+    morsels = -(-N_FACT // CHUNK)
+    one_rows, one_shapes, one_moved = _mask_sightings(data, 0, f"mask1_{n}")
+    assert one_moved == 2 * 3 * morsels
+    rows, shapes, moved = _mask_sightings(data, n, f"mask{n}")
+    assert rows == one_rows and moved == 0
+    # the same decisions in the same positions as on one chip
+    assert [[k for k, _v in sh] for sh in shapes[0]] == \
+        [[k for k, _v in sh] for sh in one_shapes[0]]
+    X.clear_shared_programs()
+    monkeypatch.setattr(X, "_mask_carrying_filters", lambda plan: frozenset())
+    off_rows, off_shapes, off_moved = _mask_sightings(data, n, f"mask{n}")
+    assert off_rows == rows and off_shapes == shapes and off_moved == 0
+    one_off = _mask_sightings(data, 0, f"mask1_{n}")
+    assert one_off == (one_rows, one_shapes, 0)
+
+
 def test_the_collective_span_ends_before_the_fetch_begins(data, baseline):
     """Tracer on: every `collective` span covers the gather program alone
     and the gathered partials' copy to the host is the `exec.fetch` after

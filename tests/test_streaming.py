@@ -347,7 +347,7 @@ KEYLESS_WHERE = {
 }
 
 
-def keyless_session(fuse: bool) -> Session:
+def keyless_session(fuse: bool, **cfg) -> Session:
     from decimal import Decimal
     n = KEYLESS_MORSELS * CHUNK
     rng = np.random.default_rng(26)
@@ -361,10 +361,10 @@ def keyless_session(fuse: bool) -> Session:
                         type=pa.decimal128(7, 2)),
         "price": pa.array(np.round(rng.uniform(1, 100, n), 2)),
     })
-    cfg = EngineConfig(out_of_core=True, chunk_rows=CHUNK,
-                       out_of_core_min_rows=10_000, decimal_physical="i64",
-                       stream_fusion_max_branches=0 if fuse else 1)
-    s = Session(cfg)
+    cfg = {"out_of_core_min_rows": 10_000, **cfg}
+    s = Session(EngineConfig(
+        out_of_core=True, chunk_rows=CHUNK, decimal_physical="i64",
+        stream_fusion_max_branches=0 if fuse else 1, **cfg))
     s.register_arrow("sales", sales)
     s.register_arrow("one", pa.table({"k": pa.array([1], type=pa.int32())}))
     return s
@@ -705,3 +705,286 @@ def test_adapt_schedule_falls_back_and_clamps():
     # observed maxima replace the morsel bound, record actual still floors
     adapted = adapt_schedule(dec, 4096, [3, 100, 1])
     assert adapted == [("exact", 3), ("cap", 100), ("cap", 2)]
+
+
+# -- a filter that only feeds keyless integer aggregates carries its mask -----
+# query9's shape: scalar subqueries, each count(*) / avg over one `between`
+# filter of the fact table. Each filter keeps about a fifth of the rows, so
+# its survivors' bucket is under half the capacity and _maybe_compact would
+# sort the capacity and gather every column — for a masked reduce into one
+# group, which repays neither. Such a filter hands on its narrowed alive mask
+# (JaxExecutor._maybe_compact, _mask_carrying_filters); its cap decision stays
+# in the schedule.
+
+MASK_MEMBERS = 6
+
+
+def mask_query(where: str = "") -> str:
+    """`where` adds a second FilterNode under each member's, which feeds a
+    filter and so compacts as ever (it keeps whole morsels or none)."""
+    def bucket_of(lo: int, hi: int, over: int) -> str:
+        w = (where and f"{where} AND ") + f"qty BETWEEN {lo} AND {hi}"
+        return (f"CASE WHEN (SELECT COUNT(*) FROM sales WHERE {w}) > {over} "
+                f"THEN (SELECT AVG(amt) FROM sales WHERE {w}) "
+                f"ELSE (SELECT AVG(qty) FROM sales WHERE {w}) END")
+    return (f"SELECT {bucket_of(1, 20, 100)} AS b1, "
+            f"{bucket_of(21, 40, 1 << 30)} AS b2 FROM one WHERE k = 1")
+
+
+def compaction_ops(text: str) -> list:
+    """What _maybe_compact leaves in a program under a FilterNode's scope:
+    compaction_perm's sort and the gather of the columns."""
+    import re
+    return sorted(o for o in set(re.findall(r'"(jit\([^"]*)"', text))
+                  if re.search(r"/FilterNode#\d+/(compaction_perm/|gather)",
+                               o))
+
+
+def resident_program(s: Session, q: str, label: str = "mask"):
+    """Run `q` in-core, recorded and then replayed compiled; returns (rows, the
+    CompiledQuery, its lowered text with scopes, its compiled text)."""
+    for _ in range(2):
+        got = s.sql(q, backend="jax", label=label)
+    assert s.last_exec_stats["mode"] == "compile+run"
+    je = s._jax_executor()
+    (ent,) = [e for e in je._plans.values() if e.get("cq") is not None]
+    cq = ent["cq"]
+    lowered = cq._fn.lower(*cq._args(je._scans_for(ent),
+                                     ent.get("params", ())))
+    return (rows_of(got), cq, lowered.as_text(debug_info=True),
+            lowered.compile().as_text())
+
+
+def resident_session() -> Session:
+    return keyless_session(True, out_of_core_min_rows=1 << 30)
+
+
+def test_a_query9_shaped_resident_statement_compacts_no_filter(monkeypatch):
+    from nds_tpu.engine.jax_backend import executor as X
+    from nds_tpu.obs.metrics import METRICS
+    q = mask_query()
+    s = resident_session()
+    oracle = rows_of(s.sql(q, backend="numpy"))
+    before = METRICS.snapshot()
+    rows, cq, traced, compiled = resident_program(s, q)
+    assert rows == oracle and rows[0][0] is not None
+    assert cq.mask_carried == MASK_MEMBERS
+    assert METRICS.delta(before)["mask_carried_filters"] == MASK_MEMBERS
+    assert not compaction_ops(traced) and not compaction_ops(compiled)
+    # the same statement with the rule held off: the same answer from the
+    # same schedule (every filter's cap is still a decision), and the
+    # compactions the rule took out
+    X.clear_shared_programs()
+    monkeypatch.setattr(X, "_mask_carrying_filters", lambda plan: frozenset())
+    s_off = resident_session()
+    before = METRICS.snapshot()
+    rows_off, cq_off, traced_off, compiled_off = resident_program(s_off, q)
+    assert rows_off == oracle and cq_off.mask_carried == 0
+    assert METRICS.delta(before).get("mask_carried_filters", 0) == 0
+    assert cq.decisions == cq_off.decisions
+    assert [k for k, _v in cq.decisions] == ["cap"] * (MASK_MEMBERS + 1)
+    assert cq.module_name == cq_off.module_name
+    # (a compaction only count(*) consumes is dead code to JAX's lowering
+    # already: the four avg members' stay, in the compiled text too)
+    for text in (traced_off, compiled_off):
+        assert len({o.split("/compaction_perm/")[0]
+                    for o in compaction_ops(text)
+                    if "/compaction_perm/" in o}) == MASK_MEMBERS - 2
+
+
+RULE_OFF = {
+    "float_operand":
+        "SELECT (SELECT AVG(price) FROM sales WHERE qty BETWEEN 1 AND 20) "
+        "AS a FROM one WHERE k = 1",
+    "count_distinct":
+        "SELECT (SELECT COUNT(DISTINCT qty) FROM sales WHERE pos < 2000) "
+        "AS a FROM one WHERE k = 1",
+    "keyed_aggregate":
+        "SELECT qty, SUM(amt) AS s FROM sales WHERE qty BETWEEN 1 AND 20 "
+        "GROUP BY qty ORDER BY qty",
+    "filter_under_a_join":
+        "SELECT COUNT(*) AS c, SUM(amt) AS s FROM sales JOIN one ON k = qty "
+        "WHERE pos < 2000",
+    # the CTE's filter runs once for two parents: a keyless SUM and a sort
+    "second_parent_is_a_sort":
+        "WITH f AS (SELECT pos, qty, amt FROM sales "
+        "WHERE qty BETWEEN 1 AND 20) "
+        "SELECT (SELECT SUM(amt) FROM f) AS a, (SELECT MAX(pos) FROM "
+        "(SELECT pos FROM f ORDER BY amt, pos LIMIT 5) t) AS b "
+        "FROM one WHERE k = 1",
+}
+
+
+@pytest.mark.parametrize("case", sorted(RULE_OFF))
+def test_the_filter_still_compacts_where_the_rule_does_not_hold(case):
+    from nds_tpu.obs.metrics import METRICS
+    q = RULE_OFF[case]
+    s = resident_session()
+    oracle = rows_of(s.sql(q, backend="numpy"))
+    before = METRICS.snapshot()
+    rows, cq, traced, compiled = resident_program(s, q)
+    assert rows == oracle and rows
+    assert cq.mask_carried == 0
+    assert METRICS.delta(before).get("mask_carried_filters", 0) == 0
+    assert compaction_ops(traced) and compaction_ops(compiled)
+    if case == "second_parent_is_a_sort":
+        # one filter, one decision, two parents
+        assert len({o.split("/FilterNode#")[1].split("/")[0]
+                    for o in compaction_ops(traced)}) == 1
+
+
+def test_avg_without_x64_sums_in_float_and_keeps_the_compaction():
+    """x64 off, kernels.agg_apply averages in float: the segment path, which
+    a compaction does repay. count(*) and an integer sum stay exempt."""
+    import jax
+    from nds_tpu.engine.jax_backend.executor import (_mask_carrying_filters,
+                                                     _masked_reduction)
+    from nds_tpu.engine.plan import AggregateNode, FilterNode, iter_plan_nodes
+    from nds_tpu.engine.planner import Planner
+    from nds_tpu.sql import parse_sql
+    s = resident_session()
+    plan = Planner(s._catalog()).plan_query(parse_sql(mask_query()))
+    aggs = [n for n in iter_plan_nodes(plan) if isinstance(n, AggregateNode)]
+    filters = [n for n in iter_plan_nodes(plan) if isinstance(n, FilterNode)]
+    assert len(aggs) == MASK_MEMBERS and len(filters) == MASK_MEMBERS + 1
+    assert jax.config.read("jax_enable_x64")
+    assert all(_masked_reduction(a) for a in aggs)
+    assert len(_mask_carrying_filters(plan)) == MASK_MEMBERS
+    with jax.enable_x64(False):
+        exempt = [a for a in aggs if _masked_reduction(a)]
+        assert [[x.func for x in a.aggs] for a in exempt] == \
+            [["count_star"]] * 2
+        assert len(_mask_carrying_filters(plan)) == 2
+
+
+def test_a_shared_projection_needs_every_parent_to_be_a_reduction():
+    """execute() memoises by id(node): a filter (or a projection over it)
+    that two parents share runs once, so one parent of another kind — a
+    sort, the root, an expression — and it compacts."""
+    from nds_tpu.engine.jax_backend.executor import _mask_carrying_filters
+    from nds_tpu.engine.plan import (AggregateNode, AggSpec, BCol, BLit,
+                                     BScalarSubquery, FilterNode, ProjectNode,
+                                     ScanNode, SetOpNode, SortKey, SortNode)
+    qty, amt = BCol("int", 0, "qty"), BCol("dec2", 1, "amt")
+    kw = {"out_names": ["qty", "amt"], "out_dtypes": ["int", "dec2"]}
+
+    def scan():
+        return ScanNode("sales", ["qty", "amt"], **kw)
+
+    def keyless(child, *aggs):
+        return AggregateNode(child, [], list(aggs), out_names=["v"],
+                             out_dtypes=["int"])
+
+    f = FilterNode(scan(), BLit("bool", True), **kw)
+    proj = ProjectNode(f, [qty, amt], **kw)
+    total = keyless(proj, AggSpec("sum", amt))
+    count = keyless(f, AggSpec("count_star", None))
+    both = SetOpNode("union", True, total, count, out_names=["v"],
+                     out_dtypes=["int"])
+    assert _mask_carrying_filters(both) == {id(f)}
+    # a third parent that sorts the projection
+    sort = SortNode(proj, [SortKey(qty)], **kw)
+    union = SetOpNode("union", True, both, keyless(sort, AggSpec("min", qty)),
+                      out_names=["v"], out_dtypes=["int"])
+    assert _mask_carrying_filters(union) == frozenset()
+    # the root, and a subquery expression's plan, consume as they are
+    assert _mask_carrying_filters(f) == frozenset()
+    assert _mask_carrying_filters(proj) == frozenset()
+    sub = ProjectNode(scan(), [BScalarSubquery("int", f)], **kw)
+    assert _mask_carrying_filters(keyless(sub, AggSpec("count_star", None))) \
+        == frozenset()
+    # a keyed or a distinct reduction is no such consumer
+    keyed = AggregateNode(f, [qty], [AggSpec("sum", amt)],
+                          out_names=["qty", "v"], out_dtypes=["int", "dec2"])
+    assert _mask_carrying_filters(keyed) == frozenset()
+    assert _mask_carrying_filters(
+        keyless(f, AggSpec("count", qty, distinct=True))) == frozenset()
+
+
+def mask_sighting(s: Session, q: str, monkeypatch):
+    """One streamed run of `q` against the numpy oracle: what the counters
+    moved by, and run_spied's dispatches."""
+    from nds_tpu.obs.metrics import METRICS
+    oracle = rows_of(s.sql(q, backend="numpy"))
+    before = METRICS.snapshot()
+    with monkeypatch.context() as m:
+        got, _cap_nodes, dispatches = run_spied(s, q, m)
+    assert rows_of(got) == oracle
+    moved = METRICS.delta(before)
+    return ({k: moved.get(k, 0) for k in TIGHT_COUNTERS +
+             ("mask_carried_filters",)}, dispatches, rows_of(got))
+
+
+@pytest.mark.parametrize("fuse", [True, False], ids=["fused", "per_member"])
+def test_a_tight_morsel_program_compacts_no_exempt_filter(fuse, monkeypatch):
+    """The second sighting's caps (about a fifth of a morsel) are under half
+    the morsel's capacity: the tight program would compact in every member,
+    where the inflated one never could. It carries the masks instead, and
+    the third morsel, which the predicate on pos empties for every member,
+    still yields each member's one row."""
+    from nds_tpu.engine.jax_backend.device import bucket
+    from nds_tpu.engine.streaming import schedule_shape
+    s = keyless_session(fuse)
+    q = mask_query(f"pos < {2 * CHUNK}")
+    per_sighting = KEYLESS_MORSELS * MASK_MEMBERS
+    first, _d, rows = mask_sighting(s, q, monkeypatch)
+    assert rows[0][0] is not None and rows[0][1] is not None
+    assert first["tight_morsel_replays"] == 0
+    assert first["mask_carried_filters"] == per_sighting
+    states = s._stream_cache[q]["gstates"]
+    assert [st["tight"] for st in states] == [True]
+    for cq in states[0]["cqs"]:
+        # a member's decisions: the filter on pos, then the exempt one
+        caps = [v for k, v in cq.decisions if k == "cap"][1::2]
+        assert caps and all(2 * bucket(v) < bucket(CHUNK) for v in caps)
+    shape = [schedule_shape(cq.decisions) for cq in states[0]["cqs"]]
+
+    second, dispatches, _rows = mask_sighting(s, q, monkeypatch)
+    assert second["tight_morsel_replays"] == KEYLESS_MORSELS
+    assert second["mask_carried_filters"] == per_sighting
+    assert second["morsel_re_records"] == second["replay_mismatches"] == 0
+    assert second["compiles"] == (1 if fuse else MASK_MEMBERS)
+    programs = {id(cq): (cq, specs) for cq, specs, _out in dispatches}
+    assert len(programs) == (1 if fuse else MASK_MEMBERS)
+    for cq, specs in programs.values():
+        assert cq in states[0]["cqs"]
+        assert cq.mask_carried == (MASK_MEMBERS if fuse else 1)
+        lowered = cq._fn.lower(*specs)
+        assert not compaction_ops(lowered.as_text(debug_info=True))
+        assert not compaction_ops(lowered.compile().as_text())
+    # every morsel, the emptied third too, hands back one row a member
+    partials = [t for _cq, _specs, out in dispatches
+                for t in (out if fuse else [out])]
+    assert len(partials) == per_sighting
+    assert [int(np.asarray(t.alive).sum()) for t in partials] == \
+        [1] * per_sighting
+
+    third, _d, _rows = mask_sighting(s, q, monkeypatch)
+    assert third["compiles"] == 0
+    assert third["tight_morsel_replays"] == KEYLESS_MORSELS
+    assert third["mask_carried_filters"] == per_sighting
+    assert [schedule_shape(cq.decisions)
+            for cq in s._stream_cache[q]["gstates"][0]["cqs"]] == shape
+
+
+def test_the_streamed_schedule_is_what_it_was_without_the_rule(monkeypatch):
+    """The exempt filter's cap stays a decision: first-sighting and tight
+    schedules have the shape they had, position for position."""
+    from nds_tpu.engine.jax_backend import executor as X
+    from nds_tpu.engine.streaming import schedule_shape
+    q = mask_query()
+
+    def shapes():
+        s = keyless_session(True)
+        out = []
+        for _ in range(2):
+            mask_sighting(s, q, monkeypatch)
+            (state,) = s._stream_cache[q]["gstates"]
+            out.append(([schedule_shape(cq.decisions)
+                         for cq in state["cqs"]], state["raw"], state["obs"]))
+        return out
+
+    on = shapes()
+    X.clear_shared_programs()
+    monkeypatch.setattr(X, "_mask_carrying_filters", lambda plan: frozenset())
+    assert shapes() == on

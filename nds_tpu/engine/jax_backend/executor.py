@@ -303,6 +303,9 @@ class CompiledQuery:
         # compacting (JaxExecutor._maybe_compact): fixed by the trace, 0
         # under a mesh; run() moves mask_carried_filters by it
         self.mask_carried = 0
+        # (window nodes, rollup grouping sets, set operations, outer joins)
+        # this program holds; count_dispatch() moves their counters by it
+        self.plan_shapes = _plan_shapes(plan)
         self._fn = None
         self._aot = None     # AOT executable from precompile()
         self._aot_specs = None  # flat (shape, dtype) list the AOT was lowered for
@@ -329,6 +332,12 @@ class CompiledQuery:
         if ex.fallback_nodes:
             raise NotJittable(f"fallback under trace: {ex.fallback_nodes}")
         return out, rec.checks
+
+    def count_dispatch(self) -> None:
+        """One dispatch of this program: what its plan holds, counted."""
+        _metrics.MASK_CARRIED_FILTERS.inc(self.mask_carried)
+        for counter, n in zip(_PLAN_SHAPE_COUNTERS, self.plan_shapes):
+            counter.inc(n)
 
     def _args(self, scans: dict, values: tuple) -> tuple:
         missing = [k for k in self.scan_keys if k not in scans]
@@ -517,7 +526,7 @@ class CompiledQuery:
                                 raise aot_err
                     else:
                         out, checks = fn(*args)
-                    _metrics.MASK_CARRIED_FILTERS.inc(self.mask_carried)
+                    self.count_dispatch()
                     if TRACER.enabled:
                         jax.block_until_ready((out, checks))
                 with TRACER.span("exec.fetch", cat="device"):
@@ -634,7 +643,7 @@ class BatchedQuery:
             with jax.profiler.TraceAnnotation(self.label):
                 with TRACER.span("exec.wait", cat="device"):
                     out, checks = fn(scan_tuple, stacked)
-                    _metrics.MASK_CARRIED_FILTERS.inc(self.cq.mask_carried)
+                    self.cq.count_dispatch()
                     if TRACER.enabled:
                         jax.block_until_ready((out, checks))
                 with TRACER.span("exec.fetch", cat="device"):
@@ -1993,13 +2002,7 @@ class JaxExecutor:
     # -- aggregate -----------------------------------------------------------
     def _run_aggregate(self, node: AggregateNode) -> DTable:
         child = self.execute(node.child)
-        if node.rollup_levels is not None:
-            grouping_sets = [list(range(k)) for k in node.rollup_levels]
-        elif node.rollup:
-            grouping_sets = [list(range(k))
-                             for k in range(len(node.group_exprs), -1, -1)]
-        else:
-            grouping_sets = [list(range(len(node.group_exprs)))]
+        grouping_sets = _grouping_sets(node)
         if self._sorted_agg_eligible(node, child, grouping_sets):
             return self._aggregate_sorted(node, child, grouping_sets)
         pieces = [self._aggregate_one_sharded(node, child, keep)
@@ -2930,6 +2933,40 @@ def _masked_reduction(node: AggregateNode) -> bool:
                     phys_dtype(s.arg.dtype), jnp.integer)):
             return False
     return True
+
+
+def _grouping_sets(node: AggregateNode) -> list:
+    """The grouping sets ``node`` emits, each the indices of its group_exprs
+    that stay keys: a rollup's prefixes, the full one first (or the levels a
+    segmented rollup names), else the one full set."""
+    if node.rollup_levels is not None:
+        return [list(range(k)) for k in node.rollup_levels]
+    if node.rollup:
+        return [list(range(k))
+                for k in range(len(node.group_exprs), -1, -1)]
+    return [list(range(len(node.group_exprs)))]
+
+
+_PLAN_SHAPE_COUNTERS = (_metrics.WINDOW_NODES, _metrics.ROLLUP_SETS,
+                        _metrics.SETOP_NODES, _metrics.OUTER_JOINS)
+
+
+def _plan_shapes(plan) -> tuple:
+    """(WindowNodes, grouping sets that rollup AggregateNodes emit,
+    SetOpNodes, outer JoinNodes) of one program's plan, or of the member
+    plans of a fused group; a node two parents share counts once. Another
+    compile unit's nodes stand behind a VirtualScanNode and are its own."""
+    windows = sets = setops = outer = 0
+    for n in iter_plan_nodes(plan):
+        if isinstance(n, WindowNode):
+            windows += 1
+        elif isinstance(n, AggregateNode) and n.rollup:
+            sets += len(_grouping_sets(n))
+        elif isinstance(n, SetOpNode):
+            setops += 1
+        elif isinstance(n, JoinNode) and n.kind in ("left", "right", "full"):
+            outer += 1
+    return windows, sets, setops, outer
 
 
 def _mask_carrying_filters(root: PlanNode) -> frozenset:

@@ -615,6 +615,22 @@ MASK_CARRIED_FILTERS = METRICS.counter(
     "their narrowed alive mask instead of compacting, because only keyless "
     "integer aggregates consume them (a static count per program; 0 under "
     "a mesh, where nothing compacts)")
+# Plan shapes a dispatched program holds (static counts fixed when the
+# program is built, moved per dispatch like mask_carried_filters; eager and
+# nojit executions move none): 0 where a statement is expected to hold one
+# means the stratum never reached a compiled program
+WINDOW_NODES = METRICS.counter(
+    "window_nodes", "WindowNodes of dispatched compiled programs (a static "
+    "count per program)")
+ROLLUP_SETS = METRICS.counter(
+    "rollup_sets", "grouping sets that the rollup AggregateNodes of "
+    "dispatched compiled programs emit (a static count per program)")
+SETOP_NODES = METRICS.counter(
+    "setop_nodes", "SetOpNodes (UNION, INTERSECT, EXCEPT) of dispatched "
+    "compiled programs (a static count per program)")
+OUTER_JOINS = METRICS.counter(
+    "outer_joins", "left, right and full outer JoinNodes of dispatched "
+    "compiled programs (a static count per program)")
 COLLECTIVE_BYTES = METRICS.counter(
     "collective_bytes", "per-chip ingress of the sharded morsels' partial "
     "all_gathers by the ring model: (n-1)/n of the gathered total")

@@ -750,3 +750,48 @@ def test_bytes_fetched_counts_what_a_dispatch_returns(monkeypatch):
     assert returned[-1] == 4 * len(cq.decisions) + sum(
         leaf.nbytes for leaf in jax.tree_util.tree_leaves(outputs[-1]))
     assert "bytes_fetched" in om.METRICS.describe()
+
+
+# -- the plan shapes a dispatched program holds (ISSUE 32) ---------------------
+
+SHAPE_COUNTERS = ("window_nodes", "rollup_sets", "setop_nodes", "outer_joins")
+
+
+@pytest.mark.parametrize("sql,want", [
+    ("SELECT k, v, RANK() OVER (PARTITION BY k ORDER BY v) AS r FROM t "
+     "WHERE v < 50 ORDER BY k, v", (1, 0, 0, 0)),
+    ("SELECT k, SUM(v) AS sv FROM t GROUP BY ROLLUP (k) ORDER BY k",
+     (0, 2, 0, 0)),
+    ("SELECT k FROM t WHERE v < 100 INTERSECT SELECT k FROM t WHERE v > 900 "
+     "UNION ALL SELECT k FROM t WHERE v = 500", (0, 0, 2, 0)),
+    ("SELECT t.k, u.w FROM t LEFT OUTER JOIN u ON t.k = u.k WHERE t.v < 20 "
+     "ORDER BY 1, 2", (0, 0, 0, 1)),
+    ("SELECT t.k, COUNT(*) AS c FROM t, u WHERE t.k = u.k GROUP BY t.k "
+     "ORDER BY 1", (0, 0, 0, 0)),
+], ids=["window", "rollup", "setops", "outer_join", "none"])
+def test_plan_shape_counters_move_by_the_programs_static_counts(sql, want):
+    """window_nodes / rollup_sets / setop_nodes / outer_joins move at each
+    dispatch of a compiled program by what its plan holds, and by nothing
+    where no program is dispatched: the host backend, the record pass."""
+    s = make_session()
+    s.register_arrow("u", pa.table({
+        "k": pa.array([0, 1, 2, 9], type=pa.int32()),
+        "w": pa.array([10, 11, 12, 19], type=pa.int64())}))
+
+    def moved(before):
+        d = om.METRICS.delta(before)
+        return tuple(d.get(name, 0) for name in SHAPE_COUNTERS)
+
+    before = om.METRICS.snapshot()
+    oracle = s.sql(sql, backend="numpy").to_pylist()
+    s.sql(sql, backend="jax")                   # the record pass
+    assert moved(before) == (0, 0, 0, 0)
+    for dispatch in (1, 2):
+        got = s.sql(sql, backend="jax")
+        assert s.last_exec_stats["mode"] in ("compiled", "compile+run")
+        assert moved(before) == tuple(dispatch * n for n in want)
+    assert got.to_pylist() == oracle
+    cq = s._jax_exec._plans[("sql", sql)]["cq"]
+    assert cq.plan_shapes == want
+    for name in SHAPE_COUNTERS:
+        assert name in om.METRICS.describe()

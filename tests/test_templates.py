@@ -63,8 +63,11 @@ def test_template_differential(sessions, number):
 # compiled-replay differential runs on a representative spread of plan shapes
 # (correlated subquery, star agg, rollup, window, set op, outer join, union
 # CTE) rather than all 103 units; the benchmark's cells run the compiled path
-# on the real chip and test_compiled_plans.py covers the machinery.
-COMPILED_SUBSET = (1, 5, 12, 22, 51, 93)
+# on the real chip and test_compiled_plans.py covers the machinery. 20, 38,
+# 57 and 86 are, with 93, the units of the benchmark's power_stratified_sf1
+# cell (ISSUE 32): what that cell compares against its plain references on
+# the chip is held to the numpy oracle here.
+COMPILED_SUBSET = (1, 5, 12, 20, 22, 38, 51, 57, 86, 93)
 
 
 @pytest.mark.parametrize("number", COMPILED_SUBSET)

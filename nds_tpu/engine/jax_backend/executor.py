@@ -252,6 +252,13 @@ def count_fetched(fetched) -> None:
     _metrics.BYTES_FETCHED.inc(device_bytes(fetched))
 
 
+def count_join_paths(join_paths: tuple) -> None:
+    """Add one dispatched program's (direct-address, sort-based) joins to
+    direct_joins / sorted_joins."""
+    _metrics.DIRECT_JOINS.inc(join_paths[0])
+    _metrics.SORTED_JOINS.inc(join_paths[1])
+
+
 def _verify_schedule(decisions: list, checks_host: list) -> None:
     for (kind, planned), actual in zip(decisions, checks_host):
         a = int(actual)
@@ -303,6 +310,9 @@ class CompiledQuery:
         # compacting (JaxExecutor._maybe_compact): fixed by the trace, 0
         # under a mesh; run() moves mask_carried_filters by it
         self.mask_carried = 0
+        # (direct-address, sort-based) joins of this program: which path
+        # each JoinNode took is a recorded decision, so fixed by the trace
+        self.join_paths = (0, 0)
         # (window nodes, rollup grouping sets, set operations, outer joins)
         # this program holds; count_dispatch() moves their counters by it
         self.plan_shapes = _plan_shapes(plan)
@@ -327,6 +337,7 @@ class CompiledQuery:
                          shard_min_rows=self.shard_min_rows)
         out = ex.replay(self.plan)
         self.mask_carried = ex.mask_carried
+        self.join_paths = ex.join_paths
         if rec.idx != len(rec.decisions):
             raise NotJittable("decision schedule length drift")
         if ex.fallback_nodes:
@@ -336,6 +347,7 @@ class CompiledQuery:
     def count_dispatch(self) -> None:
         """One dispatch of this program: what its plan holds, counted."""
         _metrics.MASK_CARRIED_FILTERS.inc(self.mask_carried)
+        count_join_paths(self.join_paths)
         for counter, n in zip(_PLAN_SHAPE_COUNTERS, self.plan_shapes):
             counter.inc(n)
 
@@ -707,6 +719,10 @@ class JaxExecutor:
         # plan), and how many of them this executor has run
         self._mask_carry: frozenset = frozenset()
         self.mask_carried = 0
+        # JoinNodes this executor ran through _fast_join, and through the
+        # sort-based path (dense_rank + build_side + probe_counts_by_gid)
+        self.direct_joins = 0
+        self.sorted_joins = 0
         self._scan_cache: dict[str, DTable] = scan_tables if scan_tables \
             is not None else {}           # accelerator-resident tables
         self._trace = trace
@@ -790,6 +806,13 @@ class JaxExecutor:
             # single-host CPU mesh (tests/dryrun): record single-device,
             # execute sharded — the caches hold different layouts
             self._scan_cache_rec = {}
+
+    @property
+    def join_paths(self) -> tuple:
+        """(direct-address, sort-based) joins run so far: what a program
+        traced through this executor moves direct_joins / sorted_joins by
+        at each dispatch (count_join_paths)."""
+        return self.direct_joins, self.sorted_joins
 
     def _exec_sharding(self, capacity: int):
         """Placement for an accelerator-resident scan of given capacity."""
@@ -2627,6 +2650,7 @@ class JaxExecutor:
             if out is not None:
                 return out
 
+        self.sorted_joins += 1
         key_data = []
         for lc, rc in zip(lkeys, rkeys):
             ld, rd = _joinable_pair(lc, rc)
@@ -2807,13 +2831,29 @@ class JaxExecutor:
         """Direct-address single-key join against a unique build side.
 
         Build: scatter build-row indices into a [LIMIT] table addressed by
-        (key - min_key). Probe: one gather + a key-equality confirm (which
-        also makes the path immune to range-arithmetic overflow). 1:1 match
-        means the output keeps the probe capacity — no expansion step, no
-        capacity decision, no sorts. Eligibility (unique keys, bounded
-        range) is data-dependent: decided at record time and replayed as an
-        exact schedule decision, so record and replay always take the same
-        branch.
+        (key - min_key). Probe: ONE gather per probe row, lut[key - min_key],
+        under a range test made on the keys themselves (two comparisons, no
+        subtraction, so nothing wraps). 1:1 match means the output keeps
+        the probe capacity — no expansion step, no capacity decision, no
+        sorts. Eligibility (unique keys, bounded range) is data-dependent:
+        decided at record time and replayed as an exact schedule decision,
+        so record and replay always take the same branch.
+
+        The build key is NOT gathered back to confirm the match: the
+        decision implies it. Under ``span_ok & unique & cnt_r > 0`` every
+        live build key lies in [rmin, rmax] with rmax - rmin < LIMIT, no
+        address was clipped and no two live rows share one, so lut[p] >= 0
+        iff a live build row holds key rmin + p, and that row is lut[p];
+        for rmin <= ld <= rmax, lut[ld - rmin] >= 0 <=> rd[lut[ld - rmin]]
+        == ld. WHAT GUARDS THE MATCH IS THEREFORE THE SCHEDULE CHECK: a
+        replay over a build side that left the decision (a duplicate key, a
+        span past LIMIT, no live row) computes wrong rows, and is right
+        only because it is thrown away. Every path that hands out a
+        replay's rows verifies its check scalars first and raises
+        ReplayMismatch (a re-record) on drift: _verify_schedule in
+        CompiledQuery.run, BatchedQuery._verify in BatchedQuery.run,
+        ShardedMorselQuery._verify in shard_exec (every replica's scalar).
+        A new replay path must do the same before it returns rows.
         """
         kind = node.kind
         lcap, rcap = left.capacity, right.capacity
@@ -2829,26 +2869,34 @@ class JaxExecutor:
             rmin = jnp.min(jnp.where(r_ok, rd, big))
             rmax = jnp.max(jnp.where(r_ok, rd, small))
             cnt_r = jnp.sum(r_ok.astype(_I32))
-            span_ok = (rmax - rmin) < limit
+            # rmax - rmin wraps between keys near both ends of the dtype
+            # and would record a span that is not one: where rmin + LIMIT
+            # - 1 leaves the dtype no key can pass it, elsewhere the sum
+            # is exact
+            fits = rmin <= big - (limit - 1)
+            span_ok = ~fits | (rmax <= rmin + (limit - 1))
             lut_idx = jnp.clip(rd - rmin, 0, limit - 1)
             scatter_idx = jnp.where(r_ok, lut_idx, limit)
             hist = jnp.zeros(limit + 1, _I32).at[scatter_idx].add(1)[:limit]
             unique = jnp.max(hist) <= 1
-            state.update(rmin=rmin, scatter_idx=scatter_idx)
+            state.update(rmin=rmin, rmax=rmax, scatter_idx=scatter_idx)
             return (span_ok & unique & (cnt_r > 0)).astype(_I32)
 
         if not self._decide_exact_lazy(probe):
             return None
-        rmin, scatter_idx = state["rmin"], state["scatter_idx"]
+        self.direct_joins += 1
+        rmin, rmax = state["rmin"], state["rmax"]
+        scatter_idx = state["scatter_idx"]
 
         lut = jnp.full(limit + 1, -1, _I32).at[scatter_idx].set(
             jnp.arange(rcap, dtype=_I32))[:limit]
-        pidx = ld - rmin
-        in_range = (pidx >= 0) & (pidx < limit)
-        r_row = lut[jnp.clip(pidx, 0, limit - 1)]
+        # the range is tested on the keys, never on ld - rmin (which wraps
+        # for a probe key at wrap distance); the recorded decision makes
+        # the lut entry the whole match (docstring)
+        in_range = (ld >= rmin) & (ld <= rmax)
+        r_row = lut[jnp.clip(ld - rmin, 0, limit - 1)]
         safe_r = jnp.clip(r_row, 0, rcap - 1)
-        # key-equality confirm: correctness never rests on range arithmetic
-        matched = l_ok & in_range & (r_row >= 0) & (rd[safe_r] == ld)
+        matched = l_ok & in_range & (r_row >= 0)
 
         if kind in ("semi", "anti") and node.residual is None:
             if kind == "semi":

@@ -56,7 +56,8 @@ from ..streaming import partition_morsel_rows
 from .device import (DTable, PackedTable, _pack_payload, bucket,
                      plan_lanes)
 from .executor import (JaxExecutor, ReplayMismatch, _named, _no_load,
-                       _Recorder, count_fetched, program_name)
+                       _Recorder, count_fetched, count_join_paths,
+                       program_name)
 
 
 # -- sharded morsel staging ---------------------------------------------------
@@ -181,6 +182,10 @@ class ShardedMorselQuery:
         self.gather_label = base.replace("/morsel:", "/gather:", 1) \
             + f"@mesh{self.n_shards}"
         self.module_name = program_name(base, name_fingerprint)[:56]
+        # (direct-address, sort-based) joins of the local program, fixed
+        # by its trace; run() moves direct_joins / sorted_joins by it once
+        # a dispatch, not once a replica
+        self.join_paths = (0, 0)
         self._fn = None
         self._gather = None
         self._replicated: dict = {}     # scan key -> (src id, replicated)
@@ -194,6 +199,7 @@ class ShardedMorselQuery:
         ex = JaxExecutor(_no_load, recorder=rec, scan_tables=scans,
                          mesh=None, shard_local=True)
         out = ex.replay(self.plan)
+        self.join_paths = ex.join_paths
         if rec.idx != len(rec.decisions):
             raise ReplayMismatch("decision schedule length drift (sharded)")
         if ex.fallback_nodes:
@@ -270,6 +276,7 @@ class ShardedMorselQuery:
             with jax.profiler.TraceAnnotation(self.label):
                 with TRACER.span("exec.wait", cat="device"):
                     out, checks = self._fn(morsel, others)
+                    count_join_paths(self.join_paths)
                     if TRACER.enabled:
                         jax.block_until_ready((out, checks))
                 with TRACER.span("exec.fetch", cat="device"):

@@ -631,6 +631,16 @@ SETOP_NODES = METRICS.counter(
 OUTER_JOINS = METRICS.counter(
     "outer_joins", "left, right and full outer JoinNodes of dispatched "
     "compiled programs (a static count per program)")
+# Which path the joins of a dispatched program took (a recorded decision per
+# JoinNode, so a static count per program, moved per dispatch like the plan
+# shapes above; a cross join and the mesh shuffle join are neither)
+DIRECT_JOINS = METRICS.counter(
+    "direct_joins", "joins of dispatched compiled programs that took the "
+    "direct-address path (JaxExecutor._fast_join: one integer key, a unique "
+    "build side whose key span fits the lookup table)")
+SORTED_JOINS = METRICS.counter(
+    "sorted_joins", "joins of dispatched compiled programs that took the "
+    "sort-based path (dense_rank + build_side + probe_counts_by_gid)")
 COLLECTIVE_BYTES = METRICS.counter(
     "collective_bytes", "per-chip ingress of the sharded morsels' partial "
     "all_gathers by the ring model: (n-1)/n of the gathered total")

@@ -72,6 +72,10 @@ class ArgSpecMismatch(ValueError):
     compiled-replay failure to localize otherwise."""
 
 
+#: the widest span of build keys the direct-address join addresses: two i32
+#: tables (the uniqueness histogram, the lookup table) of at most 64 MB each
+_DIRECT_SPAN_MAX = 1 << 24
+
 _NOJIT_ERRORS = (NotJittable, NotImplementedError,
                  jax.errors.TracerArrayConversionError,
                  jax.errors.ConcretizationTypeError)
@@ -1666,6 +1670,54 @@ class JaxExecutor:
                           else jnp.zeros((), _I32))
         return v
 
+    def _decide_table_lazy(self, measure: Callable[[], jax.Array],
+                           probe: Callable[[int], jax.Array],
+                           bound: int) -> int:
+        """The size of the table a fast path builds over a span it measures
+        in its input, or 0 where the path is off.
+
+        Two decisions. The eligibility, exact and one-sided as in
+        _decide_exact_lazy: a recorded 0 replays a constant, so the general
+        path pays for no probe in its compiled program. Under a recorded 1
+        only, the span as a ``cap``: the table is the span's ladder bucket,
+        and a replay passes iff its traced span is at most that bucket —
+        what inflate_schedule, adapt_schedule, the max-merge of shared
+        programs and a batched replay's stacked draws already do with any
+        capacity. Neither decision is a row count (label None).
+
+        ``measure()`` is the span (0: nothing to address; past ``bound`` the
+        path is off, and ``probe`` is not asked at record time);
+        ``probe(size)`` is the rest of the eligibility, over a table of
+        ``size`` entries."""
+        rec = self._rec
+        if rec is None or rec.mode == "record":
+            span = int(measure())
+            size = bucket(span) if 0 < span <= bound else 0
+            ok = int(probe(size)) if size else 0
+            if rec is not None:
+                rec.decisions.append(("exact", ok))
+                rec.nodes.append(None)
+                if ok:
+                    rec.decisions.append(("cap", span))
+                    rec.nodes.append(None)
+            return size if ok else 0
+        kind, ok = rec.decisions[rec.idx]
+        rec.idx += 1
+        if kind != "exact":
+            raise NotJittable("decision kind drift (exact)")
+        if not ok:
+            rec.checks.append(jnp.zeros((), _I32))
+            return 0
+        kind, span = rec.decisions[rec.idx]
+        rec.idx += 1
+        if kind != "cap":
+            raise NotJittable("decision kind drift (cap)")
+        size = bucket(max(int(span), 1))
+        span_t = measure()              # before the probe, as at record time
+        rec.checks.append(jnp.asarray(probe(size), _I32))
+        rec.checks.append(jnp.asarray(span_t, _I32))
+        return size
+
     def _decide_branch(self, value: bool) -> bool:
         """Record/replay a CAPACITY-DEPENDENT structural branch.
 
@@ -2632,12 +2684,12 @@ class JaxExecutor:
 
         if len(lkeys) == 1 and kind in ("inner", "left", "semi", "anti"):
             # direct-address fast path: the NDS star-join shape (single int
-            # key, unique build side with a bounded key range — dimension
-            # primary keys are dense). Replaces the sort-based machinery
-            # (dense_rank over lcap+rcap rows + build sort + expansion)
-            # with one scatter + gathers: TPU lax.sort is O(log^2 n) merge
-            # passes over every operand, the dominant HBM traffic of a
-            # power-run query program.
+            # key, unique build side whose keys span at most 2^24 —
+            # dimension surrogate keys, filtered or not). Replaces the
+            # sort-based machinery (dense_rank over lcap+rcap rows + build
+            # sort + expansion) with one scatter + gathers: TPU lax.sort is
+            # O(log^2 n) merge passes over every operand, the dominant HBM
+            # traffic of a power-run query program.
             out = self._fast_join(node, left, right, lkeys[0], rkeys[0],
                                   left.alive & lvalid, right.alive & rvalid,
                                   lvalid, rvalid)
@@ -2834,55 +2886,90 @@ class JaxExecutor:
         (key - min_key). Probe: ONE gather per probe row, lut[key - min_key],
         under a range test made on the keys themselves (two comparisons, no
         subtraction, so nothing wraps). 1:1 match means the output keeps
-        the probe capacity — no expansion step, no capacity decision, no
-        sorts. Eligibility (unique keys, bounded range) is data-dependent:
-        decided at record time and replayed as an exact schedule decision,
-        so record and replay always take the same branch.
+        the probe capacity — no expansion step, no row-count decision, no
+        sorts.
+
+        LIMIT is the ladder bucket of the SPAN of the live build keys,
+        rmax - rmin + 1, not a multiple of the build side's capacity: a
+        filter thins a dimension's rows, not the span of its surrogate
+        keys, so a dimension compacted to 18 survivors over 18,000 keys
+        joins through a 32,768-entry table. The span is data and the
+        table's size a shape, so it enters the program as every other
+        data-dependent shape does (_decide_table_lazy): the eligibility
+        ``unique & cnt_r > 0 & span <= _DIRECT_SPAN_MAX`` is recorded as an
+        exact, one-sided decision, and under it the span as a ``cap``,
+        which a replay passes iff its traced span is at most the recorded
+        bucket. A parameter draw that moves the span inside its bucket
+        replays; one that leaves it re-records.
 
         The build key is NOT gathered back to confirm the match: the
-        decision implies it. Under ``span_ok & unique & cnt_r > 0`` every
-        live build key lies in [rmin, rmax] with rmax - rmin < LIMIT, no
-        address was clipped and no two live rows share one, so lut[p] >= 0
-        iff a live build row holds key rmin + p, and that row is lut[p];
-        for rmin <= ld <= rmax, lut[ld - rmin] >= 0 <=> rd[lut[ld - rmin]]
-        == ld. WHAT GUARDS THE MATCH IS THEREFORE THE SCHEDULE CHECK: a
-        replay over a build side that left the decision (a duplicate key, a
-        span past LIMIT, no live row) computes wrong rows, and is right
-        only because it is thrown away. Every path that hands out a
-        replay's rows verifies its check scalars first and raises
-        ReplayMismatch (a re-record) on drift: _verify_schedule in
-        CompiledQuery.run, BatchedQuery._verify in BatchedQuery.run,
-        ShardedMorselQuery._verify in shard_exec (every replica's scalar).
-        A new replay path must do the same before it returns rows.
+        decisions imply it. Under ``unique & cnt_r > 0`` and ``span <=
+        LIMIT`` every live build key lies in [rmin, rmin + LIMIT), none was
+        kept out of the table and no two live rows share an address, so
+        lut[p] >= 0 iff a live build row holds key rmin + p, and that row
+        is lut[p]; for rmin <= ld <= rmax, lut[ld - rmin] >= 0 <=>
+        rd[lut[ld - rmin]] == ld. WHAT GUARDS THE MATCH IS THEREFORE THE
+        SCHEDULE CHECK, and it guards three things: a replay over a build
+        side that left the exact decision (a duplicate key, no live row), or
+        whose span outgrew the recorded bucket (the ``cap``: its keys past
+        the table are in no entry, and a probe for one reads a neighbour's),
+        computes wrong rows, and is right only because it is thrown away.
+        Every path that hands out a replay's rows verifies its check
+        scalars first and raises ReplayMismatch (a re-record) on drift:
+        _verify_schedule in CompiledQuery.run, BatchedQuery._verify in
+        BatchedQuery.run, ShardedMorselQuery._verify in shard_exec (every
+        replica's scalar). A new replay path must do the same before it
+        returns rows.
         """
         kind = node.kind
         lcap, rcap = left.capacity, right.capacity
         ld, rd = _joinable_pair(lkey, rkey)
         if not jnp.issubdtype(rd.dtype, jnp.integer):
             return None    # float keys: no address arithmetic
-        limit = min(4 * rcap, 1 << 24)
+        if lkey.codebook is not None and rkey.codebook is None:
+            # the build keys were mapped into the probe side's code space
+            # (_joinable_pair): one the codebook lacks is -1 there and
+            # matches no probe row. It stays out of the table — several of
+            # them would read as one duplicated key, in the replay alone
+            # (the record pass sees the decoded values)
+            r_ok = r_ok & (rd >= 0)
         big = jnp.iinfo(rd.dtype).max
         small = jnp.iinfo(rd.dtype).min
         state: dict = {}
 
-        def probe() -> jax.Array:
+        def within(key: jax.Array, width: int) -> jax.Array:
+            # key < rmin + width for key >= rmin. rmax - rmin wraps between
+            # keys near both ends of the dtype and would read a span that is
+            # not one: where rmin + width - 1 leaves the dtype no key can
+            # pass it, elsewhere the sum is exact
+            rmin = state["rmin"]
+            fits = rmin <= big - (width - 1)
+            return ~fits | (key <= rmin + (width - 1))
+
+        def measure() -> jax.Array:
             rmin = jnp.min(jnp.where(r_ok, rd, big))
             rmax = jnp.max(jnp.where(r_ok, rd, small))
             cnt_r = jnp.sum(r_ok.astype(_I32))
-            # rmax - rmin wraps between keys near both ends of the dtype
-            # and would record a span that is not one: where rmin + LIMIT
-            # - 1 leaves the dtype no key can pass it, elsewhere the sum
-            # is exact
-            fits = rmin <= big - (limit - 1)
-            span_ok = ~fits | (rmax <= rmin + (limit - 1))
-            lut_idx = jnp.clip(rd - rmin, 0, limit - 1)
-            scatter_idx = jnp.where(r_ok, lut_idx, limit)
-            hist = jnp.zeros(limit + 1, _I32).at[scatter_idx].add(1)[:limit]
-            unique = jnp.max(hist) <= 1
-            state.update(rmin=rmin, rmax=rmax, scatter_idx=scatter_idx)
-            return (span_ok & unique & (cnt_r > 0)).astype(_I32)
+            state.update(rmin=rmin, rmax=rmax, cnt_r=cnt_r)
+            # exact up to the bound, one past it beyond: a check scalar is
+            # an i32, and a replay past the bound fails whatever it reads
+            span = jnp.where(within(rmax, _DIRECT_SPAN_MAX),
+                             rmax - rmin + 1, _DIRECT_SPAN_MAX + 1)
+            return jnp.where(cnt_r > 0, span, 0).astype(_I32)
 
-        if not self._decide_exact_lazy(probe):
+        def probe(limit: int) -> jax.Array:
+            # a live key past the table (a replay whose span outgrew the
+            # recorded bucket) goes where the dead rows go: it fails the
+            # cap check, and must not read as a duplicate on its way
+            scatter_idx = jnp.where(r_ok & within(rd, limit),
+                                    rd - state["rmin"], limit)
+            hist = jnp.zeros(limit + 1, _I32).at[scatter_idx].add(1)[:limit]
+            state.update(scatter_idx=scatter_idx)
+            return ((jnp.max(hist) <= 1)
+                    & (state["cnt_r"] > 0)).astype(_I32)
+
+        limit = self._decide_table_lazy(measure, probe, _DIRECT_SPAN_MAX)
+        if not limit:
             return None
         self.direct_joins += 1
         rmin, rmax = state["rmin"], state["rmax"]
@@ -2891,7 +2978,7 @@ class JaxExecutor:
         lut = jnp.full(limit + 1, -1, _I32).at[scatter_idx].set(
             jnp.arange(rcap, dtype=_I32))[:limit]
         # the range is tested on the keys, never on ld - rmin (which wraps
-        # for a probe key at wrap distance); the recorded decision makes
+        # for a probe key at wrap distance); the recorded decisions make
         # the lut entry the whole match (docstring)
         in_range = (ld >= rmin) & (ld <= rmax)
         r_row = lut[jnp.clip(ld - rmin, 0, limit - 1)]

@@ -333,15 +333,45 @@ def test_live_toggle_invalidates_stream_cache(bench_shape):
 
 def test_dict_upload_cache_counts_hits(bench_shape):
     """The per-group device codebook uploads once; every later decode site
-    / morsel re-record reuses it (obs/metrics dict_uploads_saved)."""
+    / morsel re-record reuses it (obs/metrics dict_uploads_saved). Since
+    ISSUE 38 the join on ``fk`` is direct and maps its key pair once a trace
+    (the sort-based path mapped it again), so the second use of ``fk``'s
+    codebook is the second sighting's trace of the tight programs."""
     from nds_tpu.obs.metrics import METRICS
     before = METRICS.snapshot()
     s = _session(bench_shape, True)
+    s.sql(Q_BENCH, backend="jax")
     s.sql(Q_BENCH, backend="jax")
     after = METRICS.snapshot()
     assert after.get("dict_uploads_saved", 0) > \
         before.get("dict_uploads_saved", 0)
     assert after.get("decode_sites", 0) > before.get("decode_sites", 0)
+
+
+def test_a_dimension_with_keys_the_fact_never_holds_joins_on_codes_directly(
+        bench_shape):
+    """The dimension's keys map into the fact key's code space; those the
+    codebook lacks are all -1 there and match nothing. They stay out of the
+    direct-address table (ISSUE 38: the keys' 3M span no longer sends this
+    join to the sort-based path), so the replay — which sees codes where
+    the record pass saw values — finds no duplicate and no morsel
+    re-records."""
+    extra = np.arange(5, 3_000_000, 99_991)
+    dk = np.concatenate([bench_shape["dim"]["dk"].to_numpy(), extra])
+    data = dict(bench_shape, dim=pa.table({
+        "dk": pa.array(dk, type=pa.int64()),
+        "grp": pa.array((np.arange(len(dk)) % 13).astype(np.int32))}))
+    oracle = rows_of(_session(data, True).sql(Q_BENCH, backend="numpy"))
+    s = _session(data, True)
+    for _sighting in range(2):
+        got = rows_of(s.sql(Q_BENCH, backend="jax"))
+        st = dict(s.last_exec_stats)
+        assert st["mode"] == "streaming" and st["re_records"] == 0
+        assert st["enc_spec"]["fact"]["fk"].startswith("dict[")
+        assert [r[:5] for r in got] == [r[:5] for r in oracle]
+    (entry,) = s._stream_cache.values()
+    assert [cq.join_paths for g in entry["gstates"] for cq in g["cqs"]] == \
+        [(1, 0)]
 
 
 def test_sharded_encoded_roundtrip(bench_shape):

@@ -40,7 +40,7 @@ NON_METRIC_CONSTS = frozenset({
 #: fallback when the gate module cannot be parsed for its own constant
 DEFAULT_REPORT_ONLY_SUFFIXES = ("_ms", "_bytes", "bytes_uploaded",
                                 "bytes_fetched", "tight_morsel_replays",
-                                "mask_carried_filters")
+                                "mask_carried_filters", "bytes_decoded")
 
 
 def _gate_artifacts(root: str | None):

@@ -53,7 +53,6 @@ import pyarrow as pa
 
 from ..obs import metrics as _metrics
 from ..obs.flight import FLIGHT
-from ..obs.trace import TRACER
 from . import plan as P
 from . import streaming
 from .column import is_dec
@@ -757,9 +756,7 @@ class ResultCache:
             if preds is None:
                 continue
             cand.hits += 1
-            with TRACER.span("cache.subsume",
-                             rows=cand.result.num_rows):
-                table = _refilter(cand, preds)
+            table = _refilter(cand, preds)
             _metrics.RESULT_CACHE_SUBSUMPTION_HITS.inc()
             FLIGHT.record("cache_hit", tier="subsumed",
                           from_rows=cand.result.num_rows,
@@ -937,9 +934,8 @@ class ResultCache:
             child=agg.child, group_exprs=list(agg.group_exprs),
             aggs=list(partial_specs), out_names=list(p_names),
             out_dtypes=list(p_dtypes))
-        with TRACER.span("cache.ivm_capture", groups=len(agg.group_exprs)):
-            partial = _execute_plan(self.session, partial_plan,
-                                    use_jax=False)
+        partial = _execute_plan(self.session, partial_plan,
+                                use_jax=False)
         scan_by_table = {}
         for t in {n.table for n in P.iter_plan_nodes(agg.child)
                   if isinstance(n, P.ScanNode)}:
@@ -1010,10 +1006,9 @@ class ResultCache:
                                  out_dtypes=list(st.p_dtypes))
         final_b = streaming._final_builder(st.agg, st.recipes, st.p_names,
                                            st.p_dtypes)
-        with TRACER.span("cache.ivm_finalize", rows=partial.num_rows):
-            result = _execute_plan(
-                session, streaming.rebuild_above(st.path, final_b(mat)),
-                use_jax)
+        result = _execute_plan(
+            session, streaming.rebuild_above(st.path, final_b(mat)),
+            use_jax)
         new_ivm = _IvmState(st.agg, st.path, st.partial_specs, st.recipes,
                             st.p_names, st.p_dtypes, partial,
                             st.partial_plan, st.scan_by_table)
@@ -1056,18 +1051,17 @@ class ResultCache:
                                  out_dtypes=list(scan.out_dtypes))
         dplan = streaming.substitute_nodes(st.partial_plan,
                                            {id(scan): mat})
-        with TRACER.span("cache.ivm_insert", rows=inserts.num_rows):
-            delta_partial = _execute_plan(self.session, dplan,
-                                          use_jax=False)
-            if delta_partial.num_rows == 0:
-                return partial
-            merged = self._concat_partials(st, [partial, delta_partial])
-            combine = streaming._combine_builder(
-                st.agg, st.recipes, st.p_names, st.p_dtypes)
-            mat2 = P.MaterializedNode(table=merged, label="ivm-merge",
-                                      out_names=list(st.p_names),
-                                      out_dtypes=list(st.p_dtypes))
-            return Executor(_no_load).execute(combine(mat2))
+        delta_partial = _execute_plan(self.session, dplan,
+                                      use_jax=False)
+        if delta_partial.num_rows == 0:
+            return partial
+        merged = self._concat_partials(st, [partial, delta_partial])
+        combine = streaming._combine_builder(
+            st.agg, st.recipes, st.p_names, st.p_dtypes)
+        mat2 = P.MaterializedNode(table=merged, label="ivm-merge",
+                                  out_names=list(st.p_names),
+                                  out_dtypes=list(st.p_dtypes))
+        return Executor(_no_load).execute(combine(mat2))
 
     def _ivm_delete(self, st: _IvmState, partial, table, deletes):
         """Recompute only delta-touched groups: the deleted rows' group
@@ -1082,63 +1076,62 @@ class ResultCache:
                                  out_dtypes=list(scan.out_dtypes))
         dplan = streaming.substitute_nodes(st.partial_plan,
                                            {id(scan): mat})
-        with TRACER.span("cache.ivm_delete", rows=deletes.num_rows):
-            touched = _execute_plan(self.session, dplan, use_jax=False)
-            if touched.num_rows == 0:
-                return partial       # deletes never reached the aggregate
-            ngroups = len(st.agg.group_exprs)
-            child = st.agg.child
+        touched = _execute_plan(self.session, dplan, use_jax=False)
+        if touched.num_rows == 0:
+            return partial       # deletes never reached the aggregate
+        ngroups = len(st.agg.group_exprs)
+        child = st.agg.child
 
-            def key_pred(exprs):
-                """Membership predicate over the touched group-key value
-                sets (per-column: a cartesian superset — over-inclusive
-                recomputation is correct, just wider)."""
-                pred = None
-                for i in range(ngroups):
-                    vals, has_null = _col_values(touched.columns[i])
-                    e = exprs[i]
-                    c = None
-                    if vals:
-                        c = P.BCall("bool", "in_list", [e], extra=vals)
-                    if has_null:
-                        isn = P.BCall("bool", "isnull", [e])
-                        c = isn if c is None else P.BCall("bool", "or",
-                                                          [c, isn])
-                    if c is None:
-                        continue
-                    pred = c if pred is None else P.BCall("bool", "and",
-                                                          [pred, c])
-                return pred
+        def key_pred(exprs):
+            """Membership predicate over the touched group-key value
+            sets (per-column: a cartesian superset — over-inclusive
+            recomputation is correct, just wider)."""
+            pred = None
+            for i in range(ngroups):
+                vals, has_null = _col_values(touched.columns[i])
+                e = exprs[i]
+                c = None
+                if vals:
+                    c = P.BCall("bool", "in_list", [e], extra=vals)
+                if has_null:
+                    isn = P.BCall("bool", "isnull", [e])
+                    c = isn if c is None else P.BCall("bool", "or",
+                                                      [c, isn])
+                if c is None:
+                    continue
+                pred = c if pred is None else P.BCall("bool", "and",
+                                                      [pred, c])
+            return pred
 
-            child_pred = key_pred(st.agg.group_exprs)
-            if child_pred is None:
-                return None
-            recompute = P.AggregateNode(
-                child=P.FilterNode(child, child_pred,
-                                   out_names=list(child.out_names),
-                                   out_dtypes=list(child.out_dtypes)),
-                group_exprs=list(st.agg.group_exprs),
-                aggs=list(st.partial_specs),
-                out_names=list(st.p_names), out_dtypes=list(st.p_dtypes))
-            recomputed = _execute_plan(self.session, recompute,
-                                       use_jax=False)
-            # keep every partial row whose group the delta did NOT touch.
-            # Three-valued logic: an untouched NULL-keyed group evaluates
-            # `key IN (...)` to NULL, and NOT(NULL) would silently drop
-            # it — coalesce the membership to FALSE first so "not
-            # touched" keeps NULL verdicts
-            part_pred = key_pred([P.BCol(st.p_dtypes[i], i, st.p_names[i])
-                                  for i in range(ngroups)])
-            keep = P.FilterNode(
-                P.MaterializedNode(table=partial, label="ivm-partials",
-                                   out_names=list(st.p_names),
-                                   out_dtypes=list(st.p_dtypes)),
-                P.BCall("bool", "not",
-                        [P.BCall("bool", "coalesce",
-                                 [part_pred, P.BLit("bool", False)])]),
-                out_names=list(st.p_names), out_dtypes=list(st.p_dtypes))
-            kept = Executor(_no_load).execute(keep)
-            return self._concat_partials(st, [kept, recomputed])
+        child_pred = key_pred(st.agg.group_exprs)
+        if child_pred is None:
+            return None
+        recompute = P.AggregateNode(
+            child=P.FilterNode(child, child_pred,
+                               out_names=list(child.out_names),
+                               out_dtypes=list(child.out_dtypes)),
+            group_exprs=list(st.agg.group_exprs),
+            aggs=list(st.partial_specs),
+            out_names=list(st.p_names), out_dtypes=list(st.p_dtypes))
+        recomputed = _execute_plan(self.session, recompute,
+                                   use_jax=False)
+        # keep every partial row whose group the delta did NOT touch.
+        # Three-valued logic: an untouched NULL-keyed group evaluates
+        # `key IN (...)` to NULL, and NOT(NULL) would silently drop
+        # it — coalesce the membership to FALSE first so "not
+        # touched" keeps NULL verdicts
+        part_pred = key_pred([P.BCol(st.p_dtypes[i], i, st.p_names[i])
+                              for i in range(ngroups)])
+        keep = P.FilterNode(
+            P.MaterializedNode(table=partial, label="ivm-partials",
+                               out_names=list(st.p_names),
+                               out_dtypes=list(st.p_dtypes)),
+            P.BCall("bool", "not",
+                    [P.BCall("bool", "coalesce",
+                             [part_pred, P.BLit("bool", False)])]),
+            out_names=list(st.p_names), out_dtypes=list(st.p_dtypes))
+        kept = Executor(_no_load).execute(keep)
+        return self._concat_partials(st, [kept, recomputed])
 
     def _concat_partials(self, st: _IvmState, parts: list):
         from . import arrow_bridge

@@ -575,11 +575,15 @@ class Session:
             return pending[0] if len(pending) == 1 \
                 else pa.concat_tables(pending)
 
+        consumed = 0    # source batches pulled so far (morsel.read's attr)
+
         def emit(batches):
             """Re-chunk a stream of arrow tables into `rows`-sized morsels."""
+            nonlocal consumed
             pending: list[pa.Table] = []
             count = 0
             for b in batches:
+                consumed += 1
                 t = pa.Table.from_batches([b]) if isinstance(
                     b, pa.RecordBatch) else b
                 while t.num_rows:
@@ -598,8 +602,26 @@ class Session:
             batches = src(columns)
         else:  # fallback: full load, sliced (correct, not memory-bounded)
             batches = [arrow_bridge.to_arrow(self.load_table(name, columns))]
-        for part in emit(batches):
-            yield arrow_bridge.from_arrow(part, self._dec_as_int())
+        # an explicit next() so that each span closes before the yield: the
+        # read is the main thread's wait for the next re-chunked Arrow part
+        # (the dataset scan's batches, slice, concat), the conversion the
+        # dictionary / validity / decimal materialization under the GIL
+        parts = emit(batches)
+        dec = self._dec_as_int()
+        while True:
+            before = consumed
+            with TRACER.span("morsel.read", cat="host", table=name) as sp:
+                part = next(parts, None)
+                nrows, nbytes = (part.num_rows, part.nbytes) \
+                    if part is not None else (0, 0)
+                sp.set(rows=nrows, bytes=nbytes, batches=consumed - before)
+            if part is None:
+                return
+            _metrics.BYTES_DECODED.inc(nbytes)
+            with TRACER.span("morsel.from_arrow", cat="host",
+                             rows=part.num_rows, columns=part.num_columns):
+                table = arrow_bridge.from_arrow(part, dec)
+            yield table
 
     def load_table(self, name: str, columns=None) -> Table:  # lint: thread-entry (streaming staging threads + service lanes load concurrently)
         """Load a table, optionally projected to `columns` (scan pruning:
@@ -1678,10 +1700,24 @@ class Session:
             be measurable (ExecStats.host_decode_ms)."""
             nonlocal host_ms
             import time as _time
-            t0 = _time.perf_counter()
-            m = next(it, None)
-            host_ms += (_time.perf_counter() - t0) * 1000.0
+            with TRACER.span("morsel.decode", cat="host",
+                             table=group.table) as sp:
+                t0 = _time.perf_counter()
+                m = next(it, None)
+                host_ms += (_time.perf_counter() - t0) * 1000.0
+                sp.set(rows=m.num_rows if m is not None else 0)
             return m
+
+        def join_stage(at: int) -> None:
+            """The main thread blocked on the staging thread (no thread, no
+            span): after morsel `at`'s partials, or on the way out."""
+            nonlocal stage_thread
+            if stage_thread is None:
+                return
+            with TRACER.span("morsel.stage_wait", cat="host",
+                             table=group.table, morsel=at):
+                stage_thread.join()
+            stage_thread = None
 
         try:
             it = iter(morsels)
@@ -1700,7 +1736,12 @@ class Session:
                     if err is not None:
                         prefetch_errs.append(
                             f"{type(err).__name__}: {err}")
-                    buf = stage(morsel)
+                    # a stage the main thread pays itself: a group's first
+                    # morsel, or the one after a failed prefetch
+                    with TRACER.span("morsel.stage_sync", cat="upload",
+                                     table=group.table,
+                                     prefetch_error=err is not None):
+                        buf = stage(morsel)
                 nxt = pull(it)
                 if nxt is not None:
                     # stage the NEXT morsel concurrently with this run
@@ -1720,25 +1761,27 @@ class Session:
                                  table=group.table, morsel=count,
                                  rows=morsel.num_rows, bytes=buf_bytes):
                     outs = run_members()
-                free_dtable(prev)
-                for (job, plist), out in zip(sinks, outs):
-                    plist.append(arrow_bridge.to_arrow(to_host(out)))
-                    if sum(p.num_rows for p in plist) > \
-                            self.config.stream_compact_rows:
-                        plist[:] = [self._combine_partials(job, plist)]
-                count += 1
+                with TRACER.span("morsel.partials", cat="host",
+                                 members=len(sinks)) as sp:
+                    free_dtable(prev)
+                    rows = 0
+                    for (job, plist), out in zip(sinks, outs):
+                        plist.append(arrow_bridge.to_arrow(to_host(out)))
+                        rows += plist[-1].num_rows
+                        if sum(p.num_rows for p in plist) > \
+                                self.config.stream_compact_rows:
+                            plist[:] = [self._combine_partials(job, plist)]
+                    sp.set(rows=rows)
                 rows_streamed += morsel.num_rows
-                if stage_thread is not None:
-                    stage_thread.join()
-                    stage_thread = None
+                join_stage(count)
+                count += 1
                 morsel = nxt
         finally:
             # free every morsel-sized buffer even on a mid-stream failure
             # (device OOM on the next query otherwise): the current buffer,
             # the record-side copy, the host morsel reference, and whatever
             # the staging thread uploaded
-            if stage_thread is not None:
-                stage_thread.join()
+            join_stage(count)
             free_dtable(staged.pop("buf", None))
             free_dtable(jexec._scan_cache.pop(mkey, None))
             free_dtable(jexec._scan_cache_rec.pop(mkey, None))

@@ -579,8 +579,6 @@ def plan_scan_groups(jobs: list[StreamJob], shared: bool) -> list[ScanGroup]:
     per branch otherwise (the pre-round-7 behavior, kept reachable for A/B
     via shared_scan=False / --no_shared_scan). Branch order inside a group
     is (job, branch) order, so partial-merge order is deterministic."""
-    from ..obs.trace import TRACER
-
     keyed: dict = {}
     order: list = []
     for ji, job in enumerate(jobs):
@@ -593,14 +591,12 @@ def plan_scan_groups(jobs: list[StreamJob], shared: bool) -> list[ScanGroup]:
                 order.append(key)
             keyed[key].append((ji, bi, b))
     groups = []
-    with TRACER.span("stream.plan_groups", shared=shared,
-                     branches=sum(len(m) for m in keyed.values())):
-        for key in order:
-            members = keyed[key]
-            cols, dtypes, plans = fuse_group([b for _, _, b in members])
-            groups.append(ScanGroup(members[0][2].big_table, cols, dtypes,
-                                    [(ji, bi) for ji, bi, _ in members],
-                                    plans))
+    for key in order:
+        members = keyed[key]
+        cols, dtypes, plans = fuse_group([b for _, _, b in members])
+        groups.append(ScanGroup(members[0][2].big_table, cols, dtypes,
+                                [(ji, bi) for ji, bi, _ in members],
+                                plans))
     return groups
 
 
@@ -620,14 +616,6 @@ def verify_groups(groups: list[ScanGroup], col_stats=None,
     EngineConfig.verify_plans == "per-pass" (the groups never flow through
     planner.PassPipeline); raises PlanVerifyError naming the group/member
     as the offending pass."""
-    from ..obs.trace import TRACER
-
-    with TRACER.span("stream.verify_groups", groups=len(groups)):
-        return _verify_groups(groups, col_stats, enc_stats)
-
-
-def _verify_groups(groups: list[ScanGroup], col_stats=None,
-                   enc_stats=None) -> None:
     from .verify import (PlanVerifyError, check_scan_encodings,
                          check_scan_lanes, verify_plan)
 

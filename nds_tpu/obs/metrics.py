@@ -591,6 +591,10 @@ MORSELS = METRICS.counter(
     "morsels", "morsels executed across all streamed queries")
 BYTES_UPLOADED = METRICS.counter(
     "bytes_uploaded", "host->device bytes staged for streamed morsels")
+BYTES_DECODED = METRICS.counter(
+    "bytes_decoded", "Arrow bytes of the re-chunked morsel parts handed to "
+    "arrow_bridge.from_arrow (Session.iter_morsels), moved once a morsel: "
+    "over host_decode_ms, the streamed decode's rate")
 BYTES_FETCHED = METRICS.counter(
     "bytes_fetched", "device->host bytes returned by program dispatches: "
     "results and check scalars")

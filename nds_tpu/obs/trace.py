@@ -60,7 +60,14 @@ Span names (``cat``): ``query`` > ``plan`` > ``parse`` / ``plan.pass`` /
 the wait for its result; the gathered partials' copy to the host is the
 ``exec.fetch`` after it); ``morsel.stage`` / ``morsel.stage_sharded`` /
 ``morsel.exec`` /
-``merge.partials`` / ``finalize``; ``system_query``; the service's
+``merge.partials`` / ``finalize``; the streamed pass's main thread, under
+``query``: ``morsel.decode`` (the wait for the next morsel) >
+``morsel.read`` (the next re-chunked Arrow part) / ``morsel.from_arrow``
+(Arrow to the engine's ``Table``), ``morsel.stage_sync`` (a stage the
+main thread pays itself, around ``morsel.stage`` / ``_sharded``),
+``morsel.stage_wait`` (blocked on the staging thread) and
+``morsel.partials`` (the members' partials to the host, compacted);
+``system_query``; the service's
 ``service/ticket`` > ``service/queue`` / ``service/plan`` /
 ``service/lane_wait`` / ``service/dispatch`` / ``service/materialize`` /
 ``frontdoor/reply`` and, on the lane thread, ``service/lane_idle``.

@@ -93,9 +93,12 @@ STRICT_ZERO = (
 #: tight_morsel_replays counts how often a streamed statement was seen
 #: again, which is the workload's choice and no behaviour of the engine;
 #: mask_carried_filters counts dispatches of programs whose plan holds a
-#: filter under a keyless integer aggregate, the workload's choice too
+#: filter under a keyless integer aggregate, the workload's choice too;
+#: bytes_decoded is a byte volume like bytes_uploaded (it moves with the
+#: Arrow layout of the source's batches)
 REPORT_ONLY_SUFFIXES = ("_ms", "_bytes", "bytes_uploaded", "bytes_fetched",
-                        "tight_morsel_replays", "mask_carried_filters")
+                        "tight_morsel_replays", "mask_carried_filters",
+                        "bytes_decoded")
 
 RATIO_LO, RATIO_HI = 0.5, 2.0
 ABS_SLACK = 2

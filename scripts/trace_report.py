@@ -19,8 +19,9 @@ view a Perfetto session would start from:
 - ``--xplane FILE``: a ``jax.profiler`` trace (``*.xplane.pb``, e.g. from
   ``power --profile_folder``): device time per program from the device's
   own clock (the ``XLA Modules`` line; plan programs are
-  ``jit_nds_<query>_<unit>``) and the device's idle gaps by the ``nds.``
-  span that covers each; given the Chrome trace of the same run as
+  ``jit_nds_<query>_<unit>``) and the device's idle gaps, each split among
+  the ``nds.`` spans open on the thread that dispatches (innermost first:
+  self time); given the Chrome trace of the same run as
   ARTIFACT too, how far the two clocks are apart after the recorded anchor.
 
 Usage:  python scripts/trace_report.py ARTIFACT [--top N]
@@ -266,7 +267,8 @@ def print_bench(doc: dict, top: int) -> None:
 
 def print_xplane(path: str, chrome_trace: str | None, top: int) -> None:
     """The device's side of a run: device time per program and idle gaps
-    by covering ``nds.`` span, both on the device trace's clock."""
+    by the innermost ``nds.`` span open on the dispatching thread
+    (``xplane.idle_gaps``), both on the device trace's clock."""
     sys.path.insert(0, REPO)
     from nds_tpu.obs import xplane
     trace = xplane.read(path)

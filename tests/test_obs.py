@@ -510,7 +510,7 @@ def test_spans_are_host_events_of_a_profile_within_a_millisecond(tmp_path):
     trace = xplane.read(glob.glob(
         str(tmp_path / "prof" / "plugins" / "profile" / "*" /
             "*.xplane.pb"))[0])
-    names = {n for _s, _e, n in trace["spans"]}
+    names = {n for _s, _e, n, _thread in trace["spans"]}
     assert {"nds.query:prof_q", "nds.exec:prof_q", "nds.exec.wait",
             "nds.exec.fetch", "nds.detached.parent"} <= names, names
     assert "nds.detached" not in names      # begin()/end() is not mirrored

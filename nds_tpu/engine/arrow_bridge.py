@@ -6,10 +6,13 @@ materialize as Arrow tables for reporting/validation/output writing).
 """
 from __future__ import annotations
 
+import sys
+
 import numpy as np
 import pyarrow as pa
 import pyarrow.compute as pc
 
+from ..obs import metrics as _metrics
 from .column import Column, Table, dec_dtype, dec_scale, is_dec
 
 
@@ -46,31 +49,101 @@ def _chunked_to_array(arr: pa.ChunkedArray | pa.Array) -> pa.Array:
     return arr
 
 
-def _decimal_to_scaled_i64(arr: pa.Array) -> np.ndarray:
-    """Exact decimal128(p,s) -> value*10^s as int64 (no float round-trip)."""
-    t = arr.type
-    # fast path only when every scaled value provably fits int64
-    # (10^18 < 2^63): the safe=False cast below would wrap silently
-    if t.precision <= 18:
-        mul = pa.scalar(10 ** t.scale, pa.decimal128(t.scale + 1, 0))
-        ints = pc.cast(pc.multiply(arr, mul), pa.int64(), safe=False)
-        ints = pc.fill_null(ints, 0)
-        return ints.to_numpy(zero_copy_only=False)
-    out = np.zeros(len(arr), dtype=np.int64)     # precision edge: exact loop
-    for i, d in enumerate(arr.to_pylist()):
-        if d is not None:
-            out[i] = int(d.scaleb(t.scale))
-    return out
+# -- buffer views (fixed-width columns) ----------------------------------------
+# A fixed-width Arrow array already holds its values in the engine's own
+# layout: `buffers()[1]` at `offset`, `len` values wide. These columns become
+# engine columns by one numpy view of that buffer and one widening /
+# compacting copy, chunk by chunk, with no pyarrow.compute kernel and no trip
+# through float64 (which `to_numpy` takes for an integer array with nulls,
+# and which is wrong beyond 2^53).
+
+#: index of a native-endian decimal128's low 64-bit word
+_DEC_LO = 0 if sys.byteorder == "little" else 1
 
 
-def from_arrow_column(arr, dec_as_int: bool = False) -> Column:
-    arr = _chunked_to_array(arr)
+def _chunk_valid(chunk: pa.Array) -> np.ndarray:
+    """Validity of a chunk that has nulls: its bitmap unpacked at the
+    chunk's BIT offset."""
+    first, n = chunk.offset % 8, len(chunk)
+    packed = np.frombuffer(chunk.buffers()[0], dtype=np.uint8,
+                           count=(first + n + 7) // 8,
+                           offset=chunk.offset // 8)
+    return np.unpackbits(packed, count=first + n,
+                         bitorder="little")[first:].view(np.bool_)
+
+
+def _view_column(arr, item, dtype):
+    """(data, valid) of a fixed-width arrow column whose value buffer holds
+    `item`s: `dtype` values with the slots under nulls zeroed (they are
+    undefined in Arrow; the RLE run statistics count on 0), `valid` None
+    where nothing is null. One contiguous chunk of the engine's own width
+    without nulls stays a view (it keeps the Arrow buffer alive); everything
+    else is written once into a fresh array. A decimal128 is two int64
+    words a value, whose low word is the scaled integer; wider than 18
+    digits, its valid values must prove to fit int64."""
+    item = np.dtype(item)
+    words = 2 if pa.types.is_decimal128(arr.type) else 1
+    check = words == 2 and arr.type.precision > 18
+    chunks = [c for c in (arr.chunks if isinstance(arr, pa.ChunkedArray)
+                          else [arr]) if len(c)]
+
+    def values(c, v):
+        w = np.frombuffer(c.buffers()[1], dtype=item, count=len(c) * words,
+                          offset=c.offset * words * item.itemsize)
+        if words == 1:
+            return w
+        if check:
+            # the low word is the whole value iff the high word is its
+            # sign extension
+            bad = w[1 - _DEC_LO::2] != (w[_DEC_LO::2] >> 63)
+            if (bad if v is None else bad & v).any():
+                raise OverflowError(
+                    f"{arr.type} value does not fit the scaled int64")
+        return w[_DEC_LO::2]
+
+    nulls = arr.null_count > 0
+    if len(chunks) == 1 and not nulls:
+        return np.ascontiguousarray(values(chunks[0], None), dtype=dtype), None
+    data = np.empty(len(arr), dtype=dtype)
+    valid = np.ones(len(arr), dtype=bool) if nulls else None
+    pos = 0
+    for c in chunks:
+        dst = data[pos:pos + len(c)]
+        if c.null_count:
+            v = valid[pos:pos + len(c)] = _chunk_valid(c)
+            np.multiply(values(c, v), v, out=dst)   # value or 0, in one pass
+        else:
+            dst[...] = values(c, None)
+        pos += len(c)
+    return data, valid
+
+
+def _convert(arr, dec_as_int: bool) -> tuple[Column, bool]:
+    """(engine column, whether it was made by buffer view). Strings, bools,
+    floats and float-mapped decimals keep their own paths."""
     t = arr.type
     dtype = engine_dtype(t, dec_as_int)
-    null_count = arr.null_count
     if is_dec(dtype):
-        valid = ~np.asarray(arr.is_null()) if null_count else None
-        return Column(dtype, _decimal_to_scaled_i64(arr), valid)
+        if not pa.types.is_decimal128(t):
+            # another decimal width: arrow's own checked cast, then the view
+            return _convert(arr.cast(pa.decimal128(38, t.scale)),
+                            dec_as_int)[0], False
+        data, valid = _view_column(arr, np.int64, np.int64)
+    elif dtype == "int":
+        data, valid = _view_column(arr, t.to_pandas_dtype(), np.int64)
+    elif dtype == "date":
+        if not pa.types.is_date32(t):
+            raise TypeError(f"unsupported arrow type {t}")
+        data, valid = _view_column(arr, np.int32, np.int32)
+    else:
+        return _fallback_column(_chunked_to_array(arr), dtype), False
+    return Column(dtype, data, valid), True
+
+
+def _fallback_column(arr: pa.Array, dtype: str) -> Column:
+    """The columns no buffer view covers."""
+    t = arr.type
+    null_count = arr.null_count
     if dtype == "str":
         # encode at most ONCE (already-dictionary arrays pass through), and
         # null indices fill host-side — the old float-NaN round-trip turned
@@ -89,13 +162,6 @@ def from_arrow_column(arr, dec_as_int: bool = False) -> Column:
         dictionary = arr.dictionary.to_numpy(zero_copy_only=False) \
             .astype(object)
         return Column("str", codes, valid, dictionary)
-    if dtype == "date":
-        valid = ~np.asarray(arr.is_null()) if null_count else None
-        ints = arr.cast(pa.int32())
-        if null_count:  # fill BEFORE to_numpy: nulls otherwise round-trip
-            ints = pc.fill_null(ints, 0)  # through float NaN -> int garbage
-        days = ints.to_numpy(zero_copy_only=False)
-        return Column("date", np.asarray(days, dtype=np.int32), valid)
     if dtype == "float":
         if pa.types.is_decimal(t):
             arr = arr.cast(pa.float64())
@@ -104,25 +170,39 @@ def from_arrow_column(arr, dec_as_int: bool = False) -> Column:
         if valid is not None:
             vals = np.where(valid, vals, 0.0)
         return Column("float", vals, valid)
-    if dtype == "bool":
-        valid = ~np.asarray(arr.is_null()) if null_count else None
-        vals = arr.to_numpy(zero_copy_only=False)
-        vals = np.asarray(vals, dtype=bool)
-        return Column("bool", vals, valid)
-    # int
+    # bool
     valid = ~np.asarray(arr.is_null()) if null_count else None
     vals = arr.to_numpy(zero_copy_only=False)
-    if valid is not None:
-        vals = np.where(valid, vals, 0)
-    return Column("int", np.asarray(vals, dtype=np.int64), valid)
+    vals = np.asarray(vals, dtype=bool)
+    return Column("bool", vals, valid)
 
 
-def from_arrow(table: pa.Table, dec_as_int: bool = False) -> Table:
+def _count_columns(viewed: int, fallback: int) -> None:
+    _metrics.ARROW_VIEW_COLUMNS.inc(viewed)
+    _metrics.ARROW_FALLBACK_COLUMNS.inc(fallback)
+
+
+def from_arrow_column(arr, dec_as_int: bool = False) -> Column:
+    col, viewed = _convert(arr, dec_as_int)
+    _count_columns(int(viewed), int(not viewed))
+    return col
+
+
+def from_arrow(table: pa.Table, dec_as_int: bool = False,
+               span=None, counted: bool = True) -> Table:
+    """`span`: a tracer span that takes `viewed` / `fallback`, the columns
+    converted by buffer view and by the other paths. `counted=False`: a
+    system table's poll, which may move no counter but its own."""
     from ..resilience import FAULTS
     FAULTS.fire("arrow.read")
-    return Table(list(table.schema.names),
-                 [from_arrow_column(table.column(i), dec_as_int)
-                  for i in range(table.num_columns)])
+    made = [_convert(table.column(i), dec_as_int)
+            for i in range(table.num_columns)]
+    viewed = sum(v for _, v in made)
+    if counted:
+        _count_columns(viewed, len(made) - viewed)
+    if span is not None:
+        span.set(viewed=viewed, fallback=len(made) - viewed)
+    return Table(list(table.schema.names), [c for c, _ in made])
 
 
 def to_arrow_column(col: Column) -> pa.Array:
@@ -130,9 +210,9 @@ def to_arrow_column(col: Column) -> pa.Array:
     mask = None if col.valid is None else ~col.valid
     if is_dec(col.dtype):
         # output materialization is post-aggregation (small); exact loop.
-        # precision 20 covers any scaled int64 (<= 19 digits) and keeps the
-        # fast path available if the column round-trips back through
-        # _decimal_to_scaled_i64 (streamed-partials merge)
+        # precision 20 covers any scaled int64 (<= 19 digits); a column
+        # that round-trips back through from_arrow_column (streamed-partials
+        # merge) converts by buffer view, its high words checked vectorised
         return pa.array(col.decode().tolist(),
                         type=pa.decimal128(min(38, 20 + dec_scale(col.dtype)),
                                            dec_scale(col.dtype)))

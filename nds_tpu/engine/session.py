@@ -619,8 +619,9 @@ class Session:
                 return
             _metrics.BYTES_DECODED.inc(nbytes)
             with TRACER.span("morsel.from_arrow", cat="host",
-                             rows=part.num_rows, columns=part.num_columns):
-                table = arrow_bridge.from_arrow(part, dec)
+                             rows=part.num_rows,
+                             columns=part.num_columns) as sp:
+                table = arrow_bridge.from_arrow(part, dec, span=sp)
             yield table
 
     def load_table(self, name: str, columns=None) -> Table:  # lint: thread-entry (streaming staging threads + service lanes load concurrently)

@@ -595,6 +595,14 @@ BYTES_DECODED = METRICS.counter(
     "bytes_decoded", "Arrow bytes of the re-chunked morsel parts handed to "
     "arrow_bridge.from_arrow (Session.iter_morsels), moved once a morsel: "
     "over host_decode_ms, the streamed decode's rate")
+ARROW_VIEW_COLUMNS = METRICS.counter(
+    "arrow_view_columns", "Arrow columns arrow_bridge.from_arrow_column made "
+    "engine columns by viewing the value buffer Arrow holds (integers, "
+    "date32, exact-i64 decimal128): no pyarrow.compute kernel, no float trip")
+ARROW_FALLBACK_COLUMNS = METRICS.counter(
+    "arrow_fallback_columns", "Arrow columns arrow_bridge.from_arrow_column "
+    "converted by another path than the buffer view: strings, bools, floats, "
+    "float-mapped decimals, other decimal widths")
 BYTES_FETCHED = METRICS.counter(
     "bytes_fetched", "device->host bytes returned by program dispatches: "
     "results and check scalars")

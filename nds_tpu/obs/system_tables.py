@@ -249,7 +249,8 @@ def snapshot_engine_table(name: str, session=None):
     """Engine-Table view of :func:`snapshot_arrow` (the host executor's
     scan input)."""
     from ..engine import arrow_bridge
-    return arrow_bridge.from_arrow(snapshot_arrow(name, session))
+    return arrow_bridge.from_arrow(snapshot_arrow(name, session),
+                                   counted=False)
 
 
 def collect_table_refs(ast) -> set:

@@ -63,7 +63,8 @@ the wait for its result; the gathered partials' copy to the host is the
 ``merge.partials`` / ``finalize``; the streamed pass's main thread, under
 ``query``: ``morsel.decode`` (the wait for the next morsel) >
 ``morsel.read`` (the next re-chunked Arrow part) / ``morsel.from_arrow``
-(Arrow to the engine's ``Table``), ``morsel.stage_sync`` (a stage the
+(Arrow to the engine's ``Table``; ``viewed`` / ``fallback``: its columns
+made by buffer view and by another path), ``morsel.stage_sync`` (a stage the
 main thread pays itself, around ``morsel.stage`` / ``_sharded``),
 ``morsel.stage_wait`` (blocked on the staging thread) and
 ``morsel.partials`` (the members' partials to the host, compacted);

@@ -147,6 +147,24 @@ def test_bytes_decoded_counts_the_parts_handed_to_from_arrow(session):
     assert "bytes_decoded" in om.METRICS.describe()
 
 
+def test_view_columns_count_the_morsels_columns_and_the_merge(session):
+    """Every column the statement decodes is an integer: each morsel's two
+    become engine columns by buffer view, and so do the four of the merged
+    partials (`g0`, `sum(v)__s`, `sum(v)__n`, `count(1)__cs`) on their way
+    back from Arrow; nothing takes another path."""
+    _result, events, delta = traced(session)
+    assert [(e["args"]["viewed"], e["args"]["fallback"])
+            for e in named(events, "morsel.from_arrow")] == [(2, 0)] * MORSELS
+    assert delta["arrow_view_columns"] == MORSELS * 2 + 4
+    assert delta.get("arrow_fallback_columns", 0) == 0
+    assert {"arrow_view_columns", "arrow_fallback_columns"} <= \
+        set(om.METRICS.describe())
+    # the counters are always on, like bytes_decoded
+    before = om.METRICS.snapshot()
+    session.sql(QUERY)
+    assert om.METRICS.delta(before)["arrow_view_columns"] == MORSELS * 2 + 4
+
+
 def test_tracer_off_records_nothing_and_answers_the_same(session):
     TRACER.clear()
     before = om.METRICS.snapshot()

@@ -256,11 +256,18 @@ def count_fetched(fetched) -> None:
     _metrics.BYTES_FETCHED.inc(device_bytes(fetched))
 
 
+_JOIN_PATH_COUNTERS = (_metrics.DIRECT_JOINS, _metrics.SORTED_JOINS,
+                       _metrics.SCAN_ROWS, _metrics.DIRECT_PROBE_ROWS,
+                       _metrics.SORTED_PROBE_ROWS,
+                       _metrics.EXPANDED_JOIN_ROWS)
+
+
 def count_join_paths(join_paths: tuple) -> None:
-    """Add one dispatched program's (direct-address, sort-based) joins to
-    direct_joins / sorted_joins."""
-    _metrics.DIRECT_JOINS.inc(join_paths[0])
-    _metrics.SORTED_JOINS.inc(join_paths[1])
+    """Add one dispatched program's JaxExecutor.join_paths to direct_joins /
+    sorted_joins and to the four row counters beside them; a program that
+    has not been traced yet holds a shorter tuple, which reads as zeros."""
+    for counter, n in zip(_JOIN_PATH_COUNTERS, join_paths):
+        counter.inc(n)
 
 
 def _verify_schedule(decisions: list, checks_host: list) -> None:
@@ -314,8 +321,10 @@ class CompiledQuery:
         # compacting (JaxExecutor._maybe_compact): fixed by the trace, 0
         # under a mesh; run() moves mask_carried_filters by it
         self.mask_carried = 0
-        # (direct-address, sort-based) joins of this program: which path
-        # each JoinNode took is a recorded decision, so fixed by the trace
+        # (direct-address, sort-based) joins of this program and the rows
+        # its scans, probes and expansions hold (JaxExecutor.join_paths):
+        # which path each JoinNode took is a recorded decision and every
+        # capacity a shape, so fixed by the trace
         self.join_paths = (0, 0)
         # (window nodes, rollup grouping sets, set operations, outer joins)
         # this program holds; count_dispatch() moves their counters by it
@@ -727,6 +736,13 @@ class JaxExecutor:
         # sort-based path (dense_rank + build_side + probe_counts_by_gid)
         self.direct_joins = 0
         self.sorted_joins = 0
+        # rows by capacity (shapes, so Python integers under a trace too):
+        # of every table scan, of the probe side of every direct and of
+        # every sort-based join, and of every expansion's output
+        self.scan_rows = 0
+        self.direct_probe_rows = 0
+        self.sorted_probe_rows = 0
+        self.expanded_join_rows = 0
         self._scan_cache: dict[str, DTable] = scan_tables if scan_tables \
             is not None else {}           # accelerator-resident tables
         self._trace = trace
@@ -813,10 +829,15 @@ class JaxExecutor:
 
     @property
     def join_paths(self) -> tuple:
-        """(direct-address, sort-based) joins run so far: what a program
-        traced through this executor moves direct_joins / sorted_joins by
-        at each dispatch (count_join_paths)."""
-        return self.direct_joins, self.sorted_joins
+        """(direct-address joins, sort-based joins, scanned rows, rows that
+        probed directly, rows that probed through the sort, rows of expanded
+        join outputs) run so far, rows by capacity: what a program traced
+        through this executor moves direct_joins, sorted_joins, scan_rows,
+        direct_probe_rows, sorted_probe_rows and expanded_join_rows by at
+        each dispatch (count_join_paths)."""
+        return (self.direct_joins, self.sorted_joins, self.scan_rows,
+                self.direct_probe_rows, self.sorted_probe_rows,
+                self.expanded_join_rows)
 
     def _exec_sharding(self, capacity: int):
         """Placement for an accelerator-resident scan of given capacity."""
@@ -2002,7 +2023,9 @@ class JaxExecutor:
             # packed morsel upload: column slicing/bitcasts fuse into the
             # compiled program (see PackedTable)
             cached = unpack_table(cached)
-        return DTable(list(node.out_names), cached.cols, cached.alive)
+        out = DTable(list(node.out_names), cached.cols, cached.alive)
+        self.scan_rows += out.capacity
+        return out
 
     # -- sort / distinct -----------------------------------------------------
     def _run_sort(self, node: SortNode) -> DTable:
@@ -2703,6 +2726,7 @@ class JaxExecutor:
                 return out
 
         self.sorted_joins += 1
+        self.sorted_probe_rows += lcap
         key_data = []
         for lc, rc in zip(lkeys, rkeys):
             ld, rd = _joinable_pair(lc, rc)
@@ -2972,6 +2996,7 @@ class JaxExecutor:
         if not limit:
             return None
         self.direct_joins += 1
+        self.direct_probe_rows += lcap
         rmin, rmax = state["rmin"], state["rmax"]
         scatter_idx = state["scatter_idx"]
 
@@ -3036,6 +3061,7 @@ class JaxExecutor:
         total_t = jnp.sum(cnt)
         total = self._decide_cap(total_t)
         cap_out = bucket(max(total, 1))
+        self.expanded_join_rows += cap_out
         left_idx, build_pos, alive_out = kernels.expand_join(
             lo, cnt, left.alive, cap_out)
         right_rows = perm_r[jnp.clip(build_pos, 0, right.capacity - 1)]

@@ -653,6 +653,22 @@ DIRECT_JOINS = METRICS.counter(
 SORTED_JOINS = METRICS.counter(
     "sorted_joins", "joins of dispatched compiled programs that took the "
     "sort-based path (dense_rank + build_side + probe_counts_by_gid)")
+# How many rows those joins and the scans under them hold, by capacity (the
+# shapes of the dispatched program, so static per program and moved per
+# dispatch like the counts above): one 12.58M-row probe counts as one join
+# there and as 12,582,912 rows here
+SCAN_ROWS = METRICS.counter(
+    "scan_rows", "capacity of every table scan of dispatched compiled "
+    "programs (a static sum per program)")
+DIRECT_PROBE_ROWS = METRICS.counter(
+    "direct_probe_rows", "probe-side capacity of the joins of dispatched "
+    "compiled programs that took the direct-address path")
+SORTED_PROBE_ROWS = METRICS.counter(
+    "sorted_probe_rows", "probe-side capacity of the joins of dispatched "
+    "compiled programs that took the sort-based path")
+EXPANDED_JOIN_ROWS = METRICS.counter(
+    "expanded_join_rows", "output capacity of every M:N expansion "
+    "(JaxExecutor._expand_combine) of dispatched compiled programs")
 COLLECTIVE_BYTES = METRICS.counter(
     "collective_bytes", "per-chip ingress of the sharded morsels' partial "
     "all_gathers by the ring model: (n-1)/n of the gathered total")

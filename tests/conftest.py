@@ -93,6 +93,24 @@ _STALE_SINCE_PR_40 = (
     "test_the_nine_are_appended_after_what_was_there_in_the_issues_order",)
 
 
+# And for ISSUE 42, which appends the cell `power_inventory_sf1` and four row
+# counters: three cases pin what a sixth cell changes, in files only a
+# `benchmark` PR may edit — that `power_stratified_sf1` is the LAST cell to
+# report `pass_s`, that `direct_joins_per_pass` / `sorted_joins_per_pass`
+# list exactly four cells (and `outer_joins_per_pass` the strata cell alone),
+# and the half rule's arithmetic, which adds four cells in all and so holds
+# up to five committed ones. Restated, relative to the committed manifest,
+# in tests/benchmark/test_benchmark_cell_inventory_cpu.py.
+_STALE_SINCE_PR_42 = (
+    "test_benchmark_cell_strata_cpu.py::"
+    "test_the_cell_stands_after_the_accepted_four_on_one_chip",
+    "test_benchmark_join_paths_cpu.py::"
+    "test_the_two_metrics_are_data_appended_after_pr_32s_four_counters",
+    "test_benchmark_cell_streamed_x4_cpu.py::"
+    "test_the_half_rule_and_the_24_count_from_what_is_committed"
+    "[one_over_half]")
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
         for stale, issue, restated in (
@@ -100,7 +118,9 @@ def pytest_collection_modifyitems(items):
                 (_STALE_SINCE_PR_31, 31, "test_benchmark_mask_carried_cpu"),
                 (_STALE_SINCE_PR_35, 35, "test_benchmark_join_paths_cpu"),
                 (_STALE_SINCE_PR_38, 38, "test_benchmark_wide_span_joins_cpu"),
-                (_STALE_SINCE_PR_40, 40, "test_benchmark_view_cols_cpu")):
+                (_STALE_SINCE_PR_40, 40, "test_benchmark_view_cols_cpu"),
+                (_STALE_SINCE_PR_42, 42,
+                 "test_benchmark_cell_inventory_cpu")):
             if item.nodeid.endswith(stale):
                 item.add_marker(pytest.mark.xfail(
                     reason=f"pins what ISSUE {issue} changes; restated in "

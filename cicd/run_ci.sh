@@ -147,6 +147,7 @@ stage_planner() {
     # metrics-glossary completeness check — the profiling hooks thread
     # through the same planner/session/streaming paths this stage owns
     (cd "$REPO" && python -m pytest tests/test_late_materialization.py \
+        tests/test_join_stars.py \
         tests/test_capacity_ladder.py tests/test_shared_scan.py \
         tests/test_streaming.py tests/test_narrow_lanes.py \
         tests/test_obs.py tests/test_profile.py -q)

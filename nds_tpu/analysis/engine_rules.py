@@ -36,6 +36,7 @@ from .base import (Finding, def_header_pragma, dotted, has_pragma,
 PLAN_FIELDS = frozenset({
     "out_names", "out_dtypes", "child", "predicate", "exprs",
     "left_keys", "right_keys", "residual", "null_aware", "late_mat",
+    "star_build",
     "group_exprs", "aggs", "rollup", "rollup_levels", "funcs", "keys",
     "columns", "partition_by", "order_by", "nulls_first", "cte_segments",
 })

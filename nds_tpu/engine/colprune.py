@@ -252,7 +252,7 @@ class _Pruner:
                 [self._remap(k, lmap) for k in node.left_keys],
                 [self._remap(k, rmap) for k in node.right_keys],
                 residual, null_aware=node.null_aware,
-                late_mat=node.late_mat,
+                late_mat=node.late_mat, star_build=node.star_build,
                 out_names=names, out_dtypes=dtypes), out_map
         if isinstance(node, AggregateNode):
             child, cmap = self.rebuild(node.child)

@@ -326,8 +326,9 @@ class CompiledQuery:
         # which path each JoinNode took is a recorded decision and every
         # capacity a shape, so fixed by the trace
         self.join_paths = (0, 0)
-        # (window nodes, rollup grouping sets, set operations, outer joins)
-        # this program holds; count_dispatch() moves their counters by it
+        # (window nodes, rollup grouping sets, set operations, outer joins,
+        # joins whose build side is a star's join tree) this program holds;
+        # count_dispatch() moves their counters by it
         self.plan_shapes = _plan_shapes(plan)
         self._fn = None
         self._aot = None     # AOT executable from precompile()
@@ -3109,15 +3110,17 @@ def _grouping_sets(node: AggregateNode) -> list:
 
 
 _PLAN_SHAPE_COUNTERS = (_metrics.WINDOW_NODES, _metrics.ROLLUP_SETS,
-                        _metrics.SETOP_NODES, _metrics.OUTER_JOINS)
+                        _metrics.SETOP_NODES, _metrics.OUTER_JOINS,
+                        _metrics.STAR_JOINS)
 
 
 def _plan_shapes(plan) -> tuple:
     """(WindowNodes, grouping sets that rollup AggregateNodes emit,
-    SetOpNodes, outer JoinNodes) of one program's plan, or of the member
-    plans of a fused group; a node two parents share counts once. Another
-    compile unit's nodes stand behind a VirtualScanNode and are its own."""
-    windows = sets = setops = outer = 0
+    SetOpNodes, outer JoinNodes, JoinNodes whose build side is a star's own
+    join tree) of one program's plan, or of the member plans of a fused
+    group; a node two parents share counts once. Another compile unit's
+    nodes stand behind a VirtualScanNode and are its own."""
+    windows = sets = setops = outer = stars = 0
     for n in iter_plan_nodes(plan):
         if isinstance(n, WindowNode):
             windows += 1
@@ -3127,7 +3130,9 @@ def _plan_shapes(plan) -> tuple:
             setops += 1
         elif isinstance(n, JoinNode) and n.kind in ("left", "right", "full"):
             outer += 1
-    return windows, sets, setops, outer
+        elif isinstance(n, JoinNode) and n.star_build:
+            stars += 1
+    return windows, sets, setops, outer, stars
 
 
 def _mask_carrying_filters(root: PlanNode) -> frozenset:

@@ -149,6 +149,11 @@ class JoinNode(PlanNode):
     # The flag is an annotation (execution is a plain inner join); it blocks
     # re-application of the rewrite and makes rewritten plans inspectable.
     late_mat: bool = False
+    # the build side is a star's own join tree (planner._join_units: a fact
+    # joined to its own dimensions before it meets another fact). Like
+    # late_mat an annotation: execution is a plain inner join; plan_shapes
+    # counts it (star_joins) and explain shows it.
+    star_build: bool = False
 
 
 @dataclass

@@ -211,6 +211,10 @@ class Catalog:
 # ---------------------------------------------------------------------------
 
 
+#: what one pushed filter is taken to keep of a unit's rows: one row in five
+_FILTER_CUT = 5.0
+
+
 @dataclass
 class _Unit:
     """One relation participating in the FROM join graph."""
@@ -218,6 +222,8 @@ class _Unit:
     entries: list[ScopeEntry]      # local indices 0..w-1
     est_rows: float
     filters: list[A.Node] = field(default_factory=list)
+    # a star's own join tree standing as one unit (Planner._join_units)
+    star: bool = False
 
 
 class Planner:
@@ -502,11 +508,13 @@ class Planner:
             raise PlanError("SELECT without FROM is not supported")
         # explicit INNER JOIN chains flatten into the same unit/edge machinery
         # as comma joins (inner joins commute): ON conjuncts classify exactly
-        # like WHERE conjuncts, giving filter pushdown and size-ordered join
+        # like WHERE conjuncts, giving filter pushdown and _join_units'
         # placement to JOIN-syntax templates (reference query72's
         # cs JOIN inventory ON item would otherwise expand row-count-first in
-        # syntax order). Top-level LEFT joins peel into an ordered tail
-        # applied after the greedy join.
+        # syntax order; as units, catalog_sales and inventory each meet their
+        # own dimensions first and then each other once, on item and week).
+        # Top-level LEFT joins peel into an ordered tail applied after the
+        # inner group is joined.
         tail_specs: list = []
         root = self._peel_outer_tail(sel.from_, tail_specs)
         on_conjs: list = []
@@ -569,7 +577,7 @@ class Planner:
                 u.plan = P.FilterNode(u.plan, pred,
                                       out_names=list(u.plan.out_names),
                                       out_dtypes=list(u.plan.out_dtypes))
-                u.est_rows = max(1.0, u.est_rows / 5.0)
+                u.est_rows = max(1.0, u.est_rows / _FILTER_CUT)
             u.filters = []
 
         rel, col_map = self._join_units(units, edges, ctes, outer)
@@ -738,16 +746,108 @@ class Planner:
         return refs
 
     def _join_units(self, units, edges, ctes, outer):
-        """Greedy join: start from the largest (fact) unit, attach connected
-        units smallest-first (dimension build sides)."""
-        n = len(units)
-        if n == 1:
-            return units[0].plan, {0: 0}
-        remaining = set(range(n))
-        start = max(remaining, key=lambda i: units[i].est_rows)
+        """Join the units of one FROM / WHERE graph: a fact is joined to its
+        own dimensions before two facts are joined.
+
+        An equality edge is a *dimension edge* when its key on one endpoint
+        is a single column the catalog declares unique for that unit's base
+        table (_unit_key_is_unique); every other edge is M:N. The dimension
+        edges alone cut the graph into *stars* (_stars). With one star, or
+        where no star besides the largest unit's holds a second unit, the
+        whole graph is one left-deep spine (_join_greedy: start at the
+        largest unit, attach the smallest connected unit first). Otherwise
+        each star is joined on its own by that routine and stands as one
+        unit in a second round of it: the largest unit's star is the probe
+        spine, the smallest connected star (_star_est) is attached first as
+        a build side, and every edge between two stars is a key column of
+        that one JoinNode. reference query72: catalog_sales meets its three
+        filtered dimensions, d1 and d3 before it meets inventory, and
+        d1.d_week_seq = d2.d_week_seq is the second key column of the
+        fact-to-fact join, not a seven-fold expansion of inventory.
+
+        Returns (plan, {unit index: its column offset in the plan})."""
+        everyone = list(range(len(units)))
+        spine = max(everyone, key=lambda i: units[i].est_rows)
+        stars = self._stars(units, edges, ctes, outer)
+        if not any(len(s) > 1 and spine not in s for s in stars):
+            return self._join_greedy(units, edges, everyone, spine, ctes,
+                                     outer)
+        trees = [self._join_greedy(
+            units, edges, s, max(s, key=lambda i: units[i].est_rows), ctes,
+            outer) for s in stars]
+        # a star stands where a unit stood: its entries are its members' at
+        # their offsets in its own tree, qualifiers and names kept, so an
+        # edge's key binds in it as it bound in the member
+        subs = [_Unit(plan, self._joined_entries(units, offs),
+                      est_rows=self._star_est(units, s), star=len(s) > 1)
+                for s, (plan, offs) in zip(stars, trees)]
+        star_of = {u: si for si, s in enumerate(stars) for u in s}
+        between = [(star_of[a], star_of[b], le, re)
+                   for a, b, le, re in edges if star_of[a] != star_of[b]]
+        plan, star_off = self._join_greedy(
+            subs, between, list(range(len(stars))), star_of[spine], ctes,
+            outer)
+        return plan, {u: star_off[star_of[u]] + trees[star_of[u]][1][u]
+                      for u in everyone}
+
+    def _unit_key_is_unique(self, unit, expr, ctes, outer) -> bool:
+        """True when `expr` is one column of `unit` that the catalog declares
+        unique for the unit's base table, traced through the unit's pushed
+        filters and pure projections down to its scan (a filter keeps a
+        unique column unique); a CTE's or a sub-select's computed columns
+        declare nothing."""
+        if not isinstance(expr, A.ColumnRef):
+            return False
+        try:
+            bound = self._bind_in_unit(expr, unit, ctes, outer)
+        except PlanError:
+            return False
+        if not isinstance(bound, P.BCol):
+            return False
+        traced = _lm_key_scan(unit.plan, bound.index)
+        return traced is not None and self.catalog.is_unique(*traced)
+
+    def _stars(self, units, edges, ctes, outer) -> list[list[int]]:
+        """The connected components that the dimension edges alone leave, each
+        a sorted list of unit indices, in order of their first member."""
+        root = list(range(len(units)))
+
+        def find(i):
+            while root[i] != i:
+                root[i] = root[root[i]]
+                i = root[i]
+            return i
+        for a, b, lexpr, rexpr in edges:
+            if self._unit_key_is_unique(units[a], lexpr, ctes, outer) or \
+                    self._unit_key_is_unique(units[b], rexpr, ctes, outer):
+                root[find(b)] = find(a)
+        stars: dict[int, list[int]] = {}
+        for i in range(len(units)):
+            stars.setdefault(find(i), []).append(i)
+        return list(stars.values())
+
+    @staticmethod
+    def _star_est(units, star) -> float:
+        """A star's size for the ordering among stars: its largest unit's
+        estimate, cut by the pushed-filter factor once for each of its other
+        units that carries a pushed filter (a filtered dimension thins the
+        fact it joins)."""
+        big = max(star, key=lambda i: units[i].est_rows)
+        est = units[big].est_rows
+        for i in star:
+            if i != big and isinstance(units[i].plan, P.FilterNode):
+                est = max(1.0, est / _FILTER_CUT)
+        return est
+
+    def _join_greedy(self, units, edges, members, start, ctes, outer):
+        """One left-deep spine over `members` of `units`: from `start`, attach
+        whichever connected unit is smallest (dimension build sides first),
+        every edge to the units already placed a key column of its join; a
+        unit no edge reaches is cross-joined, smallest first."""
         current_plan = units[start].plan
         col_map = {start: 0}
         width = len(units[start].entries)
+        remaining = set(members)
         remaining.discard(start)
         placed = {start}
         while remaining:
@@ -772,6 +872,7 @@ class Planner:
             out_dtypes = current_plan.out_dtypes + unit.plan.out_dtypes
             current_plan = P.JoinNode(current_plan, unit.plan, kind,
                                       lkeys, rkeys, None,
+                                      star_build=unit.star,
                                       out_names=out_names, out_dtypes=out_dtypes)
             col_map[pick] = width
             width += len(unit.entries)

@@ -365,7 +365,7 @@ def _commute_join(join: JoinNode) -> PlanNode:
         join.right, join.left, "inner",
         left_keys=list(join.right_keys), right_keys=list(join.left_keys),
         residual=residual, null_aware=join.null_aware,
-        late_mat=join.late_mat,
+        late_mat=join.late_mat, star_build=join.star_build,
         out_names=list(join.right.out_names) + list(join.left.out_names),
         out_dtypes=list(join.right.out_dtypes) + list(join.left.out_dtypes))
     perm = [BCol(join.out_dtypes[i], wr + i, join.out_names[i])

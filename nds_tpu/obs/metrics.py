@@ -643,6 +643,11 @@ SETOP_NODES = METRICS.counter(
 OUTER_JOINS = METRICS.counter(
     "outer_joins", "left, right and full outer JoinNodes of dispatched "
     "compiled programs (a static count per program)")
+STAR_JOINS = METRICS.counter(
+    "star_joins", "JoinNodes of dispatched compiled programs whose build "
+    "side is a star's own join tree: a fact joined to its own dimensions "
+    "before it meets another fact (planner._join_units; a static count per "
+    "program, 0 for a statement whose joins are all fact-to-dimension)")
 # Which path the joins of a dispatched program took (a recorded decision per
 # JoinNode, so a static count per program, moved per dispatch like the plan
 # shapes above; a cross join and the mesh shuffle join are neither)

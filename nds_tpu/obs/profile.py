@@ -233,8 +233,10 @@ def node_detail(node) -> str:
     if t == "ScanNode":
         return node.table
     if t == "JoinNode":
-        return node.kind + ("+late_mat" if getattr(node, "late_mat", False)
-                            else "")
+        return node.kind + "".join(
+            mark for mark, flag in (("+late_mat", "late_mat"),
+                                    ("+star", "star_build"))
+            if getattr(node, flag, False))
     if t == "AggregateNode":
         return f"{len(node.group_exprs)}g/{len(node.aggs)}a" + \
             ("+rollup" if node.rollup else "")

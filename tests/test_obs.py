@@ -754,25 +754,27 @@ def test_bytes_fetched_counts_what_a_dispatch_returns(monkeypatch):
 
 # -- the plan shapes a dispatched program holds (ISSUE 32) ---------------------
 
-SHAPE_COUNTERS = ("window_nodes", "rollup_sets", "setop_nodes", "outer_joins")
+SHAPE_COUNTERS = ("window_nodes", "rollup_sets", "setop_nodes", "outer_joins",
+                  "star_joins")
 
 
 @pytest.mark.parametrize("sql,want", [
     ("SELECT k, v, RANK() OVER (PARTITION BY k ORDER BY v) AS r FROM t "
-     "WHERE v < 50 ORDER BY k, v", (1, 0, 0, 0)),
+     "WHERE v < 50 ORDER BY k, v", (1, 0, 0, 0, 0)),
     ("SELECT k, SUM(v) AS sv FROM t GROUP BY ROLLUP (k) ORDER BY k",
-     (0, 2, 0, 0)),
+     (0, 2, 0, 0, 0)),
     ("SELECT k FROM t WHERE v < 100 INTERSECT SELECT k FROM t WHERE v > 900 "
-     "UNION ALL SELECT k FROM t WHERE v = 500", (0, 0, 2, 0)),
+     "UNION ALL SELECT k FROM t WHERE v = 500", (0, 0, 2, 0, 0)),
     ("SELECT t.k, u.w FROM t LEFT OUTER JOIN u ON t.k = u.k WHERE t.v < 20 "
-     "ORDER BY 1, 2", (0, 0, 0, 1)),
+     "ORDER BY 1, 2", (0, 0, 0, 1, 0)),
     ("SELECT t.k, COUNT(*) AS c FROM t, u WHERE t.k = u.k GROUP BY t.k "
-     "ORDER BY 1", (0, 0, 0, 0)),
+     "ORDER BY 1", (0, 0, 0, 0, 0)),
 ], ids=["window", "rollup", "setops", "outer_join", "none"])
 def test_plan_shape_counters_move_by_the_programs_static_counts(sql, want):
-    """window_nodes / rollup_sets / setop_nodes / outer_joins move at each
-    dispatch of a compiled program by what its plan holds, and by nothing
-    where no program is dispatched: the host backend, the record pass."""
+    """window_nodes / rollup_sets / setop_nodes / outer_joins / star_joins
+    move at each dispatch of a compiled program by what its plan holds, and
+    by nothing where no program is dispatched: the host backend, the record
+    pass."""
     s = make_session()
     s.register_arrow("u", pa.table({
         "k": pa.array([0, 1, 2, 9], type=pa.int32()),
@@ -785,7 +787,7 @@ def test_plan_shape_counters_move_by_the_programs_static_counts(sql, want):
     before = om.METRICS.snapshot()
     oracle = s.sql(sql, backend="numpy").to_pylist()
     s.sql(sql, backend="jax")                   # the record pass
-    assert moved(before) == (0, 0, 0, 0)
+    assert moved(before) == (0,) * len(SHAPE_COUNTERS)
     for dispatch in (1, 2):
         got = s.sql(sql, backend="jax")
         assert s.last_exec_stats["mode"] in ("compiled", "compile+run")

@@ -8,7 +8,8 @@ to ``CompiledQuery`` / ``ShardedMorselQuery`` as ``join_paths``) — and moves
 once a dispatch by that sum: a batched dispatch once whatever rows ride it,
 a sharded morsel once and by the local program's rows, not once a replica.
 The eager record pass and the host backend move none. Tables of a dozen
-rows, programs of one join.
+rows, programs of one join. The last case is ISSUE 43's ``star_joins``: a
+plan-shape count beside them, moved the same way.
 """
 import jax
 import pytest
@@ -20,7 +21,7 @@ from nds_tpu.engine.executor import Executor
 from nds_tpu.engine.jax_backend.device import bucket
 from nds_tpu.engine.jax_backend.executor import (CompiledQuery, JaxExecutor,
                                                  count_join_paths)
-from nds_tpu.engine.plan import JoinNode
+from nds_tpu.engine.plan import JoinNode, iter_plan_nodes
 from nds_tpu.obs.metrics import METRICS
 
 COUNTERS = ("direct_joins", "sorted_joins", "scan_rows", "direct_probe_rows",
@@ -151,3 +152,59 @@ def test_a_sharded_morsel_counts_the_local_programs_rows_once_a_dispatch():
     assert moved(before) == (1, 0, 8 + 8, 8, 0, 0)
     run(build_table(RECORDED))
     assert moved(before) == (2, 0, 2 * 16, 2 * 8, 0, 0)
+
+
+#: two facts, each with a unique-key dimension of its own, joined on a key
+#: neither side holds once (ISSUE 43): the planner joins f2 to d2 first and
+#: hands that tree to ONE join as its build side
+TWO_STARS = ("SELECT d1.a, d2.b, COUNT(*) AS c FROM f1, d1, f2, d2 "
+             "WHERE f1.k1 = d1.k AND f2.k2 = d2.k AND f1.j = f2.j "
+             "GROUP BY d1.a, d2.b ORDER BY 1, 2")
+ONE_STAR = ("SELECT d1.a, d2.b, COUNT(*) AS c FROM f1, d1, d2 "
+            "WHERE f1.k1 = d1.k AND f1.j = d2.k "
+            "GROUP BY d1.a, d2.b ORDER BY 1, 2")
+
+
+@pytest.mark.parametrize("sql,want", [(TWO_STARS, 1), (ONE_STAR, 0)],
+                         ids=["two_stars", "one_star"])
+def test_star_joins_counts_a_star_build_join_once_a_dispatch(sql, want):
+    """``star_joins`` moves at each dispatch by the JoinNodes of the program
+    whose build side is a star's own join tree (``CompiledQuery.
+    plan_shapes``): 1 for a fact-to-fact join of two stars, 0 for a
+    statement whose joins are all fact-to-dimension; the record pass and
+    the host backend move none."""
+    import pyarrow as pa
+
+    from nds_tpu.engine import Session
+    s = Session()
+    ints = pa.int64()
+    s.register_arrow("f1", pa.table({
+        "k1": pa.array([0, 1, 2, 1, 0, 2, 1, 3], type=ints),
+        "j": pa.array([5, 5, 6, 7, 6, 5, 7, 9], type=ints)}), est_rows=800)
+    s.register_arrow("f2", pa.table({
+        "k2": pa.array([1, 0, 1, 2, 0], type=ints),
+        "j": pa.array([5, 6, 6, 7, 8], type=ints)}), est_rows=500)
+    for name in ("d1", "d2"):
+        s.register_arrow(name, pa.table({
+            "k": pa.array([0, 1, 2, 5, 6], type=ints),
+            "a" if name == "d1" else "b":
+                pa.array([10, 11, 12, 15, 16], type=ints)}),
+            unique_cols=("k",))
+    before = METRICS.snapshot()
+    oracle = s.sql(sql, backend="numpy").to_pylist()
+    assert len(oracle) > 1
+    s.sql(sql, backend="jax")                   # the record pass
+    assert METRICS.delta(before).get("star_joins", 0) == 0
+    for dispatch in (1, 2):
+        got = s.sql(sql, backend="jax")
+        assert s.last_exec_stats["mode"] in ("compiled", "compile+run")
+        assert METRICS.delta(before).get("star_joins", 0) == dispatch * want
+    assert got.to_pylist() == oracle
+    cq = s._jax_exec._plans[("sql", sql)]["cq"]
+    assert cq.plan_shapes[-1] == want
+    stars = [n for n in iter_plan_nodes(cq.plan)
+             if isinstance(n, JoinNode) and n.star_build]
+    assert len(stars) == want
+    for n in stars:
+        assert isinstance(n.right, JoinNode) and len(n.left_keys) == 1
+    assert "star_joins" in METRICS.describe()
